@@ -16,11 +16,8 @@ Two layers, matching how hipBone inherits gslib's setup-time selection:
 from __future__ import annotations
 
 import json
-import os
-import subprocess
-import sys
 
-SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+from benchmarks.spawn import run_child
 
 _CHILD = r"""
 import os, json, time
@@ -90,19 +87,6 @@ print(json.dumps(recs))
 """
 
 
-def _run_child(code: str, extra_env: dict | None = None, timeout: int = 600):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    env.update(extra_env or {})
-    res = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, timeout=timeout,
-    )
-    if res.returncode != 0:
-        raise RuntimeError(res.stderr[-2000:])
-    return json.loads(res.stdout.strip().splitlines()[-1])
-
-
 def records(quick: bool = True) -> list[dict]:
     """Per-site exchange plan records for the json summary.
 
@@ -116,10 +100,10 @@ def records(quick: bool = True) -> list[dict]:
         "local": [2, 2, 1] if quick else [2, 2, 2],
         "repeats": 3 if quick else 5,
     }
-    return _run_child(
+    return run_child(
         _CHILD_PLAN,
         {"EXCHANGE_PLAN_CFG": json.dumps(cfg)},
-        timeout=900,
+        section="exchange",
     )
 
 
@@ -140,7 +124,7 @@ def rows_from(recs: list[dict]) -> list[str]:
 
 
 def main(quick: bool = True) -> list[str]:
-    data = _run_child(_CHILD)
+    data = run_child(_CHILD, timeout=600, section="exchange")
     rows = ["exchange,chunk_floats,all_to_all_us,pairwise_us,crystal_us,winner"]
     for chunk, row in data.items():
         rows.append(

@@ -11,12 +11,7 @@ absolute GFLOPS; TPU absolutes live in §Roofline.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
-SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+from benchmarks.spawn import run_child
 
 _CHILD = r"""
 import os, sys, json, time
@@ -58,15 +53,7 @@ def _run(ranks: int, degree: int, local: tuple) -> dict:
         .replace("DEGREE", str(degree))
         .replace("LOCAL", str(local))
     )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, timeout=900,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    return run_child(code, section="fig456")
 
 
 def main(quick: bool = True) -> list[str]:

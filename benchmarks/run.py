@@ -53,6 +53,10 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         batched_solve,
         exchange_select,

@@ -7,12 +7,7 @@ Runs BOTH storage modes (hipBone assembled vs NekBone scattered) at N=7 on
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
-SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+from benchmarks.spawn import run_child
 
 _CHILD = r"""
 import os, json, time
@@ -56,16 +51,8 @@ print(json.dumps({
 
 
 def _run(ranks: int, fused: bool | None = None) -> dict:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     child = _CHILD.replace("RANKS", str(ranks)).replace("FUSED", repr(fused))
-    out = subprocess.run(
-        [sys.executable, "-c", child],
-        capture_output=True, text=True, env=env, timeout=900,
-    )
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    return json.loads(out.stdout.strip().splitlines()[-1])
+    return run_child(child, section="table2")
 
 
 def main(quick: bool = True, fused: bool | None = None) -> list[str]:

@@ -4,21 +4,20 @@ Drives the `repro.testing.faults` injectors through a real solve and checks
 that each one trips exactly the `SolveStatus` it models, then that the
 fallback chain (`repro.core.resilience`) recovers each scenario to
 CONVERGED.  Exits non-zero on the first wrong verdict — CI runs this as
-the fault-injection smoke leg, once plain and once under HIPBONE_FUSED=1
-(where the forced-probe-failure scenario additionally proves the fused
-operator degrades to the split pipeline instead of crashing).
+the fault-injection smoke leg.  A last leg checks the kernel selection
+rules on whatever backend runs it: float64 is never fused on a native
+backend, and an explicit request for the fused operator raises where that
+kernel has no lowering instead of degrading.
 
     PYTHONPATH=src python examples/fault_injection.py
 """
-import os
-import warnings
-
 import jax
 
 jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     SolveStatus,
     build_problem,
@@ -30,7 +29,6 @@ from repro.core import (
 from repro.core.precond import make_preconditioner
 from repro.kernels import ops
 from repro.testing import (
-    force_fused_failure,
     mask_precond,
     nan_at_iteration,
     negate_precond,
@@ -50,6 +48,7 @@ def check(name: str, got, want) -> None:
 
 
 def main() -> int:
+    enable_compile_cache()
     prob = build_problem(3, (3, 2, 2), lam=0.7, deform=0.2,
                          dtype=jnp.float64)
     a = poisson_assembled(prob)
@@ -102,22 +101,20 @@ def main() -> int:
         print(f"        attempt {att['attempt']}: {att['action']:>32} "
               f"precond={att['precond']:<7} -> {att['status']}")
 
-    print("fused-operator degradation:")
-    # force the static policy to "yes" so the probe is consulted even on a
-    # CPU host — the degradation must hold under HIPBONE_FUSED=1 too
-    os.environ["HIPBONE_FUSED"] = "1"
-    shape = dict(n_degree=prob.mesh.n_degree, n_global=prob.n_global)
-    with force_fused_failure():
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            fuse = ops.should_fuse_operator(jnp.float64, **shape)
-        check("probe failure → split pipeline",
-              (fuse, sum(issubclass(x.category, RuntimeWarning)
-                         for x in w)),
-              (False, 1))
-        res = cg_assembled(poisson_assembled(prob), b, n_iter=500, tol=1e-8)
-        check("solve on the degraded path", status_name(res.status),
-              "converged")
+    print("kernel selection rules:")
+    native = not ops.default_interpret()
+    check("float64 never fused on a native backend",
+          native and ops.should_fuse_streams(jnp.float64), False)
+    check("auto policy follows HIPBONE_FUSED off a native backend",
+          native or poisson_assembled(prob).fused,
+          native or ops.fused_override() is True)
+    try:
+        poisson_assembled(prob, fused=True)
+        raised = False
+    except NotImplementedError:
+        raised = True
+    check("fused=True raises where the kernel has no lowering",
+          raised, native)
 
     if FAILED:
         print(f"\n{len(FAILED)} scenario(s) failed: {FAILED}")

@@ -1,29 +1,15 @@
 """Distributed hipBone: multi-rank CG with communication-hiding split.
 
-Emulates a multi-rank run on N fake CPU devices (set before jax import),
-exercising the full distributed path: padded-consistent assembled storage,
-halo sum-exchange via static ppermutes, interior/halo overlap split, and
-masked+psum inner products.
+Runs the full distributed path — padded-consistent assembled storage, halo
+sum-exchange via static ppermutes, interior/halo overlap split, and
+masked+psum inner products — on one rank per device.  On a TPU host the
+ranks are the chips (``--ranks`` defaults to all of them; ``--ranks 1``
+uses the first); elsewhere they are virtual CPU devices (default 8):
 
     PYTHONPATH=src python examples/poisson_scaling.py --ranks 8 --n 7
 """
 import argparse
 import os
-import sys
-
-if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
-    # relaunch with the device count pinned before jax import
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--ranks", type=int, default=8)
-    args, rest = ap.parse_known_args()
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.ranks}"
-    )
-    os.execv(
-        sys.executable,
-        [sys.executable, __file__, "--ranks", str(args.ranks)] + rest,
-    )
-
 import time
 
 import jax
@@ -32,14 +18,19 @@ import numpy as np
 
 from repro.compat import make_mesh
 from repro.comms.topology import ProcessGrid, factor3
+from repro.compile_cache import enable_compile_cache
 from repro.core.cg import status_name
 from repro.core.distributed import build_dist_problem, dist_cg, dist_spectrum
 from repro.core.fom import nekbone_flops_per_iter
 
+CPU_RANKS = 8
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--ranks", type=int, default=8)
+    ap.add_argument("--ranks", type=int, default=None,
+                    help="ranks, one per device (default: every chip on a "
+                         f"TPU host, else {CPU_RANKS} virtual CPU devices)")
     ap.add_argument("--n", type=int, default=7)
     ap.add_argument("--local", type=int, default=2, help="elements per axis per rank")
     ap.add_argument("--iters", type=int, default=100)
@@ -82,8 +73,20 @@ def main() -> None:
                          "choice — only wall time moves.")
     args = ap.parse_args()
 
-    ranks = args.ranks
-    assert len(jax.devices()) == ranks, "device count mismatch"
+    # virtual CPU devices, one per rank (read when the backend starts; a
+    # TPU backend ignores the flag)
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count="
+            f"{args.ranks or CPU_RANKS}"
+        ).strip()
+    enable_compile_cache()
+    devices = jax.devices()
+    default_ranks = len(devices) if jax.default_backend() == "tpu" else CPU_RANKS
+    ranks = args.ranks or default_ranks
+    if ranks > len(devices):
+        ap.error(f"--ranks {ranks} exceeds the {len(devices)} devices here")
     dtype = jnp.dtype(args.dtype)
     if dtype == jnp.float64:
         jax.config.update("jax_enable_x64", True)
@@ -97,9 +100,10 @@ def main() -> None:
         "flexible" if pdtype is not None and pdtype != dtype else "standard"
     )
     grid = ProcessGrid(factor3(ranks))
-    mesh = make_mesh((ranks,), ("ranks",))
+    mesh = make_mesh((ranks,), ("ranks",), devices=devices[:ranks])
     local = (args.local,) * 3
     prob = build_dist_problem(args.n, grid, local, lam=1.0, dtype=dtype)
+    print(f"devices: {devices[0].platform} {devices[0].device_kind} x{ranks}")
     print(f"ranks={ranks} grid={grid.shape} local={local} N={args.n} "
           f"global DOFs={prob.n_global:,} halo elems/rank={prob.halo_elems}/{prob.e_local} "
           f"precond={args.precond}")

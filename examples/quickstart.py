@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     build_problem,
     cg_assembled,
@@ -29,15 +30,16 @@ def main() -> None:
     ap.add_argument("--n", type=int, default=7, help="polynomial degree")
     ap.add_argument("--elems", type=int, default=6, help="elements per axis")
     ap.add_argument("--iters", type=int, default=100)
-    ap.add_argument("--pallas", action="store_true", help="use the Pallas kernel (interpret mode on CPU)")
+    ap.add_argument("--pallas", action="store_true", help="use the Pallas element kernel (native on TPU, interpreted elsewhere)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     prob = build_problem(args.n, (args.elems,) * 3, lam=1.0, dtype=jnp.float32)
     e = prob.mesh.n_elements
     print(f"mesh: {args.elems}^3 elements, N={args.n}  "
           f"N_G={prob.n_global:,} DOFs, N_L={prob.n_local:,} local nodes")
 
-    local_op = ops.make_local_op(interpret=True) if args.pallas else None
+    local_op = ops.make_local_op() if args.pallas else None
     a = poisson_assembled(prob, local_op=local_op)
     rng = np.random.default_rng(0)
     b = jnp.asarray(rng.standard_normal(prob.n_global), jnp.float32)

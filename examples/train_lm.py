@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data import SyntheticLM
 from repro.models.config import ModelConfig
 from repro.models.model import init_model
@@ -67,6 +68,7 @@ def main() -> None:
     ap.add_argument("--compress", action="store_true",
                     help="int8+error-feedback gradient psum over the dp axis")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_cfg(args.size)
     params, _ = init_model(cfg, jax.random.key(0), jnp.float32)

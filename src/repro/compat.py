@@ -1,15 +1,15 @@
-"""jax version-compat shims for the small API surface the repo depends on.
+"""Thin wrappers over the small jax API surface the repo's meshes use.
 
-The repo targets the current jax API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``), but the
-pinned CI/runtime image may carry an older jax (0.4.x) where shard_map
-still lives in ``jax.experimental`` under the ``check_rep`` spelling and
-meshes have no axis types.  Everything in-repo goes through these three
-helpers so a jax upgrade is a no-op and a downgrade is survivable:
+The repo targets the installed jax (0.9): ``jax.shard_map``,
+``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``.
+Everything in-repo goes through these helpers so the spelling lives in
+one place:
 
-  * ``make_mesh(shape, names)``       — Auto-typed mesh where supported
-  * ``shard_map(f, mesh=..., ...)``   — check_rep/check_vma translated
-  * ``abstract_mesh(shape, names)``   — both AbstractMesh signatures
+  * ``make_mesh(shape, names)``       — Auto-typed mesh
+  * ``shard_map(f, mesh=..., ...)``   — the repo's ``check_rep`` spelling
+    of ``check_vma``
+  * ``abstract_mesh(shape, names)``   — AbstractMesh from sizes and names
+  * ``axis_size`` / ``pcast_varying`` — named-axis size, varying cast
 """
 from __future__ import annotations
 
@@ -19,8 +19,6 @@ import jax
 
 __all__ = ["make_mesh", "shard_map", "abstract_mesh", "axis_size", "pcast_varying"]
 
-_AXIS_TYPE_AUTO = getattr(getattr(jax.sharding, "AxisType", None), "Auto", None)
-
 
 def make_mesh(
     axis_shapes: Sequence[int],
@@ -28,68 +26,33 @@ def make_mesh(
     *,
     devices: Sequence[Any] | None = None,
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
-    if _AXIS_TYPE_AUTO is not None:
-        return jax.make_mesh(
-            tuple(axis_shapes),
-            tuple(axis_names),
-            devices=devices,
-            axis_types=(_AXIS_TYPE_AUTO,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), devices=devices)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(axis_shapes),
+        tuple(axis_names),
+        devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+    )
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs, check_rep: bool = True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-
-else:  # jax < 0.5: experimental module, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs, check_rep: bool = True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_rep,
-        )
+def shard_map(f: Callable, *, mesh, in_specs, out_specs, check_rep: bool = True):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_rep,
+    )
 
 
-if hasattr(jax.lax, "axis_size"):
-
-    def axis_size(axis_name) -> int:
-        return jax.lax.axis_size(axis_name)
-
-else:
-
-    def axis_size(axis_name) -> int:
-        # psum of a Python literal over a named axis folds to a static int
-        return jax.lax.psum(1, axis_name)
+def axis_size(axis_name) -> int:
+    return jax.lax.axis_size(axis_name)
 
 
-if hasattr(jax.lax, "pcast"):
-
-    def pcast_varying(x, axes):
-        """Mark ``x`` device-varying over ``axes`` (new-jax vma typing)."""
-        return jax.lax.pcast(x, tuple(axes), to="varying")
-
-else:
-
-    def pcast_varying(x, axes):
-        # old shard_map has no varying-manual-axes type system; its
-        # check_rep rewrite inserts pbroadcasts itself, so identity is right
-        return x
+def pcast_varying(x, axes):
+    """Mark ``x`` device-varying over ``axes`` (vma typing)."""
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def abstract_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str]
 ) -> jax.sharding.AbstractMesh:
-    """AbstractMesh across the (sizes, names) / ((name, size),...) signatures."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(tuple(axis_names), tuple(axis_shapes)))
-        )
+    """AbstractMesh from per-axis sizes and names."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))

@@ -57,6 +57,10 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+
+# TPU's default f32 matmul is one bf16 pass; the solver needs full f32
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "CGResult",
     "CG_VARIANTS",
@@ -141,13 +145,13 @@ def fused_residual_update(
 ) -> tuple[jax.Array, jax.Array]:
     """One-pass r update + self-dot (reference; Pallas version in kernels/)."""
     r_new = r - alpha * ap
-    return r_new, jnp.vdot(r_new, r_new)
+    return r_new, jnp.vdot(r_new, r_new, precision=_HI)
 
 
 def _dot(a: jax.Array, b: jax.Array, w: jax.Array | None) -> jax.Array:
     if w is None:
-        return jnp.vdot(a, b)
-    return jnp.vdot(a * w, b)
+        return jnp.vdot(a, b, precision=_HI)
+    return jnp.vdot(a * w, b, precision=_HI)
 
 
 def _safe_div(a, b):

@@ -92,6 +92,10 @@ from .schwarz import (
     overlap_counts_1d,
 )
 
+
+# TPU's default f32 matmul is one bf16 pass; the solver needs full f32
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "DistPoisson",
     "build_dist_problem",
@@ -1184,7 +1188,7 @@ def dist_spectrum(
         dinv = _box_dinv(prob, g1, w1, screen=s1)
         if bcm1 is not None:
             dinv = bcm1 * dinv
-        mdot = lambda a, bb: jnp.vdot(a * m1, bb)
+        mdot = lambda a, bb: jnp.vdot(a * m1, bb, precision=_HI)
         lmin, lmax = lanczos_extremes(
             operator, dinv, seed_s[0],
             iters=lanczos_iters, dot=mdot,
@@ -1238,7 +1242,7 @@ def dist_lambda_max(
         dinv = _box_dinv(prob, g1, w1, screen=s1)
         if bcm1 is not None:
             dinv = bcm1 * dinv
-        mdot = lambda a, bb: jnp.vdot(a * m1, bb)
+        mdot = lambda a, bb: jnp.vdot(a * m1, bb, precision=_HI)
         return power_lambda_max(
             operator, dinv, seed_s[0],
             iters=power_iters, dot=mdot,
@@ -1346,9 +1350,9 @@ def dist_cg(
         single-pass fused assembled kernel
         (``kernels.ops.poisson_assembled_fused`` — gather, local op and
         scatter-add in one Pallas pass over the rank-local box) instead of
-        the split pipeline.  ``None`` defers to
-        ``kernels.ops.should_fuse_operator`` (native-Pallas backend + VMEM
-        fit; ``HIPBONE_FUSED=0/1`` forces), except when an explicit
+        the split pipeline; interpret mode only (a native backend
+        raises).  ``None`` defers to ``kernels.ops.should_fuse_operator``
+        (off unless ``HIPBONE_FUSED=1``), except when an explicit
         ``local_op`` pins the split pipeline.  Preconditioner-internal
         A-applies keep the split form — they run in ``precond_dtype`` and
         their traffic is not the Eq. 4 bound this kernel targets.
@@ -1456,9 +1460,7 @@ def dist_cg(
         else:
             from ..kernels import ops as _kops  # lazy: kernels import core
 
-            fused_operator = _kops.should_fuse_operator(
-                prob.dtype, n_degree=prob.n_degree, n_global=prob.m3
-            )
+            fused_operator = _kops.should_fuse_operator()
     spec = P(prob.axis_name)
     hist_len = n_iter
 
@@ -1646,7 +1648,7 @@ def dist_cg(
                 pc = schwarz_apply(0, pprob, bcm1c)
             elif precond == "chebyshev":
                 if lmax is None:
-                    mdot = lambda a, bb: jnp.vdot(a * m1c, bb)
+                    mdot = lambda a, bb: jnp.vdot(a * m1c, bb, precision=_HI)
                     lmin_e, lmax_e = lanczos_extremes(
                         operator_pc, dinv, seed_s[0],
                         iters=lanczos_iters, dot=mdot, psum=psum,
@@ -1725,7 +1727,7 @@ def dist_cg(
 
                 smoothers, smoothers_pair = [], []
                 for i in range(len(levels) - 1):
-                    mdot = lambda a, bb, mk=lvl_masks[i]: jnp.vdot(a * mk, bb)
+                    mdot = lambda a, bb, mk=lvl_masks[i]: jnp.vdot(a * mk, bb, precision=_HI)
                     if pmg_smoother == "schwarz":
                         base = schwarz_apply(i, levels[i], lvl_bcms[i])
                     else:
@@ -1758,7 +1760,7 @@ def dist_cg(
                             )
                         )
                 # coarsest (degree-1): full-interval Chebyshev "solve"
-                mdot_c = lambda a, bb: jnp.vdot(a * lvl_masks[-1], bb)
+                mdot_c = lambda a, bb: jnp.vdot(a * lvl_masks[-1], bb, precision=_HI)
                 lmin_e, lmax_e = lanczos_extremes(
                     lvl_ops[-1], lvl_dinvs[-1], lvl_seeds[-1],
                     iters=lanczos_iters, dot=mdot_c, psum=psum,
@@ -2002,7 +2004,7 @@ def dist_cg_scattered(
             if precond == "jacobi":
                 pc = jacobi_apply(dinv_l)
             else:
-                wdot = lambda a, bb: jnp.vdot(a * w1c, bb)
+                wdot = lambda a, bb: jnp.vdot(a * w1c, bb, precision=_HI)
                 if lmax is None:
                     seed_l = jnp.take(seed_s[0], l2g_flat, axis=0).reshape(
                         b1.shape

@@ -55,6 +55,10 @@ from . import sem
 from .gather_scatter import gather, scatter
 from .operator import local_operator_columns
 
+
+# TPU's default f32 matmul is one bf16 pass; the solver needs full f32
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "tensor3_interp_matrix",
     "galerkin_element_blocks",
@@ -116,7 +120,7 @@ def galerkin_element_blocks(
         g.dtype,
     )
     cols = local_operator_columns(g, d, lam, w, jhat)    # (E, p_f, p_c)
-    return _symmetrize(jnp.einsum("pj,epk->ejk", jhat, cols))
+    return _symmetrize(jnp.einsum("pj,epk->ejk", jhat, cols, precision=_HI))
 
 
 def coarsen_element_blocks(blocks: jax.Array, j: np.ndarray) -> jax.Array:
@@ -127,7 +131,7 @@ def coarsen_element_blocks(blocks: jax.Array, j: np.ndarray) -> jax.Array:
     already-materialized blocks; the fine grid is never revisited.
     """
     jhat = jnp.asarray(tensor3_interp_matrix(j), blocks.dtype)
-    return _symmetrize(jnp.einsum("pj,epq,qk->ejk", jhat, blocks, jhat))
+    return _symmetrize(jnp.einsum("pj,epq,qk->ejk", jhat, blocks, jhat, precision=_HI))
 
 
 def galerkin_ladder_blocks(
@@ -161,7 +165,7 @@ def block_matvec_einsum(blocks: jax.Array, u: jax.Array) -> jax.Array:
     XLA lowers this to one batched MXU matmul; ``kernels.ops.block_matvec``
     is the explicit Pallas variant with the same contract.
     """
-    return jnp.einsum("eij,ej->ei", blocks, u)
+    return jnp.einsum("eij,ej->ei", blocks, u, precision=_HI)
 
 
 def galerkin_block_apply(

@@ -27,6 +27,10 @@ from . import geometry, sem
 from .gather_scatter import gather, gather_scatter, inverse_degree, scatter
 from .mesh import BoxMesh, build_box_mesh, dirichlet_mask, normalize_bc
 
+
+# TPU's default f32 matmul is one bf16 pass; the solver needs full f32
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "local_poisson",
     "local_operator_columns",
@@ -79,9 +83,9 @@ def local_poisson(
     u3 = u.reshape(e, n1, n1, n1)  # (E, t, s, r)
 
     # Gradient: three batched contractions — these hit the MXU.
-    ur = jnp.einsum("ia,etsa->etsi", d, u3)
-    us = jnp.einsum("jb,etbr->etjr", d, u3)
-    ut = jnp.einsum("kc,ecsr->eksr", d, u3)
+    ur = jnp.einsum("ia,etsa->etsi", d, u3, precision=_HI)
+    us = jnp.einsum("jb,etbr->etjr", d, u3, precision=_HI)
+    ut = jnp.einsum("kc,ecsr->eksr", d, u3, precision=_HI)
 
     g3 = g.reshape(e, 6, n1, n1, n1)
     wr = g3[:, 0] * ur + g3[:, 1] * us + g3[:, 2] * ut
@@ -90,9 +94,9 @@ def local_poisson(
 
     # Divergence: transposed contractions.
     out = (
-        jnp.einsum("ia,etsi->etsa", d, wr)
-        + jnp.einsum("jb,etjr->etbr", d, ws)
-        + jnp.einsum("kc,eksr->ecsr", d, wt)
+        jnp.einsum("ia,etsi->etsa", d, wr, precision=_HI)
+        + jnp.einsum("jb,etjr->etbr", d, ws, precision=_HI)
+        + jnp.einsum("kc,eksr->ecsr", d, wt, precision=_HI)
     ).reshape(e, p)
 
     screen = u if jw is None else jw * u
@@ -355,18 +359,19 @@ def poisson_assembled(
 ) -> Callable[[jax.Array], jax.Array]:
     """hipBone operator: x_G (N_G,) -> A x_G (N_G,).
 
-    Split form (default off-TPU): y_L = (S_L + λW) Z x_G, then the gather
+    Split form (the default): y_L = (S_L + λW) Z x_G, then the gather
     Z^T y_L — three XLA ops.  ``local_op`` lets callers swap in the Pallas
     element kernel for the middle stage; default is the pure-jnp reference.
 
     ``fused`` selects the single-kernel form instead
     (``kernels.ops.poisson_assembled_fused``): gather, local operator and
-    scatter-add in one Pallas pass, no x_L/y_L HBM round-trips.  ``None``
-    defers to ``kernels.ops.should_fuse_operator`` (native-Pallas backend +
-    VMEM fit; ``HIPBONE_FUSED=0/1`` forces it off/on) — except when a
-    custom ``local_op`` is given, which pins the split pipeline that uses
-    it.  ``fused_kwargs`` passes ``block_e`` / ``interpret`` /
-    ``gather_mode`` through to the fused wrapper.
+    scatter-add in one Pallas pass, no x_L/y_L HBM round-trips.  It runs
+    only through the Pallas interpreter; ``fused=True`` on a native
+    backend raises.  ``None`` defers to ``kernels.ops.should_fuse_operator``
+    (off unless ``HIPBONE_FUSED=1``) — except when a custom ``local_op``
+    is given, which pins the split pipeline that uses it.
+    ``fused_kwargs`` passes ``block_e`` / ``interpret`` / ``gather_mode``
+    through to the fused wrapper.
     """
     if fused is None:
         if local_op is not None:
@@ -374,11 +379,7 @@ def poisson_assembled(
         else:
             from ..kernels import ops as _kops  # lazy: kernels import core
 
-            fused = _kops.should_fuse_operator(
-                prob.dtype,
-                n_degree=prob.mesh.n_degree,
-                n_global=prob.n_global,
-            )
+            fused = _kops.should_fuse_operator()
     if fused:
         if local_op is not None:
             raise ValueError(

@@ -75,6 +75,10 @@ from . import sem
 from .gather_scatter import gather, scatter
 from .schwarz import SCHWARZ_INNER_DEGREE, make_schwarz_apply
 
+
+# TPU's default f32 matmul is one bf16 pass; the solver needs full f32
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "local_operator_diagonal",
     "assembled_diagonal",
@@ -150,9 +154,9 @@ def local_operator_diagonal(
     # Same contraction patterns as the divergence in local_poisson, with D²
     # and the diagonal metric blocks.
     diag = (
-        jnp.einsum("ia,etsi->etsa", d2, g3[:, 0])   # Σ_i D[i,r]² G_rr
-        + jnp.einsum("jb,etjr->etbr", d2, g3[:, 3])  # Σ_j D[j,s]² G_ss
-        + jnp.einsum("kc,eksr->ecsr", d2, g3[:, 5])  # Σ_k D[k,t]² G_tt
+        jnp.einsum("ia,etsi->etsa", d2, g3[:, 0], precision=_HI)   # Σ_i D[i,r]² G_rr
+        + jnp.einsum("jb,etjr->etbr", d2, g3[:, 3], precision=_HI)  # Σ_j D[j,s]² G_ss
+        + jnp.einsum("kc,eksr->ecsr", d2, g3[:, 5], precision=_HI)  # Σ_k D[k,t]² G_tt
     )
     dd = jnp.diagonal(d)
     ddr = dd.reshape(1, 1, 1, n1)
@@ -210,7 +214,7 @@ def masked_seed(prob, v0: jax.Array) -> jax.Array:
 
 
 def _default_dot(a: jax.Array, b: jax.Array) -> jax.Array:
-    return jnp.vdot(a, b)
+    return jnp.vdot(a, b, precision=_HI)
 
 
 def _base_apply(
@@ -561,9 +565,9 @@ def tensor3_interp(j: jax.Array, u: jax.Array) -> jax.Array:
     e = u.shape[0]
     n_in = j.shape[1]
     u3 = u.reshape(e, n_in, n_in, n_in)
-    u3 = jnp.einsum("ra,etsa->etsr", j, u3)
-    u3 = jnp.einsum("sb,etbr->etsr", j, u3)
-    u3 = jnp.einsum("tc,ecsr->etsr", j, u3)
+    u3 = jnp.einsum("ra,etsa->etsr", j, u3, precision=_HI)
+    u3 = jnp.einsum("sb,etbr->etsr", j, u3, precision=_HI)
+    u3 = jnp.einsum("tc,ecsr->etsr", j, u3, precision=_HI)
     return u3.reshape(e, -1)
 
 
@@ -873,9 +877,11 @@ def make_pmg_preconditioner(
             amat = amat + jnp.diag(1.0 - pc.mask.astype(amat.dtype))
         ainv = jnp.linalg.inv(amat)
         if pc.mask is None:
-            coarse_apply = lambda r: ainv @ r
+            coarse_apply = lambda r: jnp.dot(ainv, r, precision=_HI)
         else:
-            coarse_apply = lambda r: pc.mask * (ainv @ (pc.mask * r))
+            coarse_apply = lambda r: pc.mask * jnp.dot(
+                ainv, pc.mask * r, precision=_HI
+            )
     elif coarse_solve in ("chebyshev", "jacobi"):
         dinv_c = masked_dinv(pc, assembled_diagonal(pc))
         if coarse_solve == "chebyshev":

@@ -26,10 +26,9 @@ iterate may be NaN or garbage, so nothing is warm-started from it.
 callable — the sharded paths use it with `distributed.dist_cg`);
 `solve_with_fallback` is the single-device assembled-path convenience that
 rebuilds the preconditioner via `core.precond.make_preconditioner` at each
-rung.  The graceful-degradation guard for the *fused operator* lives at
-the kernel policy point instead (``kernels.ops.should_fuse_operator``
-probes the Pallas lowering once and falls back to the split pipeline on
-failure) — by the time a solve runs, the operator choice is already safe.
+rung.  The operator kernel is not part of the chain: the selection
+policy (``kernels.ops.should_fuse_operator``) never picks a kernel the
+backend cannot lower, and an explicit request for one raises.
 """
 from __future__ import annotations
 

@@ -45,6 +45,10 @@ import numpy as np
 from . import sem
 from .gather_scatter import gather_masked, scatter_masked
 
+
+# TPU's default f32 matmul is one bf16 pass; the solver needs full f32
+_HI = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "SCHWARZ_INNER_DEGREE",
     "SCHWARZ_WEIGHTINGS",
@@ -311,14 +315,14 @@ def fdm_solve(fdm: SchwarzFDM, u: jax.Array) -> jax.Array:
     cr, cs, ct = fdm.cmats[:, 0], fdm.cmats[:, 1], fdm.cmats[:, 2]
     u3 = u.reshape(e, m, m, m)
     # into the eigenbasis: Tᵀ along each direction
-    u3 = jnp.einsum("eai,etsa->etsi", tr, u3)
-    u3 = jnp.einsum("ebj,etbr->etjr", ts, u3)
-    u3 = jnp.einsum("eck,ecsr->eksr", tt, u3)
+    u3 = jnp.einsum("eai,etsa->etsi", tr, u3, precision=_HI)
+    u3 = jnp.einsum("ebj,etbr->etjr", ts, u3, precision=_HI)
+    u3 = jnp.einsum("eck,ecsr->eksr", tt, u3, precision=_HI)
 
     def hop(v: jax.Array) -> jax.Array:
-        cv = jnp.einsum("eai,etsi->etsa", cr, v)
-        cv = jnp.einsum("ebj,etjr->etbr", cs, cv)
-        cv = jnp.einsum("eck,eksr->ecsr", ct, cv)
+        cv = jnp.einsum("eai,etsi->etsa", cr, v, precision=_HI)
+        cv = jnp.einsum("ebj,etjr->etbr", cs, cv, precision=_HI)
+        cv = jnp.einsum("eck,eksr->ecsr", ct, cv, precision=_HI)
         return fdm.musum * v + fdm.lam * cv
 
     # the (E,1,1,1) per-element intervals broadcast through the shared
@@ -333,9 +337,9 @@ def fdm_solve(fdm: SchwarzFDM, u: jax.Array) -> jax.Array:
     z = solve(u3)
 
     # back out: T along each direction
-    z = jnp.einsum("eai,etsi->etsa", tr, z)
-    z = jnp.einsum("ebj,etjr->etbr", ts, z)
-    z = jnp.einsum("eck,eksr->ecsr", tt, z)
+    z = jnp.einsum("eai,etsi->etsa", tr, z, precision=_HI)
+    z = jnp.einsum("ebj,etjr->etbr", ts, z, precision=_HI)
+    z = jnp.einsum("eck,eksr->ecsr", tt, z, precision=_HI)
     return z.reshape(e, -1)
 
 

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .backend import pallas_call, resolve_interpret
+
 __all__ = ["block_matvec_pallas", "pick_block_matvec_e"]
 
 
@@ -37,6 +39,7 @@ def _kernel(b_ref, u_ref, out_ref):
     y = jax.lax.dot_general(
         b.astype(acc), u.astype(acc),
         (((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,  # TPU's default is one bf16 pass
         preferred_element_type=acc,
     )
     out_ref[...] = y.astype(out_ref.dtype)
@@ -64,17 +67,22 @@ def block_matvec_pallas(
     u: jax.Array,
     *,
     block_e: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """y[e] = blocks[e] @ u[e].  Shapes: (E, p, p), (E, p) -> (E, p).
 
     ``E`` must be a multiple of ``block_e`` (callers pad, see
-    ``kernels.ops.block_matvec``).
+    ``kernels.ops.block_matvec``).  ``interpret`` None resolves through
+    ``backend.default_interpret``.
     """
+    interpret = resolve_interpret(
+        interpret, blocks.dtype, u.dtype, kernel="block_matvec"
+    )
     e, p, _ = blocks.shape
-    assert e % block_e == 0, (e, block_e)
+    if e % block_e:
+        raise ValueError(f"E={e} not a multiple of block_e={block_e}")
     grid = (e // block_e,)
-    return pl.pallas_call(
+    return pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
@@ -84,4 +92,5 @@ def block_matvec_pallas(
         out_specs=pl.BlockSpec((block_e, p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((e, p), u.dtype),
         interpret=interpret,
+        name="block_matvec",
     )(blocks, u)

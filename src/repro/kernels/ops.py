@@ -1,29 +1,32 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles padding to tile shapes, the CPU/TPU interpret switch, and the
-reference fallback. Everything downstream (core.operator, core.cg,
+selection policies. Everything downstream (core.operator, core.cg,
 benchmarks) calls these, never pl.pallas_call directly.
 
-``interpret`` defaults to True off-TPU so the same code validates on CPU;
-on a real TPU backend it compiles via Mosaic.
+``interpret`` left as None resolves through ``default_interpret()``: the
+interpreter off-TPU, so the same code validates on CPU, and Mosaic on a
+real TPU backend.  Native kernels take no float64 (``backend``); the
+selection policies below never pick a kernel they cannot lower, and an
+explicit request for one raises instead of degrading.
 """
 from __future__ import annotations
 
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
 
-from . import ref
+from .backend import default_interpret, native_dtype_ok
 from .blocks import block_matvec_pallas, pick_block_matvec_e
 from .poisson import pick_block_e, poisson_local_pallas
 from .poisson_fused import (
-    fused_fits_vmem,
+    NO_NATIVE_LOWERING,
     pick_fused_block_e,
     poisson_assembled_fused_pallas,
 )
 from .streams import (
+    DEFAULT_BLOCK_ROWS,
     LANES,
     fused_axpy_dot_batched_pallas,
     fused_axpy_dot_pallas,
@@ -40,7 +43,6 @@ __all__ = [
     "fused_override",
     "should_fuse_streams",
     "should_fuse_operator",
-    "probe_fused_operator",
     "poisson_local",
     "poisson_assembled_fused",
     "make_poisson_assembled_fused",
@@ -61,11 +63,6 @@ __all__ = [
 ]
 
 
-def default_interpret() -> bool:
-    """Interpret Pallas kernels unless running on a real TPU."""
-    return jax.default_backend() != "tpu"
-
-
 def fused_override() -> bool | None:
     """The HIPBONE_FUSED env override shared by every auto-enable policy.
 
@@ -84,117 +81,36 @@ def should_fuse_streams(dtype) -> bool:
     """Auto-enable policy for the fused streaming stages in solver hot paths.
 
     True when Pallas compiles natively (non-interpret backend, i.e. real
-    TPU/GPU — interpret mode makes the fusions *slower* on CPU) AND the
+    TPU — interpret mode makes the fusions *slower* on CPU) AND the
     vectors the stage streams are fp32: the kernels' scalar reductions
     accumulate in fp32, which is exact enough for fp32 solves and for the
     fp32 interior of a mixed-precision preconditioner, but would throw away
-    bits an fp64 tol=1e-8 recurrence needs (and TPUs have no native fp64
-    regardless).  ``HIPBONE_FUSED`` (``fused_override``) wins over the auto
-    rule; callers keep an explicit opt-out knob on top of this.
+    bits an fp64 tol=1e-8 recurrence needs.  ``HIPBONE_FUSED``
+    (``fused_override``) wins over the auto rule, except that float64 is
+    never fused on a native backend (Mosaic has no float64); callers keep
+    an explicit opt-out knob on top of this.
     """
+    native = not default_interpret()
+    if native and not native_dtype_ok(dtype):
+        return False
     ov = fused_override()
     if ov is not None:
         return ov
-    return (not default_interpret()) and jnp.dtype(dtype) == jnp.float32
+    return native and jnp.dtype(dtype) == jnp.float32
 
 
-# probe_fused_operator state: verdict per (n_degree, n_global, dtype,
-# gather_mode) so the lowering attempt and its warning happen once per
-# shape.  _FUSED_PROBE_FAIL is the fault-injection hook
-# (repro.testing.faults.force_fused_failure) standing in for a real
-# Mosaic/VMEM failure, which needs TPU hardware to reproduce.
-_FUSED_PROBE_CACHE: dict[tuple, bool] = {}
-_FUSED_PROBE_FAIL = False
-
-
-def probe_fused_operator(
-    n_degree: int, n_global: int, dtype, *, gather_mode: str = "take"
-) -> bool:
-    """Can the fused assembled kernel actually lower for this shape?
-
-    ``should_fuse_operator``'s static policy (backend + VMEM model) can be
-    wrong on shapes the model was never calibrated for; a policy mistake
-    used to surface as a Pallas lowering / Mosaic VMEM-exhaustion crash in
-    the middle of the user's jit.  This probe attempts the lowering once
-    per shape on abstract operands (and, on a native backend, the Mosaic
-    compile — that is where VMEM overflows are raised), caches the
-    verdict, and turns a failure into a one-time warning + ``False`` so
-    callers degrade to the split scatter→local-op→gather pipeline instead
-    of crashing.
-    """
-    key = (int(n_degree), int(n_global), jnp.dtype(dtype).name, gather_mode)
-    cached = _FUSED_PROBE_CACHE.get(key)
-    if cached is not None:
-        return cached
-    n1 = n_degree + 1
-    p = n1**3
-    eb = max(1, pick_fused_block_e(n_degree, n_global, dtype))
-    try:
-        if _FUSED_PROBE_FAIL:
-            raise RuntimeError(
-                "forced fused-operator failure (repro.testing.faults)"
-            )
-        # one grid block's worth of elements exercises the kernel's full
-        # VMEM residency (field block + element streams)
-        args = (
-            jax.ShapeDtypeStruct((int(n_global),), jnp.dtype(dtype)),
-            jax.ShapeDtypeStruct((eb, p), jnp.int32),
-            jax.ShapeDtypeStruct((eb, 6, p), jnp.dtype(dtype)),
-            jax.ShapeDtypeStruct((eb, p), jnp.dtype(dtype)),
-            jax.ShapeDtypeStruct((n1, n1), jnp.dtype(dtype)),
-        )
-        fn = lambda x, l2g, g, w, d: poisson_assembled_fused(
-            x, l2g, g, w, d, lam=1.0, gather_mode=gather_mode
-        )
-        lowered = jax.jit(fn).lower(*args)
-        if not default_interpret():
-            lowered.compile()
-        ok = True
-    except Exception as exc:  # noqa: BLE001 — any lowering failure degrades
-        warnings.warn(
-            f"fused assembled operator failed to lower for N={n_degree}, "
-            f"n_global={n_global}, dtype={jnp.dtype(dtype).name} "
-            f"({type(exc).__name__}: {exc}); falling back to the split "
-            "scatter/local-op/gather pipeline for this shape",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        ok = False
-    _FUSED_PROBE_CACHE[key] = ok
-    return ok
-
-
-def should_fuse_operator(
-    dtype, *, n_degree: int | None = None, n_global: int | None = None
-) -> bool:
+def should_fuse_operator() -> bool:
     """Auto-enable policy for the single-kernel fused assembled operator.
 
-    True when Pallas compiles natively AND the resident x_G/y_G blocks fit
-    the fused kernel's VMEM budget (``fused_fits_vmem``); the split
-    scatter→local-op→gather path remains the fallback.  Unlike the stream
-    stages there is no dtype restriction — the kernel accumulates in
-    ``promote_types(dtype, f32)``, preserving fp64 semantics bit-for-bit at
-    the summation-order level.  ``HIPBONE_FUSED`` (``fused_override``)
-    forces the choice either way, including through interpret mode.
-
-    Graceful degradation: whenever the answer would be True and the shape
-    is known, ``probe_fused_operator`` verifies the kernel actually lowers
-    (cached, once per shape) — a lowering/VMEM failure demotes the answer
-    to False with a warning instead of crashing the solve, including under
-    ``HIPBONE_FUSED=1``.
+    Off unless ``HIPBONE_FUSED=1`` asks for it.  The rule: the kernel has
+    no native lowering (``kernels.poisson_fused``: Mosaic has no per-lane
+    VMEM gather/scatter), and through the interpreter it is slower than
+    XLA's split scatter→local-op→gather pipeline, so the split path is
+    the operator on every backend — float64 included.  An explicit
+    request on a native backend (``fused=True`` or ``HIPBONE_FUSED=1``)
+    reaches ``poisson_assembled_fused`` and raises there.
     """
-    ov = fused_override()
-    if ov is not None:
-        enable = ov
-    elif default_interpret():
-        return False  # interpret-mode gather/scatter is slower than XLA's
-    elif n_degree is not None and n_global is not None:
-        enable = fused_fits_vmem(n_degree, n_global, dtype)
-    else:
-        enable = True
-    if enable and n_degree is not None and n_global is not None:
-        enable = probe_fused_operator(n_degree, n_global, dtype)
-    return enable
+    return fused_override() is True
 
 
 def _pad_rows(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
@@ -216,7 +132,6 @@ def poisson_local(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused (S_L + λW) u with element padding. See kernels/poisson.py."""
-    interp = default_interpret() if interpret is None else interpret
     e = u.shape[0]
     n1 = d.shape[0]
     eb = block_e or pick_block_e(n1 - 1, u.dtype)
@@ -227,7 +142,7 @@ def poisson_local(
     g_p, _ = _pad_rows(g, eb)
     w_p, _ = _pad_rows(w, eb)
     out = poisson_local_pallas(
-        u_p, g_p, w_p, d, lam=lam, block_e=eb, interpret=interp
+        u_p, g_p, w_p, d, lam=lam, block_e=eb, interpret=interpret
     )
     return out[:e]
 
@@ -252,14 +167,12 @@ def poisson_assembled_fused(
     slices the result back to (n_global,).  Matches
     ``core.operator.poisson_assembled`` to summation-order round-off.
     """
-    interp = default_interpret() if interpret is None else interpret
     n_g = x_g.shape[0]
     e = l2g.shape[0]
     n1 = d.shape[0]
     eb = block_e or pick_fused_block_e(n1 - 1, n_g, x_g.dtype)
     eb = max(1, min(eb, max(e, 1)))
-    x_p, _ = _pad_vec(x_g, LANES)
-    x2 = x_p.reshape(-1, LANES)
+    x2 = _pad_to(x_g, -(-n_g // LANES) * LANES).reshape(-1, LANES)
     l2g_p, _ = _pad_rows(l2g.astype(jnp.int32), eb)
     g_p, _ = _pad_rows(g, eb)
     w_p, _ = _pad_rows(w, eb)
@@ -271,7 +184,7 @@ def poisson_assembled_fused(
         d,
         lam=float(lam),
         block_e=eb,
-        interpret=interp,
+        interpret=interpret,
         gather_mode=gather_mode,
     )
     return y2.reshape(-1)[:n_g]
@@ -299,6 +212,8 @@ def make_poisson_assembled_fused(
     """
     from ..core.operator import screen_stream  # lazy: core imports kernels
 
+    if not (default_interpret() if interpret is None else interpret):
+        raise NotImplementedError(NO_NATIVE_LOWERING)
     w_eff, lam_eff = screen_stream(prob)
     mask = prob.mask
 
@@ -335,13 +250,12 @@ def block_matvec(
     (``core.galerkin.block_matvec_einsum`` is the XLA reference); see
     kernels/blocks.py.  Shapes: (E, p, p), (E, p) -> (E, p).
     """
-    interp = default_interpret() if interpret is None else interpret
     e, p = u.shape
     eb = block_e or pick_block_matvec_e(p, u.dtype)
     eb = max(1, min(eb, e))
     b_p, _ = _pad_rows(blocks, eb)
     u_p, _ = _pad_rows(u, eb)
-    out = block_matvec_pallas(b_p, u_p, block_e=eb, interpret=interp)
+    out = block_matvec_pallas(b_p, u_p, block_e=eb, interpret=interpret)
     return out[:e]
 
 
@@ -352,33 +266,41 @@ def make_block_matvec(*, block_e: int | None = None, interpret: bool | None = No
     )
 
 
-def _pad_vec(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
-    n = x.size
-    pad = (-n) % multiple
+def stream_tiling(n: int, want: int = DEFAULT_BLOCK_ROWS) -> tuple[int, int]:
+    """(padded length, block rows) for streaming an n-vector as (rows, 128).
+
+    Mosaic tiles f32 as (8, 128), so a block's row count must be a
+    multiple of 8.  The rows split into ceil(rows / want) equal blocks,
+    each rounded up to 8 rows: the padding stays under 8 rows per block
+    instead of falling to 1-row blocks when the row count has no suitable
+    divisor (57³ DOFs -> 1447 rows -> 3 blocks of 488).
+    """
+    rows = max(-(-n // LANES), 1)
+    nblk = -(-rows // want)
+    br = -(-rows // nblk)
+    br += (-br) % 8
+    return nblk * br * LANES, br
+
+
+def _pad_to(x: jax.Array, size: int) -> jax.Array:
+    """Zero-pad the trailing axis of x to ``size``."""
+    pad = size - x.shape[-1]
     if pad:
-        x = jnp.concatenate([x.reshape(-1), jnp.zeros((pad,), x.dtype)])
-    return x.reshape(-1), n
-
-
-def _stream_block_rows(padded_size: int, want: int = 512) -> int:
-    rows = padded_size // LANES
-    br = min(want, rows)
-    while rows % br:
-        br -= 1
-    return br
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (pad,), x.dtype)], axis=-1
+        )
+    return x
 
 
 def fused_axpy_dot(
     r: jax.Array, ap: jax.Array, alpha: jax.Array, *, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """One-pass (r - α·Ap, ||r - α·Ap||²) for arbitrary-length vectors."""
-    interp = default_interpret() if interpret is None else interpret
-    shape = r.shape
-    r_p, n = _pad_vec(r, LANES)
-    ap_p, _ = _pad_vec(ap, LANES)
-    br = _stream_block_rows(r_p.size)
+    shape, n = r.shape, r.size
+    size, br = stream_tiling(n)
     r_new, rr = fused_axpy_dot_pallas(
-        r_p, ap_p, alpha, block_rows=br, interpret=interp
+        _pad_to(r.reshape(-1), size), _pad_to(ap.reshape(-1), size), alpha,
+        block_rows=br, interpret=interpret,
     )
     # padded tail contributes alpha*0 - 0 = 0 to both outputs
     return r_new[:n].reshape(shape), rr
@@ -387,37 +309,34 @@ def fused_axpy_dot(
 def fused_xpay(
     r: jax.Array, p: jax.Array, beta: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
-    interp = default_interpret() if interpret is None else interpret
-    shape = r.shape
-    r_p, n = _pad_vec(r, LANES)
-    p_p, _ = _pad_vec(p, LANES)
-    br = _stream_block_rows(r_p.size)
-    out = fused_xpay_pallas(r_p, p_p, beta, block_rows=br, interpret=interp)
+    shape, n = r.shape, r.size
+    size, br = stream_tiling(n)
+    out = fused_xpay_pallas(
+        _pad_to(r.reshape(-1), size), _pad_to(p.reshape(-1), size), beta,
+        block_rows=br, interpret=interpret,
+    )
     return out[:n].reshape(shape)
 
 
 def weighted_dot(
     w: jax.Array, a: jax.Array, b: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
-    interp = default_interpret() if interpret is None else interpret
-    w_p, _ = _pad_vec(w, LANES)
-    a_p, _ = _pad_vec(a, LANES)
-    b_p, _ = _pad_vec(b, LANES)
-    br = _stream_block_rows(w_p.size)
-    return weighted_dot_pallas(w_p, a_p, b_p, block_rows=br, interpret=interp)
+    size, br = stream_tiling(w.size)
+    w_p, a_p, b_p = (_pad_to(x.reshape(-1), size) for x in (w, a, b))
+    return weighted_dot_pallas(w_p, a_p, b_p, block_rows=br, interpret=interpret)
 
 
 def fused_jacobi_dot(
     dinv: jax.Array, r: jax.Array, *, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """One-pass (D⁻¹r, r·D⁻¹r) for arbitrary-length vectors (PCG z-stage)."""
-    interp = default_interpret() if interpret is None else interpret
-    shape = r.shape
-    d_p, n = _pad_vec(dinv, LANES)
-    r_p, _ = _pad_vec(r, LANES)
-    br = _stream_block_rows(r_p.size)
+    shape, n = r.shape, r.size
+    size, br = stream_tiling(n)
     # padded tail: dinv pad is 0 so z and the r·z partials stay 0 there
-    z, rz = fused_jacobi_dot_pallas(d_p, r_p, block_rows=br, interpret=interp)
+    z, rz = fused_jacobi_dot_pallas(
+        _pad_to(dinv.reshape(-1), size), _pad_to(r.reshape(-1), size),
+        block_rows=br, interpret=interpret,
+    )
     return z[:n].reshape(shape), rz
 
 
@@ -430,24 +349,13 @@ def fused_cheb_d_update(
     interpret: bool | None = None,
 ) -> jax.Array:
     """d ← a·d + c·r for arbitrary-length vectors (Chebyshev d-update)."""
-    interp = default_interpret() if interpret is None else interpret
-    shape = d.shape
-    d_p, n = _pad_vec(d, LANES)
-    r_p, _ = _pad_vec(r, LANES)
-    br = _stream_block_rows(d_p.size)
-    out = fused_cheb_d_update_pallas(a, c, d_p, r_p, block_rows=br, interpret=interp)
+    shape, n = d.shape, d.size
+    size, br = stream_tiling(n)
+    out = fused_cheb_d_update_pallas(
+        a, c, _pad_to(d.reshape(-1), size), _pad_to(r.reshape(-1), size),
+        block_rows=br, interpret=interpret,
+    )
     return out[:n].reshape(shape)
-
-
-def _pad_block(x: jax.Array, multiple: int) -> tuple[jax.Array, int]:
-    """Pad the trailing axis of a (B, n) block to a multiple of ``multiple``."""
-    n = x.shape[-1]
-    pad = (-n) % multiple
-    if pad:
-        x = jnp.concatenate(
-            [x, jnp.zeros(x.shape[:-1] + (pad,), x.dtype)], axis=-1
-        )
-    return x, n
 
 
 def fused_axpy_dot_batched(
@@ -458,29 +366,27 @@ def fused_axpy_dot_batched(
     ``alpha`` is (B,) — each solve column advances by its own CG step.
     Returns the updated (B, n) block and the (B,) squared norms.
     """
-    interp = default_interpret() if interpret is None else interpret
-    shape = r.shape
-    r_p, n = _pad_block(r, LANES)
-    ap_p, _ = _pad_block(ap, LANES)
-    br = _stream_block_rows(r_p.shape[-1])
+    n = r.shape[-1]
+    size, br = stream_tiling(n)
     # padded tail contributes alpha*0 - 0 = 0 to both outputs
     r_new, rr = fused_axpy_dot_batched_pallas(
-        r_p, ap_p, alpha, block_rows=br, interpret=interp
+        _pad_to(r, size), _pad_to(ap, size), alpha,
+        block_rows=br, interpret=interpret,
     )
-    return r_new[:, :n].reshape(shape), rr
+    return r_new[:, :n], rr
 
 
 def fused_xpay_batched(
     r: jax.Array, p: jax.Array, beta: jax.Array, *, interpret: bool | None = None
 ) -> jax.Array:
     """Per-column r + β·p over a (B, n) block; ``beta`` is (B,)."""
-    interp = default_interpret() if interpret is None else interpret
-    shape = r.shape
-    r_p, n = _pad_block(r, LANES)
-    p_p, _ = _pad_block(p, LANES)
-    br = _stream_block_rows(r_p.shape[-1])
-    out = fused_xpay_batched_pallas(r_p, p_p, beta, block_rows=br, interpret=interp)
-    return out[:, :n].reshape(shape)
+    n = r.shape[-1]
+    size, br = stream_tiling(n)
+    out = fused_xpay_batched_pallas(
+        _pad_to(r, size), _pad_to(p, size), beta,
+        block_rows=br, interpret=interpret,
+    )
+    return out[:, :n]
 
 
 def fused_jacobi_dot_batched(
@@ -491,16 +397,14 @@ def fused_jacobi_dot_batched(
     ``dinv`` stays (n,) — the diagonal stream is shared by every column,
     never replicated B-fold through memory.
     """
-    interp = default_interpret() if interpret is None else interpret
-    shape = r.shape
-    d_p, n = _pad_vec(dinv, LANES)
-    r_p, _ = _pad_block(r, LANES)
-    br = _stream_block_rows(r_p.shape[-1])
+    n = r.shape[-1]
+    size, br = stream_tiling(n)
     # padded tail: dinv pad is 0 so z and the r·z partials stay 0 there
     z, rz = fused_jacobi_dot_batched_pallas(
-        d_p, r_p, block_rows=br, interpret=interp
+        _pad_to(dinv, size), _pad_to(r, size),
+        block_rows=br, interpret=interpret,
     )
-    return z[:, :n].reshape(shape), rz
+    return z[:, :n], rz
 
 
 def make_fused_jacobi_dot_batched(

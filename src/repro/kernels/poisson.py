@@ -7,13 +7,24 @@ TPU adaptation of the paper's CUDA/HIP operator kernel (DESIGN.md §3):
   per block to avoid masked lanes.
 * TPU version: grid over *blocks of elements*; each grid step streams a
   (block_e, p) tile of DOFs plus its (block_e, 6, p) geometric factors and
-  (block_e, p) weights HBM->VMEM, performs the three tensor-product
-  contractions as element-batched ``dot_general``s (element batch folded
-  into the matmul M dimension so the MXU sees tall-skinny matmuls instead
-  of (N+1)x(N+1) crumbs), and writes the single output tile. The kernel is
-  a single pass over all seven input streams — the paper's "perfect
-  caching" traffic bound  word*N_G + (4 + 8*word)*N_L  is met by
+  (block_e, p) weights HBM->VMEM and writes the single output tile. The
+  kernel is a single pass over all seven input streams — the paper's
+  "perfect caching" traffic bound  word*N_G + (4 + 8*word)*N_L  is met by
   construction, because nothing is re-read.
+* The element's p = (N+1)^3 nodes (order t, s, r; r fastest) stay on the
+  lane axis: Mosaic cannot split the lane axis into (t, s, r), so the
+  tensor-product derivatives are applied as 2-D matmuls against Kronecker
+  factors of the 1-D derivative matrix D (``derivative_factors``):
+    - p <= 512 (N <= 7): the whole element is one row; the gradient is
+      u @ [Dr^T | Ds^T | Dt^T] with Dr = I⊗I⊗D, Ds = I⊗D⊗I, Dt = D⊗I⊗I,
+      and the divergence contracts with the same factors transposed;
+    - larger p (N = 15): the row splits into n1 lane-aligned chunks of
+      n1^2 nodes (one t-plane each); r and s derivatives are matmuls of
+      each chunk with the n1^2-wide factors I⊗D and D⊗I, and the t
+      derivative is a D-weighted sum of chunks on the VPU (D read as SMEM
+      scalars).  A p x p factor at N=15 would take 64 MB.
+  The Kronecker form spends more MXU FLOPs than the (N+1)-wide
+  contractions; it is what lowers without lane reshapes.
 * The GPU occupancy knob (registers/warp) becomes the VMEM-footprint knob
   ``block_e``, swept in benchmarks/table1_blocks.py.
 
@@ -28,87 +39,182 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import pallas_call, resolve_interpret
 
 __all__ = [
     "poisson_local_pallas",
     "local_body",
+    "body_scratch",
+    "derivative_factors",
     "vmem_bytes_per_block",
     "pick_block_e",
+    "KERNEL_VMEM_BUDGET",
+    "KERNEL_VMEM_LIMIT",
 ]
 
+# whole-element rows up to this many nodes (N <= 7); chunked rows above
+FULL_ROW_MAX_P = 512
+# block_e is sized to this working set; Mosaic's scoped VMEM limit is
+# raised to KERNEL_VMEM_LIMIT (v5e has 128 MiB of VMEM per core)
+KERNEL_VMEM_BUDGET = 24 * 2**20
+KERNEL_VMEM_LIMIT = 64 * 2**20
 
-def local_body(u, g, w, d, *, lam: float, n1: int):
-    """The three-contraction MXU body: (S_L + λW) u for one element block.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _chunk(n1: int) -> int:
+    """Lane width of one row chunk: p for whole-element rows, else n1^2."""
+    p = n1**3
+    return p if p <= FULL_ROW_MAX_P else n1 * n1
+
+
+def derivative_factors(d: jax.Array, dtype=None) -> jax.Array:
+    """Kronecker gradient factors, (c, k·c) for chunk width c.
+
+    Whole-element rows (p <= 512): ``[Dr^T | Ds^T | Dt^T]`` with the three
+    p x p operators Dr = I⊗I⊗D, Ds = I⊗D⊗I, Dt = D⊗I⊗I.  Chunked rows:
+    ``[(I⊗D)^T | (D⊗I)^T]`` over one t-plane of n1^2 nodes.  Entries are
+    products with 0/1, so the factors are exact copies of D's entries.
+    """
+    n1 = d.shape[0]
+    dd = d.astype(dtype or jnp.promote_types(d.dtype, jnp.float32))
+    eye = jnp.eye(n1, dtype=dd.dtype)
+    if _chunk(n1) == n1**3:
+        ops_ = [
+            jnp.kron(jnp.kron(eye, eye), dd),
+            jnp.kron(jnp.kron(eye, dd), eye),
+            jnp.kron(jnp.kron(dd, eye), eye),
+        ]
+    else:
+        ops_ = [jnp.kron(eye, dd), jnp.kron(dd, eye)]
+    return jnp.concatenate([o.T for o in ops_], axis=1)
+
+
+def _mm(a, b, acc):
+    """a @ b at full precision (TPU's default f32 matmul is one bf16 pass)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=acc,
+    )
+
+
+def _mm_t(a, b, acc):
+    """a @ b^T at full precision."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=acc,
+    )
+
+
+def _metric(g, ur, us, ut):
+    """(wr, ws, wt) = G (ur, us, ut) for the packed symmetric 3x3 metric."""
+    wr = g[0] * ur + g[1] * us + g[2] * ut
+    ws = g[1] * ur + g[3] * us + g[4] * ut
+    wt = g[2] * ur + g[4] * us + g[5] * ut
+    return wr, ws, wt
+
+
+def local_body(u_ref, g_ref, w_ref, kf_ref, d_ref, y_ref, ut_ref, wt_ref, *, lam):
+    """y_ref <- (S_L + λW) u for one element block resident in VMEM.
 
     Shared between the element-local kernel below and the single-pass fused
-    assembled kernel (kernels/poisson_fused.py). Pure function of VMEM-
-    resident values; returns the (Eb, p) result in the accumulation dtype
-    (``promote_types(u.dtype, f32)`` — fp64 inputs accumulate in fp64).
+    assembled kernel (kernels/poisson_fused.py).  Operands are refs so the
+    chunked form streams one lane-aligned t-plane at a time instead of
+    keeping every plane live (which spills and slows Mosaic's compile).
+
+    Args:
+      u_ref: (Eb, p) element DOFs.
+      g_ref: (Eb, 6, p) packed geometric factors.
+      w_ref: (Eb, p) screen weights.
+      kf_ref: ``derivative_factors``.
+      d_ref: (n1, n1) derivative matrix in SMEM (chunked rows only).
+      y_ref, ut_ref, wt_ref: (Eb, p) scratch in the accumulation dtype
+        (``promote_types(u.dtype, f32)`` — fp64 inputs accumulate in
+        fp64); y_ref receives the result, ut/wt_ref serve chunked rows.
     """
-    eb, p = u.shape
-    f32 = jnp.float32
-    acc = jnp.promote_types(u.dtype, f32)
+    eb, p = u_ref.shape
+    acc = y_ref.dtype
+    c = kf_ref.shape[0]
+    if c == p:
+        u = u_ref[...].astype(acc)
+        grads = [_mm(u, kf_ref[:, j * p:(j + 1) * p], acc) for j in range(3)]
+        g = [g_ref[:, k, :].astype(acc) for k in range(6)]
+        fluxes = _metric(g, *grads)
+        out = lam * (w_ref[...].astype(acc) * u)
+        for j in range(3):
+            out = out + _mm_t(fluxes[j], kf_ref[:, j * p:(j + 1) * p], acc)
+        y_ref[...] = out
+        return
 
-    u3 = u.reshape(eb, n1, n1, n1).astype(acc)
-    dd = d.astype(acc)
-
-    # --- gradient: three element-batched contractions --------------------
-    # r-derivative: fold (e, t, s) into M -> (M, n1) @ (n1, n1)^T, MXU-shaped.
-    ur = jax.lax.dot_general(
-        u3.reshape(eb * n1 * n1, n1), dd,
-        ((((1,), (1,)), ((), ()))),
-        preferred_element_type=acc,
-    ).reshape(eb, n1, n1, n1)
-    # s-derivative: contract the middle axis; einsum lowers to
-    # dot_general + layout change, which Mosaic pipelines with the matmul.
-    us = jnp.einsum("jb,etbr->etjr", dd, u3, preferred_element_type=acc)
-    # t-derivative
-    ut = jnp.einsum("kc,ecsr->eksr", dd, u3, preferred_element_type=acc)
-
-    # --- metric: 15 (N+1)^3 FLOPs/elt, pure VPU ---------------------------
-    g3 = g.reshape(eb, 6, n1, n1, n1).astype(acc)
-    wr = g3[:, 0] * ur + g3[:, 1] * us + g3[:, 2] * ut
-    ws = g3[:, 1] * ur + g3[:, 3] * us + g3[:, 4] * ut
-    wt = g3[:, 2] * ur + g3[:, 4] * us + g3[:, 5] * ut
-
-    # --- divergence: transposed contractions ------------------------------
-    out = jax.lax.dot_general(
-        wr.reshape(eb * n1 * n1, n1), dd,
-        ((((1,), (0,)), ((), ()))),
-        preferred_element_type=acc,
-    ).reshape(eb, n1, n1, n1)
-    out = out + jnp.einsum("jb,etjr->etbr", dd, ws, preferred_element_type=acc)
-    out = out + jnp.einsum("kc,eksr->ecsr", dd, wt, preferred_element_type=acc)
-
-    # --- fused screen λW --------------------------------------------------
-    return out.reshape(eb, p) + lam * (w.astype(acc) * u.astype(acc))
+    n1 = p // c
+    kr, ks = kf_ref[:, 0:c], kf_ref[:, c:2 * c]
+    planes = [slice(t * c, (t + 1) * c) for t in range(n1)]
+    chunk = lambda ref, t: ref[:, planes[t]].astype(acc)
+    # t-derivative: ut_k = sum_c D[k, c] u_c across lane-aligned t-planes
+    for k in range(n1):
+        ut_k = d_ref[k, 0].astype(acc) * chunk(u_ref, 0)
+        for cc in range(1, n1):
+            ut_k = ut_k + d_ref[k, cc].astype(acc) * chunk(u_ref, cc)
+        ut_ref[:, planes[k]] = ut_k
+    for t in range(n1):
+        u_t = chunk(u_ref, t)
+        g = [g_ref[:, k, planes[t]].astype(acc) for k in range(6)]
+        wr, ws, wt = _metric(
+            g, _mm(u_t, kr, acc), _mm(u_t, ks, acc), ut_ref[:, planes[t]]
+        )
+        wt_ref[:, planes[t]] = wt
+        y_ref[:, planes[t]] = (
+            _mm_t(wr, kr, acc) + _mm_t(ws, ks, acc)
+            + lam * (chunk(w_ref, t) * u_t)
+        )
+    # t-divergence: out_t += sum_k D[k, t] wt_k
+    for t in range(n1):
+        out_t = y_ref[:, planes[t]]
+        for k in range(n1):
+            out_t = out_t + d_ref[k, t].astype(acc) * wt_ref[:, planes[k]]
+        y_ref[:, planes[t]] = out_t
 
 
-def _kernel(u_ref, g_ref, w_ref, d_ref, out_ref, *, lam: float, n1: int):
+def body_scratch(block_e: int, p: int, dtype) -> list:
+    """The three (block_e, p) accumulation-dtype scratch refs of local_body."""
+    acc = jnp.promote_types(jnp.dtype(dtype), jnp.float32)
+    return [pltpu.VMEM((block_e, p), acc) for _ in range(3)]
+
+
+def _kernel(u_ref, g_ref, w_ref, kf_ref, d_ref, out_ref, *scratch, lam: float):
     """One grid step: apply (S_L + λW) to block_e elements resident in VMEM."""
-    out = local_body(
-        u_ref[...], g_ref[...], w_ref[...], d_ref[...], lam=lam, n1=n1
-    )
-    out_ref[...] = out.astype(out_ref.dtype)
+    local_body(u_ref, g_ref, w_ref, kf_ref, d_ref, *scratch, lam=lam)
+    out_ref[...] = scratch[0][...].astype(out_ref.dtype)
 
 
 def vmem_bytes_per_block(block_e: int, n1: int, dtype=jnp.float32) -> int:
-    """Estimated VMEM working set of one grid step (inputs+outputs+temps)."""
+    """Estimated VMEM working set of one grid step.
+
+    Double-buffered input/output tiles (u, w, out and the 6-plane G block,
+    whose 6 sublanes pad to 8), the resident derivative factors (also
+    double-buffered), the three scratch planes of ``local_body`` and the
+    f32 temporaries (u, the three gradients, the three fluxes, out).
+    """
     p = n1**3
     word = jnp.dtype(dtype).itemsize
-    io = block_e * p * (1 + 6 + 1 + 1) * word        # u, G, w, out tiles
-    tmp = block_e * p * 6 * 4                        # ur/us/ut + wr/ws/wt (f32)
-    return io + tmp
+    acc = jnp.promote_types(jnp.dtype(dtype), jnp.float32).itemsize
+    c = _chunk(n1)
+    io = 2 * block_e * p * (3 + 8) * word
+    factors = 2 * c * (3 * c if c == p else 2 * c) * acc
+    tmp = block_e * p * (3 + 8) * acc
+    return io + factors + tmp
 
 
 def pick_block_e(
-    n_degree: int, dtype=jnp.float32, budget_bytes: int = 4 * 2**20
+    n_degree: int, dtype=jnp.float32, budget_bytes: int = KERNEL_VMEM_BUDGET
 ) -> int:
     """Largest power-of-two element block whose working set fits the budget.
 
-    The 4 MB default leaves VMEM room for double-buffered pipelining
-    (Mosaic overlaps the next tile's HBM->VMEM DMA with current compute,
-    the TPU analogue of the paper's >1 waves/CU occupancy goal).
+    The budget leaves VMEM room for double-buffered pipelining (Mosaic
+    overlaps the next tile's HBM->VMEM DMA with current compute, the TPU
+    analogue of the paper's >1 waves/CU occupancy goal).
     """
     n1 = n_degree + 1
     eb = 256
@@ -129,7 +235,7 @@ def poisson_local_pallas(
     *,
     lam: float,
     block_e: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fused (S_L + λW) u for element-blocked tiles.
 
@@ -141,11 +247,15 @@ def poisson_local_pallas(
       d: (n1, n1) derivative matrix.
       lam: screen parameter (static).
       block_e: elements per grid step; default via pick_block_e.
-      interpret: run the kernel body in interpret mode (CPU validation).
+      interpret: run the kernel body in interpret mode (CPU validation);
+        None resolves through ``backend.default_interpret``.
 
     Returns:
       (E, p) y_L.
     """
+    interpret = resolve_interpret(
+        interpret, u.dtype, g.dtype, w.dtype, d.dtype, kernel="poisson_local"
+    )
     e, p = u.shape
     n1 = d.shape[0]
     if n1**3 != p:
@@ -154,18 +264,22 @@ def poisson_local_pallas(
     eb = min(eb, e)
     if e % eb:
         raise ValueError(f"E={e} not a multiple of block_e={eb}; use ops.poisson_local")
-    grid = (e // eb,)
+    kf = derivative_factors(d)
 
-    return pl.pallas_call(
-        functools.partial(_kernel, lam=lam, n1=n1),
-        grid=grid,
+    return pallas_call(
+        functools.partial(_kernel, lam=lam),
+        grid=(e // eb,),
         in_specs=[
             pl.BlockSpec((eb, p), lambda i: (i, 0)),
             pl.BlockSpec((eb, 6, p), lambda i: (i, 0, 0)),
             pl.BlockSpec((eb, p), lambda i: (i, 0)),
-            pl.BlockSpec((n1, n1), lambda i: (0, 0)),
+            pl.BlockSpec(kf.shape, lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((eb, p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((e, p), u.dtype),
+        scratch_shapes=body_scratch(eb, p, u.dtype),
         interpret=interpret,
-    )(u, g, w, d)
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=KERNEL_VMEM_LIMIT),
+        name="poisson_local",
+    )(u, g, w, kf, d.astype(kf.dtype))

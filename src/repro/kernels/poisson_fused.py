@@ -18,22 +18,27 @@ exactly once per CG iteration and y_L never exists:
     per step) — TPU grids are serialized, so the accumulation is
     deterministic without atomics.
 
+Native lowering: none.  Mosaic has no per-lane VMEM gather
+(``jnp.take`` -> "Only 2D gather is supported") and refuses the scatter
+behind the loop form's ``.at[k].set``; scalar-prefetching the whole l2g
+map would also overflow SMEM at deployment sizes.  The kernel therefore
+runs only through the Pallas interpreter, ``ops.should_fuse_operator``
+never selects it on a native backend, and asking for it there raises
+(``poisson_assembled_fused_pallas``).
+
 Two gather/scatter strategies, selected by ``gather_mode``:
 
   * ``"take"`` (default): vectorized ``jnp.take`` / ``.at[].add`` on the
     VMEM-resident x_G/y_G blocks — the fast path wherever the backend
-    supports lane gather (and the interpret path CI validates on CPU).
+    supports lane gather (the interpret path CI validates on CPU).
   * ``"loop"``: the l2g map rides a ``PrefetchScalarGridSpec`` scalar-
     prefetch argument (SMEM), and gather/scatter run as a serial
-    ``fori_loop`` of single-node dynamic slices — the fallback for Mosaic
-    versions without per-lane VMEM gather. Slow but bit-compatible up to
-    summation order; duplicates within a block are handled by the serial
-    read-modify-write.
+    ``fori_loop`` of single-node dynamic slices. Slow but bit-compatible
+    up to summation order; duplicates within a block are handled by the
+    serial read-modify-write.
 
-VMEM budget: unlike the element-local kernel, x_G and y_G are resident, so
-``fused_fits_vmem`` gates the auto-enable policy (``ops.should_fuse_operator``)
-and the split path remains the fallback for global vectors too large to
-pin. Padding (elements to block_e, DOFs to the 128-lane tile) is handled by
+VMEM budget: unlike the element-local kernel, x_G and y_G are resident;
+``pick_fused_block_e`` sizes the element block around them. Padding (elements to block_e, DOFs to the 128-lane tile) is handled by
 ``ops.poisson_assembled_fused``; padded elements carry zero G/W so they
 contribute exactly 0.0 wherever their dummy index points.
 """
@@ -46,17 +51,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .poisson import local_body, pick_block_e
+from .backend import pallas_call, resolve_interpret
+from .poisson import body_scratch, derivative_factors, local_body, pick_block_e
 from .streams import LANES
 
 __all__ = [
     "poisson_assembled_fused_pallas",
     "fused_vmem_bytes",
-    "fused_fits_vmem",
     "pick_fused_block_e",
 ]
 
 FUSED_VMEM_BUDGET = 8 * 2**20
+NO_NATIVE_LOWERING = (
+    "the fused assembled operator has no native Pallas lowering (Mosaic has "
+    "no per-lane VMEM gather/scatter); use the split operator "
+    "(poisson_assembled(..., fused=False), optionally with "
+    "kernels.ops.make_local_op()) on this backend"
+)
 
 
 def fused_vmem_bytes(block_e: int, n1: int, n_pad: int, dtype=jnp.float32) -> int:
@@ -68,17 +79,6 @@ def fused_vmem_bytes(block_e: int, n1: int, n_pad: int, dtype=jnp.float32) -> in
     tiles = block_e * p * (4 + 7 * word)  # l2g (int32) + 6 G planes + W
     temps = block_e * p * 8 * acc  # u, ur/us/ut, wr/ws/wt, out
     return resident + tiles + temps
-
-
-def fused_fits_vmem(
-    n_degree: int,
-    n_global: int,
-    dtype=jnp.float32,
-    budget_bytes: int = FUSED_VMEM_BUDGET,
-) -> bool:
-    """True when the single-kernel form fits the VMEM budget at block_e=1."""
-    n_pad = -(-max(n_global, 1) // LANES) * LANES
-    return fused_vmem_bytes(1, n_degree + 1, n_pad, dtype) <= budget_bytes
 
 
 def pick_fused_block_e(
@@ -96,7 +96,9 @@ def pick_fused_block_e(
     return eb
 
 
-def _kernel_take(idx_ref, x_ref, g_ref, w_ref, d_ref, y_ref, *, lam, n1):
+def _kernel_take(
+    idx_ref, x_ref, g_ref, w_ref, kf_ref, d_ref, y_ref, u_scr, *scratch, lam
+):
     """One grid step, vector gather/scatter on the resident x/y blocks."""
     i = pl.program_id(0)
 
@@ -107,8 +109,9 @@ def _kernel_take(idx_ref, x_ref, g_ref, w_ref, d_ref, y_ref, *, lam, n1):
     idx = idx_ref[...].reshape(-1)  # (Eb*p,) int32
     x = x_ref[...].reshape(-1)  # (rows*128,) resident x_G
     eb, p = idx_ref.shape
-    u = jnp.take(x, idx, axis=0).reshape(eb, p)  # gather Z x_G
-    y_l = local_body(u, g_ref[...], w_ref[...], d_ref[...], lam=lam, n1=n1)
+    u_scr[...] = jnp.take(x, idx, axis=0).reshape(eb, p)  # gather Z x_G
+    local_body(u_scr, g_ref, w_ref, kf_ref, d_ref, *scratch, lam=lam)
+    y_l = scratch[0][...]
     # scatter-add Z^T into the revisited y_G block; duplicate indices within
     # the tile accumulate correctly through the segment-style .at[].add
     delta = jnp.zeros(x.shape, y_ref.dtype).at[idx].add(
@@ -117,7 +120,9 @@ def _kernel_take(idx_ref, x_ref, g_ref, w_ref, d_ref, y_ref, *, lam, n1):
     y_ref[...] += delta.reshape(y_ref.shape)
 
 
-def _kernel_loop(idx_ref, x_ref, g_ref, w_ref, d_ref, y_ref, *, lam, n1):
+def _kernel_loop(
+    idx_ref, x_ref, g_ref, w_ref, kf_ref, d_ref, y_ref, u_scr, *scratch, lam
+):
     """One grid step, serial dynamic-slice gather/scatter (no lane gather).
 
     ``idx_ref`` is the scalar-prefetched full (E_pad*p,) l2g map in SMEM.
@@ -137,11 +142,11 @@ def _kernel_loop(idx_ref, x_ref, g_ref, w_ref, d_ref, y_ref, *, lam, n1):
         val = x_ref[node // LANES, node % LANES]
         return u_flat.at[k].set(val)
 
-    u = jax.lax.fori_loop(
+    u_scr[...] = jax.lax.fori_loop(
         0, total, gather_one, jnp.zeros((total,), x_ref.dtype)
     ).reshape(eb, p)
-    y_l = local_body(u, g_ref[...], w_ref[...], d_ref[...], lam=lam, n1=n1)
-    y_flat = y_l.reshape(-1).astype(y_ref.dtype)
+    local_body(u_scr, g_ref, w_ref, kf_ref, d_ref, *scratch, lam=lam)
+    y_flat = scratch[0][...].reshape(-1).astype(y_ref.dtype)
 
     def scatter_one(k, carry):
         node = idx_ref[base + k]
@@ -165,7 +170,7 @@ def poisson_assembled_fused_pallas(
     *,
     lam: float,
     block_e: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
     gather_mode: str = "take",
 ) -> jax.Array:
     """Single-kernel y_G = Z^T (S_L + λW) Z x_G on pre-padded operands.
@@ -177,13 +182,21 @@ def poisson_assembled_fused_pallas(
         padded elements at slot 0 — their zero G/W makes that a no-op).
       g / w / d / lam: as in kernels/poisson.py.
       block_e: elements per grid step (pick_fused_block_e).
-      interpret: run via the Pallas interpreter (CPU validation path).
+      interpret: run via the Pallas interpreter, the only path this kernel
+        has (None resolves through ``backend.default_interpret``; a native
+        backend raises NotImplementedError).
       gather_mode: "take" (vector lane gather) or "loop" (scalar-prefetch +
         dynamic-slice fallback).
 
     Returns:
       (rows, 128) lane-tiled padded y_G.
     """
+    interpret = resolve_interpret(
+        interpret, x2.dtype, g.dtype, w.dtype, d.dtype,
+        kernel="poisson_assembled_fused",
+    )
+    if not interpret:
+        raise NotImplementedError(NO_NATIVE_LOWERING)
     e, p = l2g.shape
     n1 = d.shape[0]
     if n1**3 != p:
@@ -196,23 +209,30 @@ def poisson_assembled_fused_pallas(
     rows = x2.shape[0]
     grid = (e // block_e,)
     out_shape = jax.ShapeDtypeStruct((rows, LANES), x2.dtype)
+    kf = derivative_factors(d)
     data_specs = [
         pl.BlockSpec((rows, LANES), lambda i: (0, 0)),  # x_G, resident
         pl.BlockSpec((block_e, 6, p), lambda i: (i, 0, 0)),
         pl.BlockSpec((block_e, p), lambda i: (i, 0)),
-        pl.BlockSpec((n1, n1), lambda i: (0, 0)),
+        pl.BlockSpec(kf.shape, lambda i: (0, 0)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     out_spec = pl.BlockSpec((rows, LANES), lambda i: (0, 0))  # revisited acc
+    scratch = [pltpu.VMEM((block_e, p), x2.dtype)] + body_scratch(
+        block_e, p, x2.dtype
+    )
 
     if gather_mode == "take":
-        return pl.pallas_call(
-            functools.partial(_kernel_take, lam=lam, n1=n1),
+        return pallas_call(
+            functools.partial(_kernel_take, lam=lam),
             grid=grid,
             in_specs=[pl.BlockSpec((block_e, p), lambda i: (i, 0))] + data_specs,
             out_specs=out_spec,
             out_shape=out_shape,
+            scratch_shapes=scratch,
             interpret=interpret,
-        )(l2g, x2, g, w, d)
+            name="poisson_assembled_fused",
+        )(l2g, x2, g, w, kf, d.astype(kf.dtype))
     if gather_mode == "loop":
         # index maps receive the scalar-prefetch ref as a trailing argument
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -222,14 +242,17 @@ def poisson_assembled_fused_pallas(
                 pl.BlockSpec((rows, LANES), lambda i, s: (0, 0)),
                 pl.BlockSpec((block_e, 6, p), lambda i, s: (i, 0, 0)),
                 pl.BlockSpec((block_e, p), lambda i, s: (i, 0)),
-                pl.BlockSpec((n1, n1), lambda i, s: (0, 0)),
+                pl.BlockSpec(kf.shape, lambda i, s: (0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((rows, LANES), lambda i, s: (0, 0)),
+            scratch_shapes=scratch,
         )
-        return pl.pallas_call(
-            functools.partial(_kernel_loop, lam=lam, n1=n1),
+        return pallas_call(
+            functools.partial(_kernel_loop, lam=lam),
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
-        )(l2g.reshape(-1), x2, g, w, d)
+            name="poisson_assembled_fused",
+        )(l2g.reshape(-1), x2, g, w, kf, d.astype(kf.dtype))
     raise ValueError(f"unknown gather_mode {gather_mode!r}")

@@ -15,7 +15,8 @@ The paper's CG-iteration optimizations are streaming fusions:
     SMEM scalars, one pass).
 
 TPU mapping: 1-D vectors are viewed as (rows, 128) lane tiles; the grid
-walks row blocks; scalar reductions accumulate into a (1, 1) output block
+walks row blocks whose row count is a multiple of 8 (``kernels.ops`` pads
+every vector to whole (8k)x128 tiles, Mosaic's f32 tile); scalar reductions accumulate into a (1, 1) output block
 that every grid step revisits (TPU grids are sequential, so the
 accumulation is deterministic — unlike GPU atomics). α/β arrive as (1, 1)
 SMEM scalars so the same compiled kernel serves every iteration.
@@ -25,8 +26,8 @@ Batched (multi-RHS) layouts: the ``*_batched`` variants take a
 batched PCG (``core.cg.batched_cg_assembled``) — on a ``(B, row-blocks)``
 grid.  Per-column scalars (α per RHS, the Σ reductions) become ``(B,)``
 vectors: α/β ride in SMEM as a ``(B, 1)`` table indexed by the batch grid
-axis, and each batch row accumulates into its own revisited ``(1, 1)``
-block of a ``(B, 1)`` output.  Shared streams (the Jacobi diagonal) keep a
+axis, and each batch row accumulates into its own entry of a ``(B, 1)``
+SMEM output table.  Shared streams (the Jacobi diagonal) keep a
 single copy indexed only by the row-block axis, so the batch never
 materializes B copies of per-problem state — the per-batch-seed idiom of
 the pie ``rand_mv`` kernels.
@@ -39,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import pallas_call, resolve_interpret
 
 __all__ = [
     "fused_axpy_dot_pallas",
@@ -133,12 +136,13 @@ def _axpy_dot_batched_kernel(alpha_ref, r_ref, ap_ref, rnew_ref, acc_ref):
 
     @pl.when(i == 0)
     def _init():
-        acc_ref[0, 0] = jnp.float32(0.0)
+        acc_ref[b, 0] = jnp.float32(0.0)
 
-    acc_ref[0, 0] += part
+    acc_ref[b, 0] += part
 
 
 def _jacobi_dot_batched_kernel(dinv_ref, r_ref, z_ref, acc_ref):
+    b = pl.program_id(0)
     i = pl.program_id(1)
     r = r_ref[...]
     # dinv is the SHARED per-problem stream: one (br, LANES) block serves
@@ -151,9 +155,9 @@ def _jacobi_dot_batched_kernel(dinv_ref, r_ref, z_ref, acc_ref):
 
     @pl.when(i == 0)
     def _init():
-        acc_ref[0, 0] = jnp.float32(0.0)
+        acc_ref[b, 0] = jnp.float32(0.0)
 
-    acc_ref[0, 0] += part
+    acc_ref[b, 0] += part
 
 
 def _xpay_batched_kernel(beta_ref, r_ref, p_ref, out_ref):
@@ -178,9 +182,10 @@ def fused_axpy_dot_pallas(
     alpha: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(r - α·Ap, Σ(r - α·Ap)²) in one pass. r, ap: (rows, 128) tiles."""
+    interpret = resolve_interpret(interpret, r.dtype, ap.dtype, kernel="fused_axpy_dot")
     r2, ap2 = _as_tiles(r), _as_tiles(ap)
     rows = r2.shape[0]
     br = min(block_rows, rows)
@@ -188,7 +193,7 @@ def fused_axpy_dot_pallas(
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
     alpha2 = jnp.asarray(alpha, r2.dtype).reshape(1, 1)
     grid = (rows // br,)
-    r_new, acc = pl.pallas_call(
+    r_new, acc = pallas_call(
         _axpy_dot_kernel,
         grid=grid,
         in_specs=[
@@ -205,6 +210,7 @@ def fused_axpy_dot_pallas(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_axpy_dot",
     )(alpha2, r2, ap2)
     return r_new.reshape(r.shape), acc[0, 0]
 
@@ -216,16 +222,17 @@ def fused_xpay_pallas(
     beta: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """r + β·p, one pass."""
+    interpret = resolve_interpret(interpret, r.dtype, p.dtype, kernel="fused_xpay")
     r2, p2 = _as_tiles(r), _as_tiles(p)
     rows = r2.shape[0]
     br = min(block_rows, rows)
     if rows % br:
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
     beta2 = jnp.asarray(beta, r2.dtype).reshape(1, 1)
-    out = pl.pallas_call(
+    out = pallas_call(
         _xpay_kernel,
         grid=(rows // br,),
         in_specs=[
@@ -236,6 +243,7 @@ def fused_xpay_pallas(
         out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(r2.shape, r2.dtype),
         interpret=interpret,
+        name="fused_xpay",
     )(beta2, r2, p2)
     return out.reshape(r.shape)
 
@@ -247,15 +255,18 @@ def weighted_dot_pallas(
     b: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Σ w·a·b — NekBone's weighted inner product (extra weight stream)."""
+    interpret = resolve_interpret(
+        interpret, w.dtype, a.dtype, b.dtype, kernel="weighted_dot"
+    )
     w2, a2, b2 = _as_tiles(w), _as_tiles(a), _as_tiles(b)
     rows = w2.shape[0]
     br = min(block_rows, rows)
     if rows % br:
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
-    acc = pl.pallas_call(
+    acc = pallas_call(
         _wdot_kernel,
         grid=(rows // br,),
         in_specs=[
@@ -266,6 +277,7 @@ def weighted_dot_pallas(
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         interpret=interpret,
+        name="weighted_dot",
     )(w2, a2, b2)
     return acc[0, 0]
 
@@ -276,15 +288,18 @@ def fused_jacobi_dot_pallas(
     r: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(D⁻¹r, Σ r·D⁻¹r) in one pass — the PCG preconditioner-stage fusion."""
+    interpret = resolve_interpret(
+        interpret, dinv.dtype, r.dtype, kernel="fused_jacobi_dot"
+    )
     d2, r2 = _as_tiles(dinv), _as_tiles(r)
     rows = r2.shape[0]
     br = min(block_rows, rows)
     if rows % br:
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
-    z, acc = pl.pallas_call(
+    z, acc = pallas_call(
         _jacobi_dot_kernel,
         grid=(rows // br,),
         in_specs=[
@@ -300,6 +315,7 @@ def fused_jacobi_dot_pallas(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_jacobi_dot",
     )(d2, r2)
     return z.reshape(r.shape), acc[0, 0]
 
@@ -311,7 +327,7 @@ def fused_axpy_dot_batched_pallas(
     alpha: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batched (r - α·Ap, Σ(r - α·Ap)²): one pass over a (B, rows, 128) block.
 
@@ -319,6 +335,9 @@ def fused_axpy_dot_batched_pallas(
     step sizes (an SMEM table indexed by the batch grid axis).  Returns the
     updated (B, rows*128) block and the (B,) per-column reductions.
     """
+    interpret = resolve_interpret(
+        interpret, r.dtype, ap.dtype, kernel="fused_axpy_dot_batched"
+    )
     r3, ap3 = _as_batched_tiles(r), _as_batched_tiles(ap)
     nb, rows = r3.shape[0], r3.shape[1]
     br = min(block_rows, rows)
@@ -326,7 +345,7 @@ def fused_axpy_dot_batched_pallas(
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
     alpha2 = jnp.asarray(alpha, r3.dtype).reshape(nb, 1)
     grid = (nb, rows // br)
-    r_new, acc = pl.pallas_call(
+    r_new, acc = pallas_call(
         _axpy_dot_batched_kernel,
         grid=grid,
         in_specs=[
@@ -336,13 +355,16 @@ def fused_axpy_dot_batched_pallas(
         ],
         out_specs=[
             pl.BlockSpec((1, br, LANES), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0), memory_space=pltpu.SMEM),
+            # whole (B, 1) table in SMEM: a (1, 1) block of it is not a
+            # legal tile, and each batch row owns its own entry
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(r3.shape, r3.dtype),
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_axpy_dot_batched",
     )(alpha2, r3, ap3)
     return r_new.reshape(r.shape), acc[:, 0]
 
@@ -353,7 +375,7 @@ def fused_jacobi_dot_batched_pallas(
     r: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batched (D⁻¹r, Σ r·D⁻¹r) over a (B, rows, 128) block, one pass.
 
@@ -361,12 +383,15 @@ def fused_jacobi_dot_batched_pallas(
     replicated per column; ``r``: (B, rows*128).  Returns the (B, rows*128)
     z block and the (B,) per-column r·z reductions.
     """
+    interpret = resolve_interpret(
+        interpret, dinv.dtype, r.dtype, kernel="fused_jacobi_dot_batched"
+    )
     d2, r3 = _as_tiles(dinv), _as_batched_tiles(r)
     nb, rows = r3.shape[0], r3.shape[1]
     br = min(block_rows, rows)
     if rows % br:
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
-    z, acc = pl.pallas_call(
+    z, acc = pallas_call(
         _jacobi_dot_batched_kernel,
         grid=(nb, rows // br),
         in_specs=[
@@ -375,13 +400,16 @@ def fused_jacobi_dot_batched_pallas(
         ],
         out_specs=[
             pl.BlockSpec((1, br, LANES), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0), memory_space=pltpu.SMEM),
+            # whole (B, 1) table in SMEM: a (1, 1) block of it is not a
+            # legal tile, and each batch row owns its own entry
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(r3.shape, r3.dtype),
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_jacobi_dot_batched",
     )(d2, r3)
     return z.reshape(r.shape), acc[:, 0]
 
@@ -393,16 +421,19 @@ def fused_xpay_batched_pallas(
     beta: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Batched r + β·p over a (B, rows, 128) block; β: (B,) SMEM table."""
+    interpret = resolve_interpret(
+        interpret, r.dtype, p.dtype, kernel="fused_xpay_batched"
+    )
     r3, p3 = _as_batched_tiles(r), _as_batched_tiles(p)
     nb, rows = r3.shape[0], r3.shape[1]
     br = min(block_rows, rows)
     if rows % br:
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
     beta2 = jnp.asarray(beta, r3.dtype).reshape(nb, 1)
-    out = pl.pallas_call(
+    out = pallas_call(
         _xpay_batched_kernel,
         grid=(nb, rows // br),
         in_specs=[
@@ -413,6 +444,7 @@ def fused_xpay_batched_pallas(
         out_specs=pl.BlockSpec((1, br, LANES), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(r3.shape, r3.dtype),
         interpret=interpret,
+        name="fused_xpay_batched",
     )(beta2, r3, p3)
     return out.reshape(r.shape)
 
@@ -425,9 +457,12 @@ def fused_cheb_d_update_pallas(
     r: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """d ← a·d + c·r, one pass (Chebyshev direction update; two SMEM scalars)."""
+    interpret = resolve_interpret(
+        interpret, d.dtype, r.dtype, kernel="fused_cheb_d_update"
+    )
     d2, r2 = _as_tiles(d), _as_tiles(r)
     rows = d2.shape[0]
     br = min(block_rows, rows)
@@ -435,7 +470,7 @@ def fused_cheb_d_update_pallas(
         raise ValueError(f"rows={rows} not a multiple of block_rows={br}")
     a2 = jnp.asarray(a, d2.dtype).reshape(1, 1)
     c2 = jnp.asarray(c, d2.dtype).reshape(1, 1)
-    out = pl.pallas_call(
+    out = pallas_call(
         _cheb_d_kernel,
         grid=(rows // br,),
         in_specs=[
@@ -447,5 +482,6 @@ def fused_cheb_d_update_pallas(
         out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(d2.shape, d2.dtype),
         interpret=interpret,
+        name="fused_cheb_d_update",
     )(a2, c2, d2, r2)
     return out.reshape(d.shape)

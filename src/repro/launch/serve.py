@@ -17,41 +17,39 @@ import sys
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.hipbone import CONFIGS, REDUCED
 from repro.core import build_problem
 from repro.serving import SolveRequest, SolverEngine, SolverServeConfig
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument(
-        "--config", default="hipbone_reduced",
-        choices=sorted(CONFIGS) + ["hipbone_reduced"],
-    )
-    ap.add_argument("--requests", type=int, default=None,
-                    help="RHS columns per round (default: config batch_rhs)")
-    ap.add_argument("--max-batch", type=int, default=16,
-                    help="engine slot width per dispatch")
-    ap.add_argument("--rounds", type=int, default=2)
-    ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-
-    cfg = REDUCED if args.config == "hipbone_reduced" else CONFIGS[args.config]
-    n_req = args.requests or max(cfg.batch_rhs, 1)
+def serve_rounds(
+    cfg,
+    *,
+    requests: int | None = None,
+    max_batch: int = 16,
+    rounds: int = 2,
+    seed: int = 0,
+) -> tuple[SolverEngine, int]:
+    """Feed ``rounds`` rounds of random-RHS requests for ``cfg`` through
+    the engine; print each round; return the engine and the failure count
+    (unconverged columns plus repeated rounds that missed the setup cache).
+    """
+    n_req = requests or max(cfg.batch_rhs, 1)
     prob = build_problem(
         cfg.n_degree, cfg.local_elems, lam=cfg.lam,
         dtype=jnp.dtype(cfg.dtype), **cfg.problem_kwargs()
     )
-    engine = SolverEngine(SolverServeConfig(max_batch=args.max_batch))
-    rng = np.random.default_rng(args.seed)
+    engine = SolverEngine(SolverServeConfig(max_batch=max_batch))
+    rng = np.random.default_rng(seed)
 
     print(
         f"solver service: {cfg.name} N={cfg.n_degree} "
         f"dofs={prob.n_global} precond={cfg.precond} "
-        f"requests={n_req}/round × {args.rounds} rounds"
+        f"requests={n_req}/round × {rounds} rounds"
     )
     failures = 0
-    for rnd in range(args.rounds):
+    for rnd in range(rounds):
         reqs = [
             SolveRequest(
                 prob=prob,
@@ -79,6 +77,29 @@ def main() -> None:
             print("ERROR: repeated round missed the setup cache")
             failures += 1
     print("cache:", engine.cache.stats())
+    return engine, failures
+
+
+def main() -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument(
+        "--config", default="hipbone_reduced",
+        choices=sorted(CONFIGS) + ["hipbone_reduced"],
+    )
+    ap.add_argument("--requests", type=int, default=None,
+                    help="RHS columns per round (default: config batch_rhs)")
+    ap.add_argument("--max-batch", type=int, default=16,
+                    help="engine slot width per dispatch")
+    ap.add_argument("--rounds", type=int, default=2)
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args()
+    enable_compile_cache()
+
+    cfg = REDUCED if args.config == "hipbone_reduced" else CONFIGS[args.config]
+    _, failures = serve_rounds(
+        cfg, requests=args.requests, max_batch=args.max_batch,
+        rounds=args.rounds, seed=args.seed,
+    )
     if failures:
         sys.exit(1)
 

@@ -8,7 +8,6 @@ manufactured solutions (`repro.testing.mms`).
 """
 from .faults import (
     corrupt_wire,
-    force_fused_failure,
     mask_precond,
     nan_at_iteration,
     negate_precond,
@@ -34,7 +33,6 @@ __all__ = [
     "mms_problem",
     "mms_rhs",
     "corrupt_wire",
-    "force_fused_failure",
     "mask_precond",
     "nan_at_iteration",
     "negate_precond",

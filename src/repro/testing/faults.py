@@ -10,14 +10,12 @@ paired with the detector that must catch it (`core.cg.SolveStatus`):
 | `skew_operator`       | non-symmetric operator corruption   | DIVERGED        |
 | `mask_precond`        | partially-zeroed M⁻¹ payload        | STAGNATED       |
 | `corrupt_wire`        | corrupted halo/shell wire payload   | any of the above|
-| `force_fused_failure` | Pallas VMEM/lowering failure        | split-path      |
-|                       |                                     | fallback (ops)  |
 
 Operator/preconditioner wrappers are plain callables — compose them with
 `core.resilience.solve_with_fallback`'s ``instrument`` seam (see
-`on_attempt`) to fault only specific retry attempts.  `corrupt_wire` and
-`force_fused_failure` are context managers because their seams are module
-state read at trace time: install them *before* the solve is compiled.
+`on_attempt`) to fault only specific retry attempts.  `corrupt_wire` is a
+context manager because its seam is module state read at trace time:
+install it *before* the solve is compiled.
 
 Nothing here is imported by solver code; this module is the testing
 surface of the robustness subsystem.
@@ -35,7 +33,6 @@ from jax.experimental import io_callback
 
 __all__ = [
     "corrupt_wire",
-    "force_fused_failure",
     "mask_precond",
     "nan_at_iteration",
     "negate_precond",
@@ -162,30 +159,6 @@ def corrupt_wire(rank: int, *, mode: str = "nan", axis_name: str | None = None):
 
     with halo.wire_transform(hook):
         yield
-
-
-@contextlib.contextmanager
-def force_fused_failure():
-    """Make the fused-operator lowering probe fail (VMEM-overflow stand-in).
-
-    ``kernels.ops.probe_fused_operator`` raises for every shape while
-    active, so ``should_fuse_operator`` must warn once per shape and
-    degrade to the split pipeline — including under ``HIPBONE_FUSED=1``.
-    The probe cache is cleared on entry and restored on exit so forced
-    verdicts never leak into later policy decisions.
-    """
-    from ..kernels import ops
-
-    prev_flag = ops._FUSED_PROBE_FAIL
-    saved = dict(ops._FUSED_PROBE_CACHE)
-    ops._FUSED_PROBE_FAIL = True
-    ops._FUSED_PROBE_CACHE.clear()
-    try:
-        yield
-    finally:
-        ops._FUSED_PROBE_FAIL = prev_flag
-        ops._FUSED_PROBE_CACHE.clear()
-        ops._FUSED_PROBE_CACHE.update(saved)
 
 
 def on_attempt(

@@ -338,29 +338,60 @@ def test_config_detector_defaults_mirror_cg():
             assert cfg.cg_variant == "flexible", cfg.name
 
 
-# ------------------------------------------------- fused-operator fallback
+# ------------------------------------------------ native kernel selection
 
 
-def test_forced_probe_failure_degrades_to_split(prob64, monkeypatch):
-    """A Pallas lowering/VMEM failure in the fused-operator probe must turn
-    into one warning + the split pipeline — even under HIPBONE_FUSED=1."""
+@pytest.fixture
+def native_backend(monkeypatch):
+    """Make the kernel policies see a TPU backend (Pallas compiles natively).
+
+    Only the policies and the kernels' interpret resolution are exercised:
+    they raise or answer before anything is lowered.
+    """
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     from repro.kernels import ops
-    from repro.testing import force_fused_failure
 
+    assert not ops.default_interpret()
+
+
+@pytest.mark.parametrize("override", [None, "1", "0"])
+def test_native_backend_never_fuses_float64(
+    native_backend, prob64, override, monkeypatch
+):
+    """No Pallas kernel is selected for float64 on a native backend,
+    whatever HIPBONE_FUSED says, and a direct call refuses float64."""
+    from repro.kernels import ops
+
+    if override is None:
+        monkeypatch.delenv("HIPBONE_FUSED", raising=False)
+    else:
+        monkeypatch.setenv("HIPBONE_FUSED", override)
+    assert ops.should_fuse_streams(jnp.float64) is False
+    if override != "1":
+        assert ops.should_fuse_operator() is False
+        assert poisson_assembled(prob64).fused is False
+    from repro.kernels.backend import resolve_interpret
+
+    # the check every *_pallas entry point makes while it is traced
+    with pytest.raises(TypeError, match="float64"):
+        resolve_interpret(None, jnp.float32, jnp.float64, kernel="any")
+    assert resolve_interpret(True, jnp.float64, kernel="any") is True
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_explicit_fused_operator_raises_on_native_backend(
+    native_backend, dtype, monkeypatch
+):
+    """The fused operator has no native lowering: fused=True and
+    HIPBONE_FUSED=1 raise instead of degrading to the split pipeline."""
+    prob = build_problem(3, (2, 2, 2), lam=0.7, dtype=jnp.dtype(dtype))
+    monkeypatch.delenv("HIPBONE_FUSED", raising=False)
+    assert poisson_assembled(prob).fused is False
+    with pytest.raises(NotImplementedError, match="no native Pallas lowering"):
+        poisson_assembled(prob, fused=True)
     monkeypatch.setenv("HIPBONE_FUSED", "1")
-    args = dict(n_degree=prob64.mesh.n_degree, n_global=prob64.n_global)
-    with force_fused_failure():
-        with pytest.warns(RuntimeWarning, match="split"):
-            assert ops.should_fuse_operator(jnp.float64, **args) is False
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")  # cached verdict: no re-warn
-            assert ops.should_fuse_operator(jnp.float64, **args) is False
-        # the degraded policy builds the split-path operator
-        a = poisson_assembled(prob64)
-        assert a.fused is False
-    # probe state restored: the genuine lowering succeeds again
-    assert ops._FUSED_PROBE_FAIL is False
-    assert ops.should_fuse_operator(jnp.float64, **args) is True
+    with pytest.raises(NotImplementedError, match="no native Pallas lowering"):
+        poisson_assembled(prob)
 
 
 # ----------------------------------------------------------- sharded paths

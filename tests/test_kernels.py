@@ -73,12 +73,21 @@ def test_poisson_kernel_bf16(rng):
 
 
 def test_vmem_budget_picks_smaller_blocks():
-    from repro.kernels.poisson import pick_block_e, vmem_bytes_per_block
+    from repro.kernels.poisson import (
+        KERNEL_VMEM_BUDGET,
+        KERNEL_VMEM_LIMIT,
+        pick_block_e,
+        vmem_bytes_per_block,
+    )
 
     assert pick_block_e(15) <= pick_block_e(7) or pick_block_e(7) == 256
     for n in (7, 15):
         eb = pick_block_e(n)
-        assert vmem_bytes_per_block(eb, n + 1) <= 4 * 2**20
+        assert vmem_bytes_per_block(eb, n + 1) <= KERNEL_VMEM_BUDGET
+        assert KERNEL_VMEM_BUDGET <= KERNEL_VMEM_LIMIT
+        # a tighter budget picks a smaller block
+        small = pick_block_e(n, budget_bytes=KERNEL_VMEM_BUDGET // 4)
+        assert small < eb
 
 
 @pytest.mark.parametrize("n", [64, 128, 1000, 128 * 9, 40000])
