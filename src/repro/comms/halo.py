@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from .topology import ProcessGrid
 
 __all__ = [
@@ -172,6 +173,7 @@ def _subsets(dims: list[int]) -> list[tuple[int, ...]]:
     return out
 
 
+@obs.scope("halo.sum")
 def sum_exchange(
     box: jax.Array,
     grid: ProcessGrid,
@@ -464,6 +466,7 @@ def _expand_fused(
     return box
 
 
+@obs.scope("halo.expand")
 def expand_exchange(
     box: jax.Array,
     grid: ProcessGrid,
@@ -617,6 +620,7 @@ def _contract_fused(
     return box[d:-d, d:-d, d:-d]
 
 
+@obs.scope("halo.contract")
 def contract_exchange(
     box: jax.Array,
     grid: ProcessGrid,
@@ -670,6 +674,7 @@ def contract_exchange(
     return box[d:-d, d:-d, d:-d]
 
 
+@obs.scope("halo.copy")
 def copy_exchange(
     box: jax.Array,
     grid: ProcessGrid,

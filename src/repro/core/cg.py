@@ -57,6 +57,8 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
+
 
 # TPU's default f32 matmul is one bf16 pass; the solver needs full f32
 _HI = jax.lax.Precision.HIGHEST
@@ -201,22 +203,28 @@ def _pcg(
     flexible = variant == "flexible" and precond is not None
     x = jnp.zeros_like(b) if x0 is None else x0
 
+    def apply_operator(v):
+        with obs.scope("cg.operator"):
+            return operator(v)
+
     def apply_precond(r_vec):
         """z = M⁻¹r and the local part of r·z, in one fused pass if given."""
         if precond is None:
             raise AssertionError("apply_precond called without a preconditioner")
-        if fused_precond_dot is not None:
-            return fused_precond_dot(r_vec)
-        z_vec = precond(r_vec)
+        with obs.scope("cg.precond"):
+            if fused_precond_dot is not None:
+                return fused_precond_dot(r_vec)
+            z_vec = precond(r_vec)
         return z_vec, _dot(r_vec, z_vec, weight)
 
-    r = b - operator(x)
-    rdotr0 = allsum(_dot(r, r, weight))
-    if precond is None:
-        z, rz = r, rdotr0
-    else:
-        z, rz_local = apply_precond(r)
-        rz = allsum(rz_local)
+    with obs.scope("cg.init"):
+        r = b - apply_operator(x)
+        rdotr0 = allsum(_dot(r, r, weight))
+        if precond is None:
+            z, rz = r, rdotr0
+        else:
+            z, rz_local = apply_precond(r)
+            rz = allsum(rz_local)
     p = z
 
     # Guardrails: status codes as int32 scalars so they live in the loop
@@ -249,7 +257,7 @@ def _pcg(
     )
 
     def step(x, r, p, rz, rdotr):
-        ap = operator(p)
+        ap = apply_operator(p)
         pap = allsum(_dot(p, ap, weight))
         alpha = _safe_div(rz, pap)
         if weight is None:
@@ -286,6 +294,7 @@ def _pcg(
         # relies on scan for shard_map's check_rep) — record the *first*
         # breakdown and keep stepping; _safe_div keeps the post-breakdown
         # arithmetic inert where it can.
+        @obs.scope("cg.iteration")
         def body(carry, _):
             x, r, p, rz, rdotr, status = carry
             x, r, p, rz, rdotr, fail = step(x, r, p, rz, rdotr)
@@ -313,10 +322,12 @@ def _pcg(
     diverged_code = jnp.asarray(SolveStatus.DIVERGED, jnp.int32)
     stagnated_code = jnp.asarray(SolveStatus.STAGNATED, jnp.int32)
 
+    @obs.scope("cg.iteration")
     def cond(carry):
         rdotr, k, status = carry[4], carry[5], carry[6]
         return (k < n_iter) & (rdotr > target) & (status == run)
 
+    @obs.scope("cg.iteration")
     def wbody(carry):
         x, r, p, rz, rdotr, k, status, best, since = carry[:9]
         x, r, p, rz, rdotr_new, fail = step(x, r, p, rz, rdotr)

@@ -49,6 +49,7 @@ from ..comms.halo import (
 )
 from ..comms.topology import ProcessGrid
 from ..compat import shard_map
+from .. import obs
 from . import sem
 from .coefficients import coefficient_fields
 from .mesh import normalize_bc
@@ -670,20 +671,22 @@ def _box_galerkin_apply(
 
     def apply(x_box: jax.Array, x_raw: jax.Array | None = None) -> jax.Array:
         if two_phase:
-            x_box = copy_exchange(
-                x_box.reshape(prob.box_shape[::-1]), prob.grid,
-                prob.axis_name, xcopy[1], xcopy[0],
-            ).reshape(-1)
+            with obs.scope("halo.site.galerkin"):
+                x_box = copy_exchange(
+                    x_box.reshape(prob.box_shape[::-1]), prob.grid,
+                    prob.axis_name, xcopy[1], xcopy[0],
+                ).reshape(-1)
             x_raw = None  # the refreshed box is the only valid source
         u_h = jnp.take(x_box, l2g_flat[: eh * p], axis=0).reshape(eh, p)
         y_h = block_matvec_einsum(blocks[:eh], u_h)
         box_h = jax.ops.segment_sum(
             y_h.reshape(-1), l2g_flat[: eh * p], num_segments=m3
         )
-        box_h = sum_exchange(
-            box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xsum[1], xsum[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.galerkin"):
+            box_h = sum_exchange(
+                box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+                xsum[1], xsum[0],
+            ).reshape(-1)
 
         # interior blocks: no rank-boundary contact -> overlap the exchange
         # (and, given a raw twin, the upstream transfer exchange too)
@@ -745,10 +748,11 @@ def _apply_assembled(
 
     if two_phase:
         # paper-faithful: explicit scatter-side halo refresh first
-        x_box = copy_exchange(
-            x_box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xcopy[1], xcopy[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.operator"):
+            x_box = copy_exchange(
+                x_box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+                xcopy[1], xcopy[0],
+            ).reshape(-1)
         x_raw = None  # the refreshed box is the only valid source
     x_int = x_box if x_raw is None else x_raw
 
@@ -758,10 +762,11 @@ def _apply_assembled(
     box_h = jax.ops.segment_sum(
         y_h.reshape(-1), l2g_flat[: eh * p], num_segments=m3
     )
-    box_h = sum_exchange(
-        box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-        xsum[1], xsum[0],
-    ).reshape(-1)
+    with obs.scope("halo.site.operator"):
+        box_h = sum_exchange(
+            box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+            xsum[1], xsum[0],
+        ).reshape(-1)
 
     # interior elements: no boundary contact -> overlaps the exchange above
     if fused_interior:
@@ -787,6 +792,16 @@ def _apply_assembled(
             y_i.reshape(-1), l2g_flat[eh * p :], num_segments=m3
         )
     return box_h + box_i
+
+
+def _psum(axis_name: str) -> Callable[[jax.Array], jax.Array]:
+    """All-reduce of the recurrence scalars over the ranks, under its scope."""
+
+    @obs.scope("cg.allreduce")
+    def psum(v: jax.Array) -> jax.Array:
+        return lax.psum(v, axis_name)
+
+    return psum
 
 
 def _box_global_indices(prob: DistPoisson) -> np.ndarray:
@@ -833,10 +848,11 @@ def _box_dinv(
         jnp.asarray(prob.l2g.reshape(-1)),
         num_segments=prob.m3,
     )
-    box_diag = sum_exchange(
-        box_diag.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-        xsum[1], xsum[0],
-    ).reshape(-1)
+    with obs.scope("halo.site.diag"):
+        box_diag = sum_exchange(
+            box_diag.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+            xsum[1], xsum[0],
+        ).reshape(-1)
     return 1.0 / box_diag
 
 
@@ -871,10 +887,11 @@ def _box_transfer_pair(
         raw = jax.ops.segment_sum(
             (w_lf * u_f).reshape(-1), l2g_f, num_segments=lf.m3
         )
-        con = sum_exchange(
-            raw.reshape(lf.box_shape[::-1]), lf.grid, lf.axis_name,
-            xsum_f[1], xsum_f[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.prolong"):
+            con = sum_exchange(
+                raw.reshape(lf.box_shape[::-1]), lf.grid, lf.axis_name,
+                xsum_f[1], xsum_f[0],
+            ).reshape(-1)
         return raw, con
 
     def restrict(r_f: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -883,10 +900,11 @@ def _box_transfer_pair(
         raw = jax.ops.segment_sum(
             u_c.reshape(-1), l2g_c, num_segments=lc.m3
         )
-        con = sum_exchange(
-            raw.reshape(lc.box_shape[::-1]), lc.grid, lc.axis_name,
-            xsum_c[1], xsum_c[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.restrict"):
+            con = sum_exchange(
+                raw.reshape(lc.box_shape[::-1]), lc.grid, lc.axis_name,
+                xsum_c[1], xsum_c[0],
+            ).reshape(-1)
         return raw, con
 
     return prolong, restrict
@@ -1069,20 +1087,22 @@ def _box_schwarz_apply(
     def apply(r_box: jax.Array) -> jax.Array:
         rw = wsq * r_box
         # shell expansion first: halo-block inputs feed on the ppermutes
-        ext = expand_exchange(
-            rw.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name, s,
-            xexpand[1], xexpand[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.schwarz"):
+            ext = expand_exchange(
+                rw.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name, s,
+                xexpand[1], xexpand[0],
+            ).reshape(-1)
         u_h = jnp.take(ext, halo_flat, axis=0).reshape(eh, -1)
         acc = jax.ops.segment_sum(
             fdm_solve(fdm_halo, u_h).reshape(-1),
             halo_flat,
             num_segments=m3_ext,
         )
-        box = contract_exchange(
-            acc.reshape(sd.ext_shape[::-1]), prob.grid, prob.axis_name, s,
-            xcontract[1], xcontract[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.schwarz"):
+            box = contract_exchange(
+                acc.reshape(sd.ext_shape[::-1]), prob.grid, prob.axis_name, s,
+                xcontract[1], xcontract[0],
+            ).reshape(-1)
         # interior blocks: no shell contact -> overlap the exchanges above
         if eh < prob.e_local:
             u_i = jnp.take(rw, int_flat, axis=0).reshape(
@@ -1093,10 +1113,11 @@ def _box_schwarz_apply(
                 int_flat,
                 num_segments=prob.m3,
             )
-        out = sum_exchange(
-            box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xsum[1], xsum[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.schwarz"):
+            out = sum_exchange(
+                box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+                xsum[1], xsum[0],
+            ).reshape(-1)
         return wsq * out
 
     return apply
@@ -1192,7 +1213,7 @@ def dist_spectrum(
         lmin, lmax = lanczos_extremes(
             operator, dinv, seed_s[0],
             iters=lanczos_iters, dot=mdot,
-            psum=lambda v: lax.psum(v, prob.axis_name),
+            psum=_psum(prob.axis_name),
         )
         return lmin, lmax
 
@@ -1246,7 +1267,7 @@ def dist_lambda_max(
         return power_lambda_max(
             operator, dinv, seed_s[0],
             iters=power_iters, dot=mdot,
-            psum=lambda v: lax.psum(v, prob.axis_name),
+            psum=_psum(prob.axis_name),
         )
 
     fn = shard_map(
@@ -1576,10 +1597,11 @@ def dist_cg(
     def shard_fn(b_s, g_s, w_s, mask_s, seed_s, aux_s, pmg_s, schwarz_s):
         b1, g1, w1, m1 = b_s[0], g_s[0], w_s[0], mask_s[0]
         # make rhs consistent (replicas hold true values)
-        b1 = copy_exchange(
-            b1.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xcopy[0][1], xcopy[0][0],
-        ).reshape(-1)
+        with obs.scope("halo.site.rhs"):
+            b1 = copy_exchange(
+                b1.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+                xcopy[0][1], xcopy[0][0],
+            ).reshape(-1)
         s1 = aux_s[0][0] if has_screen else None
         bcm1 = aux_s[1 if has_screen else 0][0] if has_bc else None
 
@@ -1604,7 +1626,7 @@ def dist_cg(
             fused_interior=fused_operator, xsum=xsum[0], xcopy=xcopy[0],
             screen=s1,
         ))
-        psum = lambda v: lax.psum(v, prob.axis_name)
+        psum = _psum(prob.axis_name)
 
         # preconditioner-dtype views of the fine-level shards: the casts are
         # single ops reused by every M⁻¹-internal A-apply in the program
@@ -1965,17 +1987,18 @@ def dist_cg_scattered(
 
     def gather_scatter(y_l):
         box = jax.ops.segment_sum(y_l.reshape(-1), l2g_flat, num_segments=m3)
-        box = sum_exchange(
-            box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xs[1], xs[0],
-        ).reshape(-1)
+        with obs.scope("halo.site.scattered"):
+            box = sum_exchange(
+                box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+                xs[1], xs[0],
+            ).reshape(-1)
         return jnp.take(box, l2g_flat, axis=0).reshape(y_l.shape)
 
     def shard_fn(b_s, g_s, w_s, seed_s):
         # caller passes a consistent b_L (NekBone gather-scatters its random
         # forcing at setup; applying ZZ^T here would alter a general rhs)
         b1, g1, w1 = b_s[0], g_s[0], w_s[0]
-        psum = lambda v: lax.psum(v, prob.axis_name)
+        psum = _psum(prob.axis_name)
 
         def operator(x_l):
             s = op(x_l, g1, prob.d, 0.0, None)

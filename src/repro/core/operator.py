@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import coefficients as _coef
 from . import geometry, sem
 from .gather_scatter import gather, gather_scatter, inverse_degree, scatter
@@ -211,6 +212,7 @@ def _eval_field(spec, coords: np.ndarray) -> np.ndarray | None:
     return arr
 
 
+@obs.spanned("setup.build_problem")
 def build_problem(
     n_degree: int,
     shape: tuple[int, int, int],
@@ -229,8 +231,9 @@ def build_problem(
     mass-weighted screen).  ``bc`` is a boundary-condition spec accepted
     by ``mesh.normalize_bc`` (None = legacy, no essential BCs).
     """
-    m = build_box_mesh(n_degree, shape, deform=deform)
-    k, lam_field = _coef.coefficient_fields(coefficient, m.coords, lam)
+    with obs.span("setup.mesh"):
+        m = build_box_mesh(n_degree, shape, deform=deform)
+        k, lam_field = _coef.coefficient_fields(coefficient, m.coords, lam)
     return problem_from_mesh(
         m, lam=lam, dtype=dtype, k=k, lam_field=lam_field, bc=bc
     )
@@ -253,34 +256,36 @@ def problem_from_mesh(
     kernels, diagonals, Galerkin probes, Schwarz means, sharded boxes)
     sees variable diffusion through the ``g`` stream it already reads.
     """
-    geo = geometry.geometric_factors(m)
-    d = sem.derivative_matrix(m.n_degree)
-    w_g = inverse_degree(m.l2g, m.n_global)
-    w_l = w_g[m.l2g]
-    g = np.asarray(geo["G"])
-    k_arr = _eval_field(k, m.coords)
-    lam_arr = _eval_field(lam_field, m.coords)
-    if k_arr is not None:
-        g = g * k_arr[:, None, :]
-    tags = normalize_bc(bc)
-    mask = None if tags is None else dirichlet_mask(m, tags)
-    return PoissonProblem(
-        mesh=m,
-        lam=float(lam),
-        d=jnp.asarray(d, dtype=dtype),
-        g=jnp.asarray(g, dtype=dtype),
-        jw=jnp.asarray(geo["JW"], dtype=dtype),
-        l2g=jnp.asarray(m.l2g),
-        w_local=jnp.asarray(w_l, dtype=dtype),
-        w_global=jnp.asarray(w_g, dtype=dtype),
-        dtype=dtype,
-        k=None if k_arr is None else jnp.asarray(k_arr, dtype=dtype),
-        lam_field=(
-            None if lam_arr is None else jnp.asarray(lam_arr, dtype=dtype)
-        ),
-        mask=None if mask is None else jnp.asarray(mask, dtype=dtype),
-        bc=tags,
-    )
+    with obs.span("setup.geometry"):
+        geo = geometry.geometric_factors(m)
+        d = sem.derivative_matrix(m.n_degree)
+        w_g = inverse_degree(m.l2g, m.n_global)
+        w_l = w_g[m.l2g]
+        g = np.asarray(geo["G"])
+        k_arr = _eval_field(k, m.coords)
+        lam_arr = _eval_field(lam_field, m.coords)
+        if k_arr is not None:
+            g = g * k_arr[:, None, :]
+        tags = normalize_bc(bc)
+        mask = None if tags is None else dirichlet_mask(m, tags)
+    with obs.span("setup.upload"):
+        return PoissonProblem(
+            mesh=m,
+            lam=float(lam),
+            d=jnp.asarray(d, dtype=dtype),
+            g=jnp.asarray(g, dtype=dtype),
+            jw=jnp.asarray(geo["JW"], dtype=dtype),
+            l2g=jnp.asarray(m.l2g),
+            w_local=jnp.asarray(w_l, dtype=dtype),
+            w_global=jnp.asarray(w_g, dtype=dtype),
+            dtype=dtype,
+            k=None if k_arr is None else jnp.asarray(k_arr, dtype=dtype),
+            lam_field=(
+                None if lam_arr is None else jnp.asarray(lam_arr, dtype=dtype)
+            ),
+            mask=None if mask is None else jnp.asarray(mask, dtype=dtype),
+            bc=tags,
+        )
 
 
 def coarsen_problem(prob: PoissonProblem, n_coarse: int) -> PoissonProblem:
@@ -395,12 +400,15 @@ def poisson_assembled(
     mask = prob.mask
 
     def apply(x_g: jax.Array) -> jax.Array:
-        if mask is not None:
-            x_g = mask * x_g
-        x_l = scatter(x_g, prob.l2g)
-        y_l = op(x_l, prob.g, prob.d, lam_eff, w_eff)
-        y_g = gather(y_l, prob.l2g, prob.n_global)
-        return y_g if mask is None else mask * y_g
+        with obs.scope("op.scatter"):
+            if mask is not None:
+                x_g = mask * x_g
+            x_l = scatter(x_g, prob.l2g)
+        with obs.scope("op.local"):
+            y_l = op(x_l, prob.g, prob.d, lam_eff, w_eff)
+        with obs.scope("op.gather"):
+            y_g = gather(y_l, prob.l2g, prob.n_global)
+            return y_g if mask is None else mask * y_g
 
     apply.fused = False
     return apply
@@ -425,6 +433,7 @@ def poisson_scattered(
         )
     op = local_op or local_poisson
 
+    @obs.scope("op.scattered")
     def apply(x_l: jax.Array) -> jax.Array:
         s_l = op(x_l, prob.g, prob.d, 0.0, None)  # S_L x_L only
         return gather_scatter(s_l, prob.l2g, prob.n_global) + prob.lam * x_l

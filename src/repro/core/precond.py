@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import sem
 from .gather_scatter import gather, scatter
 from .schwarz import SCHWARZ_INNER_DEGREE, make_schwarz_apply
@@ -622,12 +623,17 @@ def make_vcycle(
 
     def cycle(level: int, r: jax.Array) -> jax.Array:
         if level == n_smoothed:
-            return coarse_apply(r)
+            with obs.scope("pmg.coarse"):
+                return coarse_apply(r)
         smooth, op = smoothers[level], operators[level]
-        z = smooth(r)                                   # pre-smooth (z₀ = 0)
-        zc = cycle(level + 1, restricts[level](r - op(z)))
-        z = z + prolongs[level](zc)                     # coarse-grid correction
-        return z + smooth(r - op(z))                    # post-smooth
+        # the level's own work only: its scope never wraps the recursion
+        with obs.scope(f"pmg.l{level}"):
+            z = smooth(r)                               # pre-smooth (z₀ = 0)
+            r_c = restricts[level](r - op(z))
+        zc = cycle(level + 1, r_c)
+        with obs.scope(f"pmg.l{level}"):
+            z = z + prolongs[level](zc)                 # coarse-grid correction
+            return z + smooth(r - op(z))                # post-smooth
 
     return lambda r: cycle(0, r)
 
@@ -670,13 +676,16 @@ def make_vcycle_overlapped(
 
     def cycle(level: int, raw: jax.Array, con: jax.Array) -> jax.Array:
         if level == n_smoothed:
-            return coarse_apply_pair(raw, con)
-        z = smoothers_pair[level](raw, con)             # pre-smooth (z₀ = 0)
-        raw_c, con_c = restricts_pair[level](con - operators[level](z))
+            with obs.scope("pmg.coarse"):
+                return coarse_apply_pair(raw, con)
+        with obs.scope(f"pmg.l{level}"):
+            z = smoothers_pair[level](raw, con)         # pre-smooth (z₀ = 0)
+            raw_c, con_c = restricts_pair[level](con - operators[level](z))
         zc = cycle(level + 1, raw_c, con_c)
-        p_raw, p_con = prolongs_pair[level](zc)         # coarse-grid corr.
-        resid = con - operators_pair[level](z + p_raw, z + p_con)
-        return (z + p_con) + smoothers[level](resid)    # post-smooth
+        with obs.scope(f"pmg.l{level}"):
+            p_raw, p_con = prolongs_pair[level](zc)     # coarse-grid corr.
+            resid = con - operators_pair[level](z + p_raw, z + p_con)
+            return (z + p_con) + smoothers[level](resid)  # post-smooth
 
     return lambda r: cycle(0, r, r)
 
@@ -777,12 +786,11 @@ def make_pmg_preconditioner(
     if len(degrees) < 2:
         raise ValueError(f"pmg ladder needs >= 2 levels, got {degrees}")
     probs = [prob]
-    for nc in degrees[1:]:
-        probs.append(coarsen_problem(probs[-1], nc))
-
     prolongs, restricts = [], []
-    for fine, coarse in zip(probs[:-1], probs[1:]):
-        p_up, r_down = make_transfer_pair(fine, coarse)
+    for i, nc in enumerate(degrees[1:], 1):
+        with obs.span(f"setup.precond.l{i}"):
+            probs.append(coarsen_problem(probs[-1], nc))
+            p_up, r_down = make_transfer_pair(probs[-2], probs[-1])
         prolongs.append(p_up)
         restricts.append(r_down)
 
@@ -839,78 +847,80 @@ def make_pmg_preconditioner(
     smoothers = []
     lmax0 = lmin0 = None
     for i in range(len(probs) - 1):
-        dinv = masked_dinv(probs[i], assembled_diagonal(probs[i]))
-        v0 = masked_seed(
-            probs[i], deterministic_seed_vector(probs[i].n_global, dinv.dtype)
-        )
-        if smoother == "schwarz":
-            base = make_schwarz_apply(
-                probs[i],
-                overlap=min(schwarz_overlap, probs[i].mesh.n_degree - 1),
-                inner_degree=schwarz_inner_degree,
+        with obs.span(f"setup.precond.l{i}"):
+            dinv = masked_dinv(probs[i], assembled_diagonal(probs[i]))
+            v0 = masked_seed(
+                probs[i], deterministic_seed_vector(probs[i].n_global, dinv.dtype)
             )
-        else:
-            base = dinv
-        lo, lmax_e, lmin_e = smoother_interval(
-            ops[i], base, v0, smoother=smoother, lanczos_iters=lanczos_iters
-        )
-        if i == 0:
-            lmax0, lmin0 = float(lmax_e), float(lmin_e)
-        smoothers.append(
-            chebyshev_apply(
-                ops[i],
-                base,
-                CHEB_SAFETY * lmax_e,
-                lmin=lo,
-                degree=smooth_degree,
+            if smoother == "schwarz":
+                base = make_schwarz_apply(
+                    probs[i],
+                    overlap=min(schwarz_overlap, probs[i].mesh.n_degree - 1),
+                    inner_degree=schwarz_inner_degree,
+                )
+            else:
+                base = dinv
+            lo, lmax_e, lmin_e = smoother_interval(
+                ops[i], base, v0, smoother=smoother, lanczos_iters=lanczos_iters
             )
-        )
+            if i == 0:
+                lmax0, lmin0 = float(lmax_e), float(lmin_e)
+            smoothers.append(
+                chebyshev_apply(
+                    ops[i],
+                    base,
+                    CHEB_SAFETY * lmax_e,
+                    lmin=lo,
+                    degree=smooth_degree,
+                )
+            )
 
     pc, opc = probs[-1], ops[-1]
-    if coarse_solve == "direct":
-        eye = jnp.eye(pc.n_global, dtype=dinv.dtype)
-        amat = jax.vmap(opc, in_axes=1, out_axes=1)(eye)
-        if pc.mask is not None:
-            # the masked coarse operator has zero rows/columns on Dirichlet
-            # DOFs; put 1 there so the inverse exists, then project the
-            # apply — exactly the subspace inverse, identity-free outside
-            amat = amat + jnp.diag(1.0 - pc.mask.astype(amat.dtype))
-        ainv = jnp.linalg.inv(amat)
-        if pc.mask is None:
-            coarse_apply = lambda r: jnp.dot(ainv, r, precision=_HI)
-        else:
-            coarse_apply = lambda r: pc.mask * jnp.dot(
-                ainv, pc.mask * r, precision=_HI
-            )
-    elif coarse_solve in ("chebyshev", "jacobi"):
-        dinv_c = masked_dinv(pc, assembled_diagonal(pc))
-        if coarse_solve == "chebyshev":
-            v0 = masked_seed(
-                pc, deterministic_seed_vector(pc.n_global, dinv_c.dtype)
-            )
-            lmin_e, lmax_e = lanczos_extremes(opc, dinv_c, v0, iters=lanczos_iters)
-            coarse_apply = chebyshev_apply(
-                opc,
-                dinv_c,
-                CHEB_SAFETY * lmax_e,
-                lmin=CHEB_LMIN_SAFETY * lmin_e,
-                degree=coarse_iters,
-            )
-        else:
+    with obs.span(f"setup.precond.l{len(probs) - 1}"):
+        if coarse_solve == "direct":
+            eye = jnp.eye(pc.n_global, dtype=dinv.dtype)
+            amat = jax.vmap(opc, in_axes=1, out_axes=1)(eye)
+            if pc.mask is not None:
+                # the masked coarse operator has zero rows/columns on Dirichlet
+                # DOFs; put 1 there so the inverse exists, then project the
+                # apply — exactly the subspace inverse, identity-free outside
+                amat = amat + jnp.diag(1.0 - pc.mask.astype(amat.dtype))
+            ainv = jnp.linalg.inv(amat)
+            if pc.mask is None:
+                coarse_apply = lambda r: jnp.dot(ainv, r, precision=_HI)
+            else:
+                coarse_apply = lambda r: pc.mask * jnp.dot(
+                    ainv, pc.mask * r, precision=_HI
+                )
+        elif coarse_solve in ("chebyshev", "jacobi"):
+            dinv_c = masked_dinv(pc, assembled_diagonal(pc))
+            if coarse_solve == "chebyshev":
+                v0 = masked_seed(
+                    pc, deterministic_seed_vector(pc.n_global, dinv_c.dtype)
+                )
+                lmin_e, lmax_e = lanczos_extremes(opc, dinv_c, v0, iters=lanczos_iters)
+                coarse_apply = chebyshev_apply(
+                    opc,
+                    dinv_c,
+                    CHEB_SAFETY * lmax_e,
+                    lmin=CHEB_LMIN_SAFETY * lmin_e,
+                    degree=coarse_iters,
+                )
+            else:
 
-            def coarse_apply(r: jax.Array) -> jax.Array:
-                # damped-Jacobi sweeps from z₀=0: a fixed polynomial in
-                # D⁻¹A, hence linear and symmetric like the other choices
-                z = (2.0 / 3.0) * dinv_c * r
-                for _ in range(coarse_iters - 1):
-                    z = z + (2.0 / 3.0) * dinv_c * (r - opc(z))
-                return z
+                def coarse_apply(r: jax.Array) -> jax.Array:
+                    # damped-Jacobi sweeps from z₀=0: a fixed polynomial in
+                    # D⁻¹A, hence linear and symmetric like the other choices
+                    z = (2.0 / 3.0) * dinv_c * r
+                    for _ in range(coarse_iters - 1):
+                        z = z + (2.0 / 3.0) * dinv_c * (r - opc(z))
+                    return z
 
-    else:
-        raise ValueError(
-            f"unknown pmg coarse_solve {coarse_solve!r}; "
-            "choose direct | chebyshev | jacobi"
-        )
+        else:
+            raise ValueError(
+                f"unknown pmg coarse_solve {coarse_solve!r}; "
+                "choose direct | chebyshev | jacobi"
+            )
 
     apply = make_vcycle(ops[:-1], smoothers, restricts, prolongs, coarse_apply)
     return apply, PrecondInfo(
@@ -990,6 +1000,7 @@ def precond_signature(kind: str, **kwargs) -> tuple:
     )
 
 
+@obs.spanned("setup.precond")
 def make_preconditioner(
     kind: str,
     prob,

@@ -17,6 +17,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .backend import default_interpret, native_dtype_ok
 from .blocks import block_matvec_pallas, pick_block_matvec_e
 from .poisson import pick_block_e, poisson_local_pallas
@@ -217,6 +218,7 @@ def make_poisson_assembled_fused(
     w_eff, lam_eff = screen_stream(prob)
     mask = prob.mask
 
+    @obs.scope("op.fused")
     def apply(x_g: jax.Array) -> jax.Array:
         if mask is not None:
             x_g = mask * x_g

@@ -21,8 +21,9 @@ Setup is cached across dispatches (:class:`core.solver_cache.SolverCache`):
 the first slab of a key pays the build (operator + preconditioner chain),
 every later slab — and every later *request batch* — reuses it untouched.
 Each dispatch appends a json-ready record with the cache hit/miss state,
-wall times and per-column iterations/status, so the batched-solve
-benchmark (and a service log) can assert the hit path did zero setup.
+wall times, the backend compiles made inside the dispatch (``repro.obs``)
+and per-column iterations/status, so the batched-solve benchmark (and a
+service log) can assert the hit path did zero setup.
 
 The seed's LLM decode engine this replaced lives on in
 ``repro.serving.lm`` (same slot-batching idea, token streams instead of
@@ -37,6 +38,7 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.cg import batched_cg_assembled, status_name
 from ..core.solver_cache import SolverCache, SolverSetup, solver_setup_key
 from ..kernels import ops
@@ -199,20 +201,25 @@ class SolverEngine:
         responses: list,
     ) -> None:
         req0 = reqs[0]
-        setup_key = solver_setup_key(
-            req0.prob, req0.kind, **dict(req0.precond)
-        )
-        state = "hit" if setup_key in self.cache else "miss"
-        setup = self.cache.get_or_build(
-            req0.prob, req0.kind, **dict(req0.precond)
-        )
-        b_block = jnp.stack([r.b for r in reqs])
-        t0 = time.perf_counter()
-        res = batched_cg_assembled(
-            setup.operator, b_block, **self._cg_kwargs(req0, setup)
-        )
-        jax.block_until_ready(res.x)
-        solve_s = time.perf_counter() - t0
+        with obs.span("engine.dispatch") as dispatch:
+            with obs.span("engine.setup_lookup"):
+                setup_key = solver_setup_key(
+                    req0.prob, req0.kind, **dict(req0.precond)
+                )
+                state = "hit" if setup_key in self.cache else "miss"
+                setup = self.cache.get_or_build(
+                    req0.prob, req0.kind, **dict(req0.precond)
+                )
+            with obs.span("engine.rhs_stack"):
+                b_block = jnp.stack([r.b for r in reqs])
+            t0 = time.perf_counter()
+            with obs.span("engine.solve"):
+                res = batched_cg_assembled(
+                    setup.operator, b_block, **self._cg_kwargs(req0, setup)
+                )
+            with obs.span("engine.block"):
+                jax.block_until_ready(res.x)
+            solve_s = time.perf_counter() - t0
 
         iters = [int(i) for i in res.iterations]
         stats = [int(s) for s in res.status]
@@ -235,6 +242,9 @@ class SolverEngine:
                 "setup_build_s": setup.build_s if state == "miss" else 0.0,
                 "solve_s": solve_s,
                 "per_solve_s": solve_s / len(reqs),
+                # backend compiles inside this dispatch (solve_s includes them)
+                "compiles": dispatch.compiles,
+                "compile_s": dispatch.compile_s,
                 "iterations": iters,
                 "status": stats,
                 "cache": self.cache.stats(),
