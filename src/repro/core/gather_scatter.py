@@ -13,20 +13,36 @@ Terminology follows the paper:
          the weighting for inner products on scattered vectors in the
          NekBone baseline.
 
-On TPU, Z is an XLA dynamic-gather (``take``) and Z^T a ``segment_sum``
-scatter-add — see DESIGN.md §3 for why the indirect load lives at the XLA
-level rather than inside the Pallas kernel.
+Two forms of Z and Z^T, the same operators:
+  indexed  (``scatter`` / ``gather``): an XLA ``take`` and a ``segment_sum``
+           scatter-add through the l2g map; any connectivity.
+  lattice  (``lattice_scatter`` / ``lattice_gather``): for the box mesh's
+           numbering (``mesh.lattice_l2g``, tested by ``is_lattice``), one
+           axis at a time as dense slices, pads and adds (the minor x axis
+           as a product with a 0/1 matrix), with no index.
+On TPU an indexed gather or scatter costs several ns per index, hundreds of
+times the HBM time of its bytes; the lattice form runs at HBM speed.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+
+from .mesh import lattice_l2g
+
+_HI = lax.Precision.HIGHEST
 
 __all__ = [
     "scatter",
     "gather",
     "gather_scatter",
+    "is_lattice",
+    "lattice_scatter",
+    "lattice_gather",
     "scatter_masked",
     "gather_masked",
     "inverse_degree",
@@ -49,6 +65,119 @@ def gather(y_l: jax.Array, l2g: jax.Array, n_global: int) -> jax.Array:
 def gather_scatter(y_l: jax.Array, l2g: jax.Array, n_global: int) -> jax.Array:
     """ZZ^T y_L — NekBone's combined gather-scatter on scattered vectors."""
     return scatter(gather(y_l, l2g, n_global), l2g)
+
+
+def is_lattice(l2g, shape: tuple[int, int, int], n_degree: int) -> bool:
+    """Whether ``l2g`` is exactly the box lattice numbering of (shape, N).
+
+    Host-side numpy, once at set-up: the lattice Z/Z^T pair may stand in for
+    the indexed one only where this holds.
+    """
+    l2g = np.asarray(l2g)
+    expect = lattice_l2g(shape, n_degree)
+    return l2g.shape == expect.shape and bool(np.array_equal(l2g, expect))
+
+
+def _expand(v: jax.Array, axis: int, ne: int, n: int) -> jax.Array:
+    """One axis of Z: (..., ne*N+1, ...) -> (..., ne, N+1, ...) windows.
+
+    Window e holds points e*N .. e*N+N: the first N of each are a reshape
+    of the leading ne*N points, the last is every N-th point from N.
+    """
+    lead, rest = v.shape[:axis], v.shape[axis + 1:]
+    head = lax.slice_in_dim(v, 0, ne * n, axis=axis)
+    tail = lax.slice_in_dim(v, n, ne * n + 1, stride=n, axis=axis)
+    return jnp.concatenate(
+        [head.reshape(lead + (ne, n) + rest), tail.reshape(lead + (ne, 1) + rest)],
+        axis=axis + 1,
+    )
+
+
+def _fold(w: jax.Array, axis: int, ne: int, n: int) -> jax.Array:
+    """One axis of Z^T, the adjoint of :func:`_expand`.
+
+    The last node of window e is the first of window e+1: it is added into
+    that column, the windows' first N nodes are flattened, and the last
+    window's last node is appended.
+    """
+    lead, rest = w.shape[:axis], w.shape[axis + 2:]
+    first = lax.slice_in_dim(w, 0, 1, axis=axis + 1)
+    last = lax.slice_in_dim(w, n, n + 1, axis=axis + 1)
+    carry = lax.pad(
+        lax.slice_in_dim(last, 0, ne - 1, axis=axis),
+        jnp.zeros((), w.dtype),
+        [(1, 0, 0) if d == axis else (0, 0, 0) for d in range(w.ndim)],
+    )
+    body = jnp.concatenate(
+        [first + carry, lax.slice_in_dim(w, 1, n, axis=axis + 1)], axis=axis + 1
+    )
+    return jnp.concatenate(
+        [
+            body.reshape(lead + (ne * n,) + rest),
+            lax.slice_in_dim(last, ne - 1, ne, axis=axis).reshape(lead + (1,) + rest),
+        ],
+        axis=axis,
+    )
+
+
+def _windows(ne: int, n: int, dtype) -> np.ndarray:
+    """:func:`_expand` of the minor axis as a 0/1 matrix (ne*N+1, ne*(N+1)).
+
+    Column e*(N+1) + a picks point e*N + a.  As a product its output rows
+    are ne*(N+1) wide and lane-dense, where a last axis of N+1 is not; each
+    output is one input times 1, so at ``Precision.HIGHEST`` it is exact.
+    """
+    col = np.arange(ne * (n + 1))
+    e, a = np.divmod(col, n + 1)
+    m = np.zeros((ne * n + 1, ne * (n + 1)), dtype)
+    m[e * n + a, col] = 1
+    return m
+
+
+# jitted so that an eager apply (set-up's Lanczos steps) compiles one program
+# per shape, not one per slice, pad and concatenation
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def lattice_scatter(
+    x_g: jax.Array, shape: tuple[int, int, int], n_degree: int
+) -> jax.Array:
+    """x_L = Z x_G on the box lattice. Shapes: x_G (N_G,) -> (E, p).
+
+    Equals ``scatter(x_g, lattice_l2g(shape, n_degree))`` exactly for
+    finite ``x_g`` (through the product a NaN or Inf spreads along x).  The
+    major axes (z, then y) expand by slices along leading dimensions, the
+    minor x axis last by a 0/1 matrix (:func:`_windows`); one transpose
+    then orders (k, j, i, c, b, a).
+    """
+    ex, ey, ez = shape
+    n = int(n_degree)
+    n1 = n + 1
+    v = x_g.reshape(ez * n + 1, ey * n + 1, ex * n + 1)
+    v = _expand(v, 0, ez, n)  # (k, c, gy, gx)
+    v = _expand(v, 2, ey, n)  # (k, c, j, b, gx)
+    v = jnp.matmul(v, _windows(ex, n, v.dtype), precision=_HI)  # (k, c, j, b, i a)
+    v = v.reshape(ez, n1, ey, n1, ex, n1).transpose(0, 2, 4, 1, 3, 5)
+    return v.reshape(ex * ey * ez, n1**3)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def lattice_gather(
+    y_l: jax.Array, shape: tuple[int, int, int], n_degree: int
+) -> jax.Array:
+    """b_G = Z^T y_L on the box lattice. Shapes: y_L (E, p) -> (N_G,).
+
+    Equals ``gather(y_l, lattice_l2g(shape, n_degree), N_G)`` up to the
+    order of each shared point's sum: :func:`lattice_scatter` in reverse,
+    x by the transposed 0/1 matrix, then y and z by :func:`_fold`.
+    """
+    ex, ey, ez = shape
+    n = int(n_degree)
+    n1 = n + 1
+    w = y_l.reshape(ez, ey, ex, n1, n1, n1).transpose(0, 3, 1, 4, 2, 5)
+    w = w.reshape(ez, n1, ey, n1, ex * n1)
+    w = jnp.matmul(w, _windows(ex, n, w.dtype).T, precision=_HI)  # (k, c, j, b, gx)
+    w = _fold(w, 2, ey, n)  # (k, c, gy, gx)
+    w = _fold(w, 0, ez, n)  # (gz, gy, gx)
+    return w.reshape(-1)
 
 
 def scatter_masked(x_g: jax.Array, l2g_ext: jax.Array) -> jax.Array:

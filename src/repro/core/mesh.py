@@ -21,6 +21,7 @@ __all__ = [
     "BoxMesh",
     "build_box_mesh",
     "dirichlet_mask",
+    "lattice_l2g",
     "normalize_bc",
     "partition_elements",
 ]
@@ -131,6 +132,29 @@ class BoxMesh:
         return (self.n_degree + 1) ** 3
 
 
+def lattice_l2g(shape: tuple[int, int, int], n_degree: int) -> np.ndarray:
+    """The box mesh's local-to-global map: the global point lattice.
+
+    Global points form the lattice ``(ez*N+1, ey*N+1, ex*N+1)``, x fastest.
+    Local node (a, b, c) of element (i, j, k) sits at lattice point
+    (i*N + a, j*N + b, k*N + c). Elements are numbered i + ex*(j + ey*k) and
+    local nodes a + (N+1)*(b + (N+1)*c), r fastest. Returns (E, p) int32.
+    """
+    ex, ey, ez = (int(s) for s in shape)
+    n = int(n_degree)
+    gx, gy = ex * n + 1, ey * n + 1
+
+    def axis(ne: int) -> np.ndarray:  # (element, local node) -> lattice index
+        return np.arange(ne)[:, None] * n + np.arange(n + 1)[None, :]
+
+    l2g = (
+        gx * gy * axis(ez)[:, None, None, :, None, None]
+        + gx * axis(ey)[None, :, None, None, :, None]
+        + axis(ex)[None, None, :, None, None, :]
+    )  # (k, j, i, c, b, a)
+    return l2g.reshape(ex * ey * ez, (n + 1) ** 3).astype(np.int32)
+
+
 def build_box_mesh(
     n_degree: int,
     shape: tuple[int, int, int],
@@ -178,28 +202,9 @@ def build_box_mesh(
     py = axis_nodes(ey, extent[1])
     pz = axis_nodes(ez, extent[2])
 
-    # Local-to-global map. Local node (a, b, c) of element (i, j, k) sits at
-    # global grid point (i*N + a, j*N + b, k*N + c). Local flat index is
-    # a + (N+1)*(b + (N+1)*c)  (r fastest), element flat index i + ex*(j + ey*k).
-    a = np.arange(n + 1)
-    la, lb, lc = np.meshgrid(a, a, a, indexing="ij")  # (r, s, t)
-    # local flat ordering: c slow, b mid, a fast
-    loc_a = la.transpose(2, 1, 0).reshape(-1)
-    loc_b = lb.transpose(2, 1, 0).reshape(-1)
-    loc_c = lc.transpose(2, 1, 0).reshape(-1)
-
-    ei, ej, ek = np.meshgrid(
-        np.arange(ex), np.arange(ey), np.arange(ez), indexing="ij"
-    )
-    # element flat ordering: k slow, j mid, i fast
-    ei = ei.transpose(2, 1, 0).reshape(-1)
-    ej = ej.transpose(2, 1, 0).reshape(-1)
-    ek = ek.transpose(2, 1, 0).reshape(-1)
-
-    gxi = ei[:, None] * n + loc_a[None, :]
-    gyj = ej[:, None] * n + loc_b[None, :]
-    gzk = ek[:, None] * n + loc_c[None, :]
-    l2g = (gxi + gx * (gyj + gy * gzk)).astype(np.int32)
+    # Local-to-global map and each local node's lattice point per axis.
+    l2g = lattice_l2g((ex, ey, ez), n)
+    gxi, gyj, gzk = l2g % gx, l2g // gx % gy, l2g // (gx * gy)
 
     coords = np.stack(
         [px[gxi], py[gyj], pz[gzk]], axis=-1

@@ -25,7 +25,15 @@ import numpy as np
 from .. import obs
 from . import coefficients as _coef
 from . import geometry, sem
-from .gather_scatter import gather, gather_scatter, inverse_degree, scatter
+from .gather_scatter import (
+    gather,
+    gather_scatter,
+    inverse_degree,
+    is_lattice,
+    lattice_gather,
+    lattice_scatter,
+    scatter,
+)
 from .mesh import BoxMesh, build_box_mesh, dirichlet_mask, normalize_bc
 
 
@@ -365,8 +373,13 @@ def poisson_assembled(
     """hipBone operator: x_G (N_G,) -> A x_G (N_G,).
 
     Split form (the default): y_L = (S_L + λW) Z x_G, then the gather
-    Z^T y_L — three XLA ops.  ``local_op`` lets callers swap in the Pallas
-    element kernel for the middle stage; default is the pure-jnp reference.
+    Z^T y_L.  ``local_op`` lets callers swap in the Pallas element kernel
+    for the middle stage; default is the pure-jnp reference.  Z and Z^T
+    are the lattice pair (dense slices, pads, adds and a 0/1 product along
+    x) when ``prob.l2g`` is the box lattice numbering, else the indexed
+    pair (``take`` and ``segment_sum``); ``apply.assembly`` says which
+    ("lattice" or "indexed"), and ``obs.tallies()`` counts the operators
+    built of each.
 
     ``fused`` selects the single-kernel form instead
     (``kernels.ops.poisson_assembled_fused``): gather, local operator and
@@ -393,24 +406,41 @@ def poisson_assembled(
             )
         from ..kernels import ops as _kops  # lazy: kernels import core
 
-        return _kops.make_poisson_assembled_fused(prob, **(fused_kwargs or {}))
+        apply = _kops.make_poisson_assembled_fused(prob, **(fused_kwargs or {}))
+    else:
+        apply = _split_assembled(prob, local_op or local_poisson)
+    obs.tally(f"op.assembly.{apply.assembly}")
+    return apply
 
-    op = local_op or local_poisson
+
+def _split_assembled(
+    prob: PoissonProblem, op: Callable[..., jax.Array]
+) -> Callable[[jax.Array], jax.Array]:
+    """The split apply Z^T (S_L + λW) Z of :func:`poisson_assembled`."""
     w_eff, lam_eff = screen_stream(prob)
     mask = prob.mask
+    shape, n = prob.mesh.shape, prob.mesh.n_degree
+    lattice = is_lattice(prob.l2g, shape, n)
+    if lattice:
+        z = lambda x_g: lattice_scatter(x_g, shape, n)
+        zt = lambda y_l: lattice_gather(y_l, shape, n)
+    else:
+        z = lambda x_g: scatter(x_g, prob.l2g)
+        zt = lambda y_l: gather(y_l, prob.l2g, prob.n_global)
 
     def apply(x_g: jax.Array) -> jax.Array:
         with obs.scope("op.scatter"):
             if mask is not None:
                 x_g = mask * x_g
-            x_l = scatter(x_g, prob.l2g)
+            x_l = z(x_g)
         with obs.scope("op.local"):
             y_l = op(x_l, prob.g, prob.d, lam_eff, w_eff)
         with obs.scope("op.gather"):
-            y_g = gather(y_l, prob.l2g, prob.n_global)
+            y_g = zt(y_l)
             return y_g if mask is None else mask * y_g
 
     apply.fused = False
+    apply.assembly = "lattice" if lattice else "indexed"
     return apply
 
 

@@ -236,6 +236,7 @@ def make_poisson_assembled_fused(
         return y_g if mask is None else mask * y_g
 
     apply.fused = True
+    apply.assembly = "indexed"  # in-kernel gather and scatter-add
     return apply
 
 

@@ -28,9 +28,10 @@ TPU adaptation of the paper's CUDA/HIP operator kernel (DESIGN.md §3):
 * The GPU occupancy knob (registers/warp) becomes the VMEM-footprint knob
   ``block_e``, swept in benchmarks/table1_blocks.py.
 
-The scatter Z (indirect read of x_G) happens outside at the XLA level —
-TPU has no efficient per-lane random HBM gather inside a kernel; XLA's
-dynamic-gather already streams it (DESIGN.md §3).
+The scatter Z (read of x_G) happens outside at the XLA level — TPU has
+no efficient per-lane random HBM gather inside a kernel. On a box mesh Z
+is dense lattice slices (``core.gather_scatter.lattice_scatter``), else
+XLA's ``take``.
 """
 from __future__ import annotations
 

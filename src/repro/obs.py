@@ -17,8 +17,11 @@ event, which a persistent-cache hit also fires) and persistent-cache hits
 are counted under the innermost open span (``counters()``), and each open
 span counts those that happen while it is open.
 
-Every name is declared in ``SCOPES`` or ``SPANS``; a name with ``{i}`` is a
-family, one name per level.  The tables are the contract that trace
+Host tallies.  ``tally(name)`` counts a set-up event the program can
+observe, such as which assembly an operator was built with (``tallies()``).
+
+Every name is declared in ``SCOPES``, ``SPANS`` or ``TALLIES``; a name with
+``{i}`` is a family, one name per level.  The tables are the contract that trace
 reductions and metrics read.
 """
 from __future__ import annotations
@@ -32,8 +35,8 @@ from typing import NamedTuple
 
 import jax
 
-__all__ = ["PREFIX", "SCOPES", "SPANS", "Span", "counters", "reset", "scope", "span",
-           "spanned", "spans"]
+__all__ = ["PREFIX", "SCOPES", "SPANS", "TALLIES", "Span", "counters", "reset", "scope",
+           "span", "spanned", "spans", "tallies", "tally"]
 
 PREFIX = "hb."  # no name-stack entry of JAX's own starts with it
 
@@ -45,9 +48,11 @@ SCOPES = {
     "cg.operator": "core/cg.py _pcg: every A-apply of the Krylov recurrence",
     "cg.precond": "core/cg.py _pcg: every z = M^-1 r",
     "cg.allreduce": "core/distributed.py: every psum of the recurrence scalars",
-    "op.scatter": "core/operator.py poisson_assembled: x_L = Z x_G (take)",
+    "op.scatter": "core/operator.py poisson_assembled: x_L = Z x_G (lattice slices "
+                  "and a 0/1 product on box meshes, else take)",
     "op.local": "core/operator.py poisson_assembled: the element operator, XLA or Pallas",
-    "op.gather": "core/operator.py poisson_assembled: Z^T y_L (segment sum)",
+    "op.gather": "core/operator.py poisson_assembled: Z^T y_L (lattice 0/1 product, "
+                 "slices, pads and adds on box meshes, else segment sum)",
     "op.fused": "kernels/ops.py: the fused assembled operator, one Pallas pass",
     "op.scattered": "core/operator.py poisson_scattered: (Z Z^T S_L + lambda) x_L",
     "pmg.l{i}": "core/precond.py V-cycle: level i's own work (smoothing, residual, "
@@ -84,12 +89,21 @@ SPANS = {
 }
 
 
+# host tallies (tally) -> what each counts
+TALLIES = {
+    "op.assembly.lattice": "core/operator.py poisson_assembled: operators built "
+                           "with the lattice Z and Z^T",
+    "op.assembly.indexed": "core/operator.py poisson_assembled: operators built "
+                           "with the indexed Z and Z^T (take, segment sum)",
+}
+
+
 def _pattern(table: dict) -> re.Pattern:
     alts = (re.escape(n).replace(re.escape("{i}"), r"\d+") for n in table)
     return re.compile("|".join(alts))
 
 
-_SCOPE_RE, _SPAN_RE = _pattern(SCOPES), _pattern(SPANS)
+_SCOPE_RE, _SPAN_RE, _TALLY_RE = _pattern(SCOPES), _pattern(SPANS), _pattern(TALLIES)
 
 
 def _check(name: str, pattern: re.Pattern, table: str) -> str:
@@ -116,6 +130,8 @@ class Span(NamedTuple):
 MAX_SPANS = 4096
 _spans: collections.deque = collections.deque(maxlen=MAX_SPANS)
 _counts: dict = {}
+_tallies: collections.Counter = collections.Counter()
+_tallies_lock = threading.Lock()
 _local = threading.local()
 
 
@@ -181,10 +197,25 @@ def counters() -> dict:
     return {k: dict(v) for k, v in _counts.items()}
 
 
+def tally(name: str) -> None:
+    """Count one more of the declared tally ``name``."""
+    _check(name, _TALLY_RE, "TALLIES")
+    with _tallies_lock:
+        _tallies[name] += 1
+
+
+def tallies() -> dict:
+    """``{tally name: count}`` of every tally counted since the last reset."""
+    with _tallies_lock:
+        return dict(_tallies)
+
+
 def reset():
-    """Forget every recorded span and count."""
+    """Forget every recorded span, count and tally."""
     _spans.clear()
     _counts.clear()
+    with _tallies_lock:
+        _tallies.clear()
 
 
 # ------------------------------------------------------------ compile counter

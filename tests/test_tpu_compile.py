@@ -1,5 +1,7 @@
 """Compile the main-path Pallas kernels natively for a described TPU v5e.
 
+The operator's lattice Z and Z^T (XLA, no kernel) are compiled here too.
+
 Nothing runs: each test lowers a kernel with ``interpret=False`` at the
 deployment shapes of the hipBone presets and compiles it for one chip of
 a described ``v5e:2x2`` topology, so what Mosaic refuses (lane-splitting
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.gather_scatter import lattice_gather, lattice_scatter
 from repro.kernels import ops
 
 N7, N7_LARGE = 57**3, 113**3  # n_global of hipbone_n7 / hipbone_n7_large
@@ -72,6 +75,21 @@ def test_element_kernel_compiles(one_chip, n_degree, n_elements):
         ((n_elements, p), "float32"),
         ((n1, n1), "float32"),
     )
+
+
+@pytest.mark.parametrize("n_degree,shape", [(7, (16, 16, 16)), (15, (8, 8, 8))])
+def test_lattice_pair_compiles(one_chip, n_degree, shape):
+    ex, ey, ez = shape
+    n_global = (ex * n_degree + 1) * (ey * n_degree + 1) * (ez * n_degree + 1)
+    local = (ex * ey * ez, (n_degree + 1) ** 3)
+    for fn, arg in (
+        (lambda x: lattice_scatter(x, shape, n_degree), (n_global,)),
+        (lambda y: lattice_gather(y, shape, n_degree), local),
+    ):
+        hlo = jax.jit(fn).lower(
+            jax.ShapeDtypeStruct(arg, jnp.float32, sharding=one_chip)
+        ).compile().as_text()
+        assert " gather(" not in hlo and " scatter(" not in hlo
 
 
 @pytest.mark.parametrize("n", [N7, N7_LARGE])
