@@ -22,9 +22,9 @@ Phases on one chip:
   (d) pMG with materialized Galerkin coarse operators at hipbone_n7 size:
       the Pallas block matvec takes the einsum's iteration count;
   (e) mixed precision, hipbone_n7_pmg_fp32: fp64 outer loop, fp32 chain.
-With ``--chips 4``: ``dist_cg`` on a factor3(4) grid with hipbone_n7
-elements per chip, NekBone mode and pMG-galerkin_mat to 1e-6, each against
-the single-device solve of the same global problem.
+With ``--chips 4``: ``dist_solver`` (``dist_cg``'s solve) on a factor3(4)
+grid with hipbone_n7 elements per chip, NekBone mode and pMG-galerkin_mat
+to 1e-6, each against the single-device solve of the same global problem.
 
 Every Pallas call of a checked program is listed with its ``interpret``
 flag (all must be False) and operand dtypes.  Times printed here are smoke
@@ -317,7 +317,7 @@ def phase_mixed(dev) -> None:
 
 
 def phase_sharded(devices) -> None:
-    """--chips 4: dist_cg on 4 chips vs the single-device solve."""
+    """--chips 4: dist_solver on 4 chips vs the single-device solve."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -327,9 +327,9 @@ def phase_sharded(devices) -> None:
     from repro.configs.hipbone import CONFIGS
     from repro.core import build_problem, cg_assembled, poisson_assembled
     from repro.core.distributed import (
-        _box_global_indices,
+        box_global_indices,
         build_dist_problem,
-        dist_cg,
+        dist_solver,
     )
     from repro.core.precond import make_preconditioner
 
@@ -341,8 +341,8 @@ def phase_sharded(devices) -> None:
     dprob = build_dist_problem(cfg.n_degree, grid, cfg.local_elems,
                                lam=cfg.lam, dtype=jnp.float32)
     ref = build_problem(cfg.n_degree, gshape, lam=cfg.lam, dtype=jnp.float32)
-    idx = _box_global_indices(dprob)
-    print(f"(sharded) dist_cg on grid {grid.shape}, {cfg.local_elems} "
+    idx = box_global_indices(dprob)
+    print(f"(sharded) dist_solver on grid {grid.shape}, {cfg.local_elems} "
           f"elements per chip, N={cfg.n_degree}; global {gshape} "
           f"dofs={ref.n_global}", flush=True)
     bg = np.random.default_rng(0).standard_normal(ref.n_global)
@@ -362,8 +362,8 @@ def phase_sharded(devices) -> None:
     }
     for label, (dkw, skw) in cases.items():
         t0 = time.perf_counter()
-        run = jax.jit(dist_cg(dprob, mesh, b_boxes, **dkw))
-        x_boxes, rdotr, iters, status, _ = run()
+        solve = dist_solver(dprob, mesh, **dkw)
+        x_boxes, rdotr, iters, status, _ = solve(b_boxes)
         jax.block_until_ready(x_boxes)
         d_s = time.perf_counter() - t0
         shard_devs = {s.device for s in x_boxes.addressable_shards}

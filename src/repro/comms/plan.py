@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..compat import shard_map
 from ..core.solver_cache import content_signature
 from . import halo
@@ -60,6 +61,7 @@ __all__ = [
     "default_policy",
     "plan_cache_dir",
     "resolve_routing",
+    "tally_routes",
 ]
 
 POLICIES = ("auto", "face_sweep", "crystal", "fused")
@@ -181,6 +183,12 @@ class ExchangePlan:
              "signature": self.signature, "from_cache": self.from_cache}
             for k in sorted(self.sites)
         ]
+
+
+def tally_routes(plan: ExchangePlan, sites: list[ExchangeSite]) -> None:
+    """Count each site under the routing ``plan`` gives it (``xch.route.*``)."""
+    for site in sites:
+        obs.tally("xch.route." + plan.lookup(site.kind, site.level)[0])
 
 
 def _forced_plan(policy: str, signature: str = "") -> ExchangePlan:

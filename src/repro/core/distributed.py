@@ -99,12 +99,14 @@ _HI = jax.lax.Precision.HIGHEST
 
 __all__ = [
     "DistPoisson",
+    "box_global_indices",
     "build_dist_problem",
     "build_pmg_levels",
     "build_pmg_galerkin_blocks",
     "dist_cg",
     "dist_cg_scattered",
     "dist_lambda_max",
+    "dist_solver",
     "dist_spectrum",
 ]
 
@@ -359,6 +361,7 @@ def build_dist_problem(
     bc: Any = None,
     k: np.ndarray | None = None,
     lam_field: np.ndarray | None = None,
+    mesh: jax.sharding.Mesh | None = None,
 ) -> DistPoisson:
     """Build the sharded screened-Poisson problem.
 
@@ -396,6 +399,10 @@ def build_dist_problem(
         never see it; λ(x) switches every A-apply to the weak mass screen
         ``JW·λ`` riding the w stream (``DistPoisson.screen``), which needs
         node coordinates (or the regular mesh) for the JW weights.
+      mesh: optional device mesh of the ranks.  Given, every per-rank array
+        is uploaded laid out over it (rank r's slab on rank r's device),
+        the layout the solvers place them in, so that no device ever holds
+        every rank's arrays; otherwise they land on the default device.
 
     Returns:
       A :class:`DistPoisson`; per-rank padded box shape is
@@ -403,113 +410,122 @@ def build_dist_problem(
     """
     n = n_degree
     bx, by, bz = local_shape
-    l2g, halo = _local_l2g(n, local_shape)
-    mask, w_local = _rank_data(grid, n, local_shape, l2g)
+    with obs.span("setup.build_dist_problem"):
+        with obs.span("setup.dist.rank_data"):
+            l2g, halo = _local_l2g(n, local_shape)
+            mask, w_local = _rank_data(grid, n, local_shape, l2g)
 
-    e_loc = bx * by * bz
-    p = (n + 1) ** 3
-    regular = g_factors is None and coords is None
-    jw = None
-    if coords is not None:
-        geo = geometric_factors_from_coords(
-            coords.reshape(grid.size * e_loc, p, 3), n
-        )
-        jw = geo["JW"].reshape(grid.size, e_loc, p)
-        if g_factors is None:
-            g_factors = geo["G"].reshape(grid.size, e_loc, 6, p)
-    if g_factors is None:
-        # regular mesh: every element congruent; element size = 1/(P_d*b_d)
-        from .geometry import geometric_factors
-        from .mesh import build_box_mesh
-
-        ref_mesh = build_box_mesh(
-            n,
-            (1, 1, 1),
-            extent=(
-                1.0 / (grid.shape[0] * bx),
-                1.0 / (grid.shape[1] * by),
-                1.0 / (grid.shape[2] * bz),
-            ),
-        )
-        geo_one = geometric_factors(ref_mesh)
-        g_one = geo_one["G"][0]  # (6, p)
-        g_factors = np.broadcast_to(
-            g_one, (grid.size, e_loc, 6, g_one.shape[-1])
-        )
-        jw = np.broadcast_to(geo_one["JW"][0], (grid.size, e_loc, p))
-
-    if coefficient is not None:
-        if k is not None or lam_field is not None:
-            raise ValueError(
-                "pass either coefficient= or explicit k/lam_field, not both"
-            )
-        node_coords = coords
-        if node_coords is None:
-            if not regular:
-                raise ValueError(
-                    "coefficient evaluation needs node coordinates; pass "
-                    "coords= alongside bare g_factors"
+            e_loc = bx * by * bz
+            p = (n + 1) ** 3
+            regular = g_factors is None and coords is None
+            jw = None
+            if coords is not None:
+                geo = geometric_factors_from_coords(
+                    coords.reshape(grid.size * e_loc, p, 3), n
                 )
-            node_coords = _regular_box_coords(grid, n, local_shape)
-        k, lam_field = coefficient_fields(
-            coefficient, node_coords.reshape(grid.size * e_loc, p, 3), lam
-        )
-        if k is not None:
-            k = k.reshape(grid.size, e_loc, p)
-        if lam_field is not None:
-            lam_field = lam_field.reshape(grid.size, e_loc, p)
+                jw = geo["JW"].reshape(grid.size, e_loc, p)
+                if g_factors is None:
+                    g_factors = geo["G"].reshape(grid.size, e_loc, 6, p)
+            if g_factors is None:
+                # regular mesh: every element congruent; element size = 1/(P_d*b_d)
+                from .geometry import geometric_factors
+                from .mesh import build_box_mesh
 
-    if k is not None:
-        k = np.asarray(k, np.float64)
-        if k.shape != (grid.size, e_loc, p):
-            raise ValueError(
-                f"k must have shape {(grid.size, e_loc, p)}, got {k.shape}"
-            )
-        # fold k into the packed factors: DᵀGD then discretizes -∇·(k∇·)
-        g_factors = np.asarray(g_factors) * k[:, :, None, :]
-    screen = None
-    if lam_field is not None:
-        lam_field = np.asarray(lam_field, np.float64)
-        if lam_field.shape != (grid.size, e_loc, p):
-            raise ValueError(
-                f"lam_field must have shape {(grid.size, e_loc, p)}, "
-                f"got {lam_field.shape}"
-            )
-        if jw is None:
-            raise ValueError(
-                "lam_field needs node coordinates (or the regular mesh) to "
-                "form the JW mass weights of the weak screen; pass coords="
-            )
-        screen = jnp.asarray(np.asarray(jw) * lam_field, dtype)
+                ref_mesh = build_box_mesh(
+                    n,
+                    (1, 1, 1),
+                    extent=(
+                        1.0 / (grid.shape[0] * bx),
+                        1.0 / (grid.shape[1] * by),
+                        1.0 / (grid.shape[2] * bz),
+                    ),
+                )
+                geo_one = geometric_factors(ref_mesh)
+                g_one = geo_one["G"][0]  # (6, p)
+                g_factors = np.broadcast_to(
+                    g_one, (grid.size, e_loc, 6, g_one.shape[-1])
+                )
+                jw = np.broadcast_to(geo_one["JW"][0], (grid.size, e_loc, p))
 
-    tags = normalize_bc(bc)
-    bc_mask = _box_dirichlet_mask(grid, n, local_shape, tags)
+            if coefficient is not None:
+                if k is not None or lam_field is not None:
+                    raise ValueError(
+                        "pass either coefficient= or explicit k/lam_field, not both"
+                    )
+                node_coords = coords
+                if node_coords is None:
+                    if not regular:
+                        raise ValueError(
+                            "coefficient evaluation needs node coordinates; pass "
+                            "coords= alongside bare g_factors"
+                        )
+                    node_coords = _regular_box_coords(grid, n, local_shape)
+                k, lam_field = coefficient_fields(
+                    coefficient, node_coords.reshape(grid.size * e_loc, p, 3), lam
+                )
+                if k is not None:
+                    k = k.reshape(grid.size, e_loc, p)
+                if lam_field is not None:
+                    lam_field = lam_field.reshape(grid.size, e_loc, p)
 
-    d = sem.derivative_matrix(n)
-    return DistPoisson(
-        grid=grid,
-        axis_name=axis_name,
-        n_degree=n,
-        local_shape=local_shape,
-        box_shape=(bx * n + 1, by * n + 1, bz * n + 1),
-        lam=float(lam),
-        halo_elems=halo,
-        l2g=l2g,
-        d=jnp.asarray(d, dtype),
-        g=jnp.asarray(g_factors, dtype),
-        w_local=jnp.asarray(w_local, dtype),
-        mask=jnp.asarray(mask, dtype),
-        dtype=dtype,
-        coords=coords,
-        regular=regular,
-        k=k,
-        lam_field=lam_field,
-        screen=screen,
-        bc=tags,
-        bc_mask=(
-            None if bc_mask is None else jnp.asarray(bc_mask, dtype)
-        ),
-    )
+            if k is not None:
+                k = np.asarray(k, np.float64)
+                if k.shape != (grid.size, e_loc, p):
+                    raise ValueError(
+                        f"k must have shape {(grid.size, e_loc, p)}, got {k.shape}"
+                    )
+                # fold k into the packed factors: DᵀGD then discretizes -∇·(k∇·)
+                g_factors = np.asarray(g_factors) * k[:, :, None, :]
+            screen = None
+            if lam_field is not None:
+                lam_field = np.asarray(lam_field, np.float64)
+                if lam_field.shape != (grid.size, e_loc, p):
+                    raise ValueError(
+                        f"lam_field must have shape {(grid.size, e_loc, p)}, "
+                        f"got {lam_field.shape}"
+                    )
+                if jw is None:
+                    raise ValueError(
+                        "lam_field needs node coordinates (or the regular mesh) to "
+                        "form the JW mass weights of the weak screen; pass coords="
+                    )
+                screen = np.asarray(jw) * lam_field
+
+            tags = normalize_bc(bc)
+            bc_mask = _box_dirichlet_mask(grid, n, local_shape, tags)
+
+            d = sem.derivative_matrix(n)
+        with obs.span("setup.dist.upload"):
+            if mesh is None:
+                upload = functools.partial(jnp.asarray, dtype=dtype)
+            else:
+                ranks = jax.sharding.NamedSharding(mesh, P(axis_name))
+
+                def upload(a):
+                    return jax.device_put(np.asarray(a, dtype), ranks)
+
+            return DistPoisson(
+                grid=grid,
+                axis_name=axis_name,
+                n_degree=n,
+                local_shape=local_shape,
+                box_shape=(bx * n + 1, by * n + 1, bz * n + 1),
+                lam=float(lam),
+                halo_elems=halo,
+                l2g=l2g,
+                d=jnp.asarray(d, dtype),
+                g=upload(g_factors),
+                w_local=upload(w_local),
+                mask=upload(mask),
+                dtype=dtype,
+                coords=coords,
+                regular=regular,
+                k=k,
+                lam_field=lam_field,
+                screen=None if screen is None else upload(screen),
+                bc=tags,
+                bc_mask=None if bc_mask is None else upload(bc_mask),
+            )
 
 
 def build_pmg_levels(
@@ -794,6 +810,95 @@ def _apply_assembled(
     return box_h + box_i
 
 
+def _rank_operator(
+    prob: DistPoisson,
+    g1: jax.Array,
+    w1: jax.Array,
+    *,
+    screen: jax.Array | None,
+    bc_mask: jax.Array | None,
+    local_op: Callable[..., jax.Array],
+    two_phase: bool,
+    fused_interior: bool,
+    xsum: tuple,
+    xcopy: tuple,
+) -> Callable[[jax.Array], jax.Array]:
+    """The outer A-apply on one rank's consistent padded box (inside shard_map).
+
+    ``_apply_assembled``'s Fig. 2 split with the exchange plan's picks,
+    wrapped as mask∘A∘mask on the Dirichlet subspace when ``bc_mask`` is
+    given.  ``dist_solver``'s Krylov operator and ``solve.operator`` are
+    both this one construction.
+    """
+    def apply(v: jax.Array) -> jax.Array:
+        return _apply_assembled(
+            prob, v, g1, w1, local_op=local_op, two_phase=two_phase,
+            fused_interior=fused_interior, xsum=xsum, xcopy=xcopy,
+            screen=screen,
+        )
+
+    if bc_mask is None:
+        return apply
+    return lambda v: bc_mask * apply(bc_mask * v)
+
+
+def _resolve_fused(local_op, fused_operator: bool | None) -> bool:
+    """``fused_operator=None``: the kernel policy, unless ``local_op`` pins the split."""
+    if fused_operator is not None:
+        return fused_operator
+    if local_op is not None:
+        return False
+    from ..kernels import ops as _kops  # lazy: kernels import core
+
+    return _kops.should_fuse_operator()
+
+
+def _aux_operands(prob: DistPoisson) -> tuple:
+    """The fine level's optional sharded arrays: (screen?, bc_mask?)."""
+    return tuple(x for x in (prob.screen, prob.bc_mask) if x is not None)
+
+
+def _aux_split(prob: DistPoisson, aux_s: tuple) -> tuple:
+    """This rank's (screen, bc_mask) out of ``_aux_operands``' shards."""
+    s1 = aux_s[0][0] if prob.screen is not None else None
+    bcm1 = aux_s[1 if s1 is not None else 0][0] if prob.bc_mask is not None else None
+    return s1, bcm1
+
+
+def _exchange_plan(
+    mesh: jax.sharding.Mesh,
+    prob: DistPoisson,
+    sites: list,
+    *,
+    exchange: str | None,
+    wire: str,
+    plan: Any,
+):
+    """The exchange plan of one solver, under ``setup.exchange_plan``.
+
+    A given ``plan`` is used as it is; otherwise it is resolved for
+    ``sites``.  Each site is counted under its route (``xch.route.*``).
+    """
+    with obs.span("setup.exchange_plan"):
+        if plan is None:
+            plan = xplan.build_exchange_plan(
+                mesh, prob.grid, prob.axis_name, sites,
+                policy=exchange, wire=wire,
+            )
+        xplan.tally_routes(plan, sites)
+    return plan
+
+
+def _place(mesh: jax.sharding.Mesh, spec: P, tree: Any) -> Any:
+    """Concrete arrays of ``tree`` laid out over ``mesh`` by ``spec`` (leading
+    axis over the ranks); abstract shapes (dry-run lowering) pass through."""
+    sharding = jax.sharding.NamedSharding(mesh, spec)
+    return jax.tree.map(
+        lambda a: jax.device_put(a, sharding) if isinstance(a, jax.Array) else a,
+        tree,
+    )
+
+
 def _psum(axis_name: str) -> Callable[[jax.Array], jax.Array]:
     """All-reduce of the recurrence scalars over the ranks, under its scope."""
 
@@ -804,7 +909,7 @@ def _psum(axis_name: str) -> Callable[[jax.Array], jax.Array]:
     return psum
 
 
-def _box_global_indices(prob: DistPoisson) -> np.ndarray:
+def box_global_indices(prob: DistPoisson) -> np.ndarray:
     """(R, m3) flat *global* DOF index of every padded-box slot (numpy).
 
     Replica slots on different ranks map to the same global index, so any
@@ -1187,19 +1292,16 @@ def dist_spectrum(
     """
     op = local_op or local_poisson
     spec = P(prob.axis_name)
-    seed_boxes = jnp.asarray(seed_values(_box_global_indices(prob)), prob.dtype)
+    seed_boxes = jnp.asarray(seed_values(box_global_indices(prob)), prob.dtype)
     if prob.bc_mask is not None:
         # Dirichlet: estimate on the interior subspace — masked seed, no
         # null-space pollution (mirrors precond.masked_seed)
         seed_boxes = seed_boxes * prob.bc_mask.astype(seed_boxes.dtype)
-    aux = tuple(x for x in (prob.screen, prob.bc_mask) if x is not None)
-    has_screen = prob.screen is not None
-    has_bc = prob.bc_mask is not None
+    aux = _aux_operands(prob)
 
     def shard_fn(g_s, w_s, mask_s, seed_s, aux_s):
         g1, w1, m1 = g_s[0], w_s[0], mask_s[0]
-        s1 = aux_s[0][0] if has_screen else None
-        bcm1 = aux_s[1 if has_screen else 0][0] if has_bc else None
+        s1, bcm1 = _aux_split(prob, aux_s)
         base = lambda v: _apply_assembled(
             prob, v, g1, w1, local_op=op, two_phase=two_phase, screen=s1
         )
@@ -1243,17 +1345,14 @@ def dist_lambda_max(
     program (keeps benchmark timings pure solve)."""
     op = local_op or local_poisson
     spec = P(prob.axis_name)
-    seed_boxes = jnp.asarray(seed_values(_box_global_indices(prob)), prob.dtype)
+    seed_boxes = jnp.asarray(seed_values(box_global_indices(prob)), prob.dtype)
     if prob.bc_mask is not None:
         seed_boxes = seed_boxes * prob.bc_mask.astype(seed_boxes.dtype)
-    aux = tuple(x for x in (prob.screen, prob.bc_mask) if x is not None)
-    has_screen = prob.screen is not None
-    has_bc = prob.bc_mask is not None
+    aux = _aux_operands(prob)
 
     def shard_fn(g_s, w_s, mask_s, seed_s, aux_s):
         g1, w1, m1 = g_s[0], w_s[0], mask_s[0]
-        s1 = aux_s[0][0] if has_screen else None
-        bcm1 = aux_s[1 if has_screen else 0][0] if has_bc else None
+        s1, bcm1 = _aux_split(prob, aux_s)
         base = lambda v: _apply_assembled(
             prob, v, g1, w1, local_op=op, two_phase=two_phase, screen=s1
         )
@@ -1285,9 +1384,30 @@ def dist_lambda_max(
 
 
 def dist_cg(
+    prob: DistPoisson, mesh: jax.sharding.Mesh, b: jax.Array, **kwargs
+):
+    """Distributed hipBone (P)CG of one right-hand side over the device mesh.
+
+    ``b`` is the (R, m3) sharded right-hand side boxes (made consistent
+    inside); every keyword argument is :func:`dist_solver`'s, which runs
+    the solve.
+
+    Returns:
+      A jitted-callable partial () -> (x, rdotr, iterations, status,
+      history): :func:`dist_solver`'s program with ``b`` bound — also
+      usable for dry-run lowering via ``jax.jit(run.func).lower(*run.args)``
+      — with the resolved plan as ``run.exchange_plan``.  A caller with
+      many right-hand sides uses :func:`dist_solver` itself.
+    """
+    solve = dist_solver(prob, mesh, **kwargs)
+    run = functools.partial(solve.program, b, *solve.operands)
+    run.exchange_plan = solve.exchange_plan
+    return run
+
+
+def dist_solver(
     prob: DistPoisson,
     mesh: jax.sharding.Mesh,
-    b: jax.Array,
     *,
     n_iter: int = 100,
     tol: float | None = None,
@@ -1318,12 +1438,16 @@ def dist_cg(
     stagnation_rtol: float = STAGNATION_RTOL,
     per_rank_stats: bool = False,
 ):
-    """Distributed hipBone (P)CG over the device mesh.
+    """Distributed hipBone (P)CG over the device mesh, compiled once for
+    every right-hand side.
+
+    Time-stepping callers solve many right-hand sides on one operator and
+    preconditioner; this is their entry point.  :func:`dist_cg` is the
+    same solve of one bound ``b``.
 
     Args:
       prob: the sharded problem (``build_dist_problem``).
       mesh: jax device mesh whose flattened size equals ``prob.grid.size``.
-      b: (R, m3) sharded right-hand side boxes (made consistent here).
       n_iter: iteration cap (NekBone's fixed count when ``tol`` is None).
       tol: optional relative-residual stopping threshold (while_loop mode).
       precond: "none" | "jacobi" | "chebyshev" | "schwarz" | "pmg".
@@ -1345,8 +1469,8 @@ def dist_cg(
         inverse degree), and each coarse apply is one batched element
         matvec riding the standard halo/interior split + sum-exchange
         (``_box_galerkin_apply``) — matching the single-device
-        ``make_pmg_preconditioner(coarse_op="galerkin_mat")``
-        iteration-for-iteration, including under ``precond_dtype``.  The
+        ``make_pmg_preconditioner(coarse_op="galerkin_mat")`` up to the
+        order of summation, including under ``precond_dtype``.  The
         *chained* "galerkin" form stays single-device (its coarse applies
         recurse to the fine grid) and raises here.
       pmg_coarse_iters: degree of the coarsest-level full-interval Chebyshev.
@@ -1422,9 +1546,13 @@ def dist_cg(
     ``prob.bc_mask`` (mask∘f∘mask — SPD on the interior subspace by
     congruence), with spectrum-estimation seeds masked per level.  The
     caller is expected to pass a bc-masked ``b`` (the same contract as
-    the single-device ``poisson_assembled`` path), and the result then
-    matches the single-device solve iteration-for-iteration, including
-    under ``precond_dtype``.
+    the single-device ``poisson_assembled`` path).  The solve then runs
+    the single-device solve's operator, preconditioner and recurrence,
+    including under ``precond_dtype``, but sums its dots in another order
+    (per rank, then ``psum``): the two ‖r‖² histories agree to rounding
+    over the first iterations, CG's amplification of rounding can part
+    them later on a hard system, and a tolerance crossing can then land
+    an iteration apart, with solutions that agree to the tolerance.
 
     The Jacobi diagonal is assembled in padded-box storage — local element
     diagonals gathered with Z_loc^T then made consistent by one
@@ -1449,10 +1577,17 @@ def dist_cg(
     full-interval degree-``pmg_coarse_iters`` Chebyshev.
 
     Returns:
-      A jitted-callable partial () -> (x, rdotr, iterations, status,
-      history) — ``status`` is the jit-safe ``core.cg.SolveStatus`` code —
-      also usable for dry-run lowering via
-      ``jax.jit(run.func).lower(*run.args)``.
+      ``solve(b_boxes) -> (x, rdotr, iterations, status, history)`` for
+      ``(R, m3)`` sharded right-hand side boxes (made consistent inside),
+      jitted once: a new ``b`` of the same shape reuses the compiled
+      program.  ``status`` is the jit-safe ``core.cg.SolveStatus`` code.
+      ``solve.program(b, *solve.operands)`` is the un-jitted shard_map
+      program with the problem's sharded arrays (laid out over ``mesh``
+      once here) as arguments; ``solve.exchange_plan`` is the resolved
+      plan.  ``solve.operator(x_boxes, *solve.operator_operands)`` is the
+      un-jitted outer A-apply the solve iterates with (its plan, split,
+      ``fused_operator``, ``two_phase`` and ``local_op``) on ``(R, m3)``
+      consistent boxes, its operands a subset of ``solve.operands``.
     """
     if precond not in PRECOND_KINDS:
         raise ValueError(f"unknown precond {precond!r}; choose from {PRECOND_KINDS}")
@@ -1475,13 +1610,7 @@ def dist_cg(
     if pmg_smooth_degree is None:
         pmg_smooth_degree = pmg_smooth_degree_default(pmg_smoother)
     op = local_op or local_poisson
-    if fused_operator is None:
-        if local_op is not None:
-            fused_operator = False
-        else:
-            from ..kernels import ops as _kops  # lazy: kernels import core
-
-            fused_operator = _kops.should_fuse_operator()
+    fused_operator = _resolve_fused(local_op, fused_operator)
     spec = P(prob.axis_name)
     hist_len = n_iter
 
@@ -1506,7 +1635,7 @@ def dist_cg(
     def _masked_seed(lvl: DistPoisson) -> jax.Array:
         """Spectrum-estimation seed for one level, Dirichlet rows zeroed
         (mirrors precond.masked_seed — Lanczos stays on the subspace)."""
-        sd = jnp.asarray(seed_values(_box_global_indices(lvl)), cdtype)
+        sd = jnp.asarray(seed_values(box_global_indices(lvl)), cdtype)
         if lvl.bc_mask is None:
             return sd
         return sd * lvl.bc_mask.astype(cdtype)
@@ -1543,9 +1672,7 @@ def dist_cg(
     else:
         levels, jmats, pmg_data = [pprob], [], ()
     # fine-level optional arrays ride their own conditional tuple
-    aux_data = tuple(
-        x for x in (prob.screen, prob.bc_mask) if x is not None
-    )
+    aux_data = _aux_operands(prob)
 
     # Schwarz setup: one _SchwarzDist per level that smooths with it —
     # level 0 for the standalone kind (overlap validated like the
@@ -1577,12 +1704,11 @@ def dist_cg(
     # class at first setup and loads the persisted plan afterwards.  The
     # picks are static python strings, so each policy traces to its own
     # compiled program with the chosen ppermute schedule baked in.
-    if exchange_plan is None:
-        exchange_plan = xplan.build_exchange_plan(
-            mesh, prob.grid, prob.axis_name,
-            _exchange_sites(prob, levels, schwarz_setups, two_phase=two_phase),
-            policy=exchange, wire=exchange_wire,
-        )
+    exchange_plan = _exchange_plan(
+        mesh, prob,
+        _exchange_sites(prob, levels, schwarz_setups, two_phase=two_phase),
+        exchange=exchange, wire=exchange_wire, plan=exchange_plan,
+    )
     xsum = [exchange_plan.lookup("sum", i) for i in range(len(levels))]
     xcopy = [exchange_plan.lookup("copy", i) for i in range(len(levels))]
     xexp = [
@@ -1594,6 +1720,14 @@ def dist_cg(
     if vcycle_overlap is None:
         vcycle_overlap = os.environ.get("HIPBONE_VCYCLE_OVERLAP", "1") != "0"
 
+    def outer_operator(g1, w1, s1, bcm1):
+        """This rank's outer A-apply: the solve's and ``solve.operator``'s."""
+        return _rank_operator(
+            prob, g1, w1, screen=s1, bc_mask=bcm1, local_op=op,
+            two_phase=two_phase, fused_interior=fused_operator,
+            xsum=xsum[0], xcopy=xcopy[0],
+        )
+
     def shard_fn(b_s, g_s, w_s, mask_s, seed_s, aux_s, pmg_s, schwarz_s):
         b1, g1, w1, m1 = b_s[0], g_s[0], w_s[0], mask_s[0]
         # make rhs consistent (replicas hold true values)
@@ -1602,8 +1736,7 @@ def dist_cg(
                 b1.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
                 xcopy[0][1], xcopy[0][0],
             ).reshape(-1)
-        s1 = aux_s[0][0] if has_screen else None
-        bcm1 = aux_s[1 if has_screen else 0][0] if has_bc else None
+        s1, bcm1 = _aux_split(prob, aux_s)
 
         def _bc_wrap(bm, f):
             """mask∘f∘mask on the Dirichlet subspace — both the operator
@@ -1621,11 +1754,7 @@ def dist_cg(
                 return bm * f(bm * v, bm * raw)
             return wrapped
 
-        operator = _bc_wrap(bcm1, lambda v: _apply_assembled(
-            prob, v, g1, w1, local_op=op, two_phase=two_phase,
-            fused_interior=fused_operator, xsum=xsum[0], xcopy=xcopy[0],
-            screen=s1,
-        ))
+        operator = outer_operator(g1, w1, s1, bcm1)
         psum = _psum(prob.axis_name)
 
         # preconditioner-dtype views of the fine-level shards: the casts are
@@ -1872,13 +2001,34 @@ def dist_cg(
         # replicated outputs are psum-derived either way
         check_rep=tol is None and not need_power and precond != "schwarz",
     )
-    run = functools.partial(
-        fn, b, prob.g, prob.w_local, prob.mask, seed_boxes, aux_data,
-        pmg_data, schwarz_data,
+    obs.tally("op.assembly.indexed")
+    operands = _place(mesh, spec, (
+        prob.g, prob.w_local, prob.mask, seed_boxes, aux_data, pmg_data,
+        schwarz_data,
+    ))
+    jitted = jax.jit(fn)
+
+    def solve(b_boxes: jax.Array):
+        return jitted(b_boxes, *operands)
+
+    def operator_fn(x_s, g_s, w_s, aux_s):
+        s1, bcm1 = _aux_split(prob, aux_s)
+        return outer_operator(g_s[0], w_s[0], s1, bcm1)(x_s[0])[None]
+
+    solve.program, solve.operands = fn, operands
+    # the A-apply the solve iterates with, on its own: (x_boxes,
+    # *solve.operator_operands) -> A x_boxes, the operands a subset of
+    # solve.operands (probes time it; tests compare it with the solve's)
+    solve.operator = shard_map(
+        operator_fn,
+        mesh=mesh,
+        in_specs=(spec, spec, spec, tuple(spec for _ in aux_data)),
+        out_specs=spec,
     )
+    solve.operator_operands = (operands[0], operands[1], operands[4])
     # observability: benchmarks/tests read the resolved plan off the handle
-    run.exchange_plan = exchange_plan
-    return run
+    solve.exchange_plan = exchange_plan
+    return solve
 
 
 def dist_cg_scattered(
@@ -1969,7 +2119,7 @@ def dist_cg_scattered(
 
     need_lanczos = precond == "chebyshev" and lmax is None
     seed_boxes = jnp.asarray(
-        seed_values(_box_global_indices(prob)), cdtype
+        seed_values(box_global_indices(prob)), cdtype
     ) if need_lanczos else jnp.zeros((prob.grid.size, 1), cdtype)
 
     if exchange_plan is None:

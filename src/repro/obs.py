@@ -81,6 +81,13 @@ SPANS = {
     "setup.precond": "core/precond.py make_preconditioner: the whole set-up",
     "setup.precond.l{i}": "one pMG level: coarsening and transfers to it, "
                           "diagonal, Lanczos interval; the coarsest its coarse solve",
+    "setup.build_dist_problem": "core/distributed.py build_dist_problem: the whole "
+                                "host build of a sharded problem",
+    "setup.dist.rank_data": "per-rank l2g, geometric factors, inverse degree and "
+                            "masks, on the host",
+    "setup.dist.upload": "the sharded problem's arrays handed to the device",
+    "setup.exchange_plan": "core/distributed.py: the exchange plan resolved (or "
+                           "taken as given) and its routes counted",
     "engine.dispatch": "serving/engine.py: one batched dispatch",
     "engine.setup_lookup": "the setup-cache lookup or build",
     "engine.rhs_stack": "the right-hand sides stacked into one block",
@@ -93,8 +100,15 @@ SPANS = {
 TALLIES = {
     "op.assembly.lattice": "core/operator.py poisson_assembled: operators built "
                            "with the lattice Z and Z^T",
-    "op.assembly.indexed": "core/operator.py poisson_assembled: operators built "
+    "op.assembly.indexed": "core/operator.py poisson_assembled and the sharded "
+                           "operators of core/distributed.py: operators built "
                            "with the indexed Z and Z^T (take, segment sum)",
+    "xch.route.face_sweep": "exchange sites that a resolved plan routes by the "
+                            "per-dimension face sweep",
+    "xch.route.crystal": "exchange sites that a resolved plan routes by the "
+                         "staged crystal route",
+    "xch.route.fused": "exchange sites that a resolved plan routes in one "
+                       "fused diagonal round",
 }
 
 

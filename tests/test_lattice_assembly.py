@@ -137,7 +137,8 @@ def test_assembly_attribute_and_tally():
     assert (a.assembly, b.assembly, c.assembly) == ("lattice", "indexed", "indexed")
     after = obs.tallies()
     grew = {k: after.get(k, 0) - before.get(k, 0) for k in obs.TALLIES}
-    assert grew == {"op.assembly.lattice": 1, "op.assembly.indexed": 2}
+    assert grew == {**dict.fromkeys(obs.TALLIES, 0),
+                    "op.assembly.lattice": 1, "op.assembly.indexed": 2}
     with pytest.raises(ValueError):
         obs.tally("op.assembly.other")
 

@@ -325,9 +325,17 @@ def test_cache_key_coefficient_sensitivity(n, seed, kind):
 
 @pytest.mark.slow
 def test_sharded_parity_random_coefficient_fields():
-    """Sharded-vs-single iteration parity holds under random positive
-    coefficient draws, not just the named families — three seeded draws
-    through the full dist_cg stack on 8 fake devices."""
+    """Sharded-vs-single parity holds under random positive coefficient
+    draws, not just the named families — three seeded draws through the
+    full dist_cg stack on 8 fake devices.
+
+    The two solves sum their dots in different orders, so their ‖r‖²
+    histories agree to rounding over the first 20 iterations (a fault in
+    the sharded operator, masks or screen breaks that at once) and may part
+    later by CG's amplification of rounding (seed 0: 5e-15 relative up to
+    iteration 24, 4e-9 at iteration 29); the tol=1e-10 crossing may then
+    land one iteration apart (seed 42: 120 sharded against 121
+    single-device, the solutions 2.2e-10 apart)."""
     run_subprocess(
         """
 import jax
@@ -382,7 +390,8 @@ for seed in (0, 7, 42):
     )
     bg = rng.standard_normal(ref.n_global) * np.asarray(ref.mask, np.float64)
     res = cg_assembled(
-        poisson_assembled(ref), jnp.asarray(bg), n_iter=300, tol=1e-10
+        poisson_assembled(ref), jnp.asarray(bg), n_iter=300, tol=1e-10,
+        record_history=True,
     )
     prob = build_dist_problem(
         N, grid, local, lam=0.8, dtype=jnp.float64,
@@ -390,14 +399,18 @@ for seed in (0, 7, 42):
         bc="mixed",
     )
     run = jax.jit(dist_cg(prob, mesh, jnp.asarray(boxes_from_global(prob, bg)),
-                          n_iter=300, tol=1e-10))
+                          n_iter=300, tol=1e-10, record_history=True))
     x_boxes, rdotr, iters, status, hist = run()
     err = np.abs(
         np.asarray(x_boxes) - boxes_from_global(prob, np.asarray(res.x))
     ).max()
-    print(seed, int(iters), int(res.iterations), err)
-    assert int(status) == 0, (seed, int(status))
-    assert int(iters) == int(res.iterations), (seed, int(iters), int(res.iterations))
+    h_dist = np.asarray(hist)[:20]
+    h_one = np.asarray(res.rdotr_history)[:20]
+    hist_err = np.max(np.abs(h_dist - h_one) / np.abs(h_one))
+    print(seed, int(iters), int(res.iterations), err, hist_err)
+    assert int(status) == 0 and int(res.status) == 0, (seed, int(status), int(res.status))
+    assert abs(int(iters) - int(res.iterations)) <= 1, (seed, int(iters), int(res.iterations))
+    assert hist_err <= 1e-12, (seed, hist_err)
     assert err < 1e-8, (seed, err)
 print("PARITY-OK")
 """,
