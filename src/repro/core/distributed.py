@@ -7,14 +7,18 @@ sharing ranks, every replica holding the true value). See DESIGN.md §5.
 
 Operator application follows the paper's Fig. 2 communication-hiding split:
 
-    scatter (local)                     u_L = x_box[l2g]
-    halo elements first                 y_h = (S_L + λW) u_L[:Eh]
-    local gather of halo contributions  box_h = Z_loc^T y_h
+    halo elements first                 y_h = (S_L + λW) Z_h x_box
+    local gather of halo contributions  box_h = Z_h^T y_h
     ── sum_exchange(box_h) ──╮          (async collective...)
-    interior elements        │          y_i = (S_L + λW) u_L[Eh:]   ...overlaps
-    local gather             │          box_i = Z_loc^T y_i          this compute
+    interior elements        │          y_i = (S_L + λW) Z_i x_box   ...overlaps
+    local gather             │          box_i = Z_i^T y_i             this compute
     ─────────────────────────╯
     combine                             A x = exchanged(box_h) + box_i
+
+Z and Z^T are the lattice pair of ``gather_scatter`` on sub-boxes of the
+padded box — six face slabs for the halo, the core for the interior
+(``_element_blocks``) — where the rank's map is that slab lattice, else
+XLA's take and segment sum through ``l2g`` (``_split_z``).
 
 Interior elements touch no rank-boundary points, so their contributions
 commute with the exchange — that is exactly why the split hides the
@@ -61,6 +65,7 @@ from .cg import (
     _pcg,
 )
 from .galerkin import block_matvec_einsum, galerkin_ladder_blocks
+from .gather_scatter import lattice_gather, lattice_scatter
 from .geometry import geometric_factors_from_coords
 from .operator import COARSE_K_FLOOR, local_poisson
 from .precond import (
@@ -168,6 +173,18 @@ class DistPoisson:
     def m3(self) -> int:
         return int(np.prod(self.box_shape))
 
+    @functools.cached_property
+    def slab_lattice(self) -> bool:
+        """Whether ``l2g`` and ``halo_elems`` are ``_local_l2g``'s: each of
+        the rank's ``_element_blocks`` is then a box lattice of its points,
+        and ``_apply_assembled`` runs Z and Zᵀ as the lattice pair on
+        sub-boxes of the padded box, else as the indexed take and segment
+        sum.  Host numpy, checked once per problem."""
+        l2g, n_halo = _local_l2g(self.n_degree, self.local_shape)
+        return self.halo_elems == n_halo and bool(
+            np.array_equal(np.asarray(self.l2g), l2g)
+        )
+
     @property
     def e_local(self) -> int:
         return int(np.prod(self.local_shape))
@@ -192,26 +209,51 @@ def _local_node_offsets(n: int, pad: int = 0) -> tuple[np.ndarray, ...]:
     )
 
 
+def _element_blocks(
+    local_shape: tuple[int, int, int],
+) -> tuple[list[tuple[tuple[int, int, int], tuple[int, int, int]]], int]:
+    """The rank's element box as sub-lattices: halo blocks first, then the core.
+
+    Each block is ``(origin, shape)`` in elements along (x, y, z).  With
+    every side at least 3 the halo is six face slabs, in this order: z-low
+    and z-high ``(bx, by, 1)``, y-low and y-high ``(bx, 1, bz-2)``, x-low
+    and x-high ``(1, by-2, bz-2)``; the interior is the
+    ``(bx-2, by-2, bz-2)`` core.  With a side of 2 or less every element
+    touches a face: the whole box is one halo block and there is no core.
+    Returns ``(blocks, number of halo blocks)``.
+    """
+    bx, by, bz = local_shape
+    if min(local_shape) <= 2:
+        return [((0, 0, 0), (bx, by, bz))], 1
+    z_slab, y_slab, x_slab = (bx, by, 1), (bx, 1, bz - 2), (1, by - 2, bz - 2)
+    halo = [
+        ((0, 0, 0), z_slab), ((0, 0, bz - 1), z_slab),
+        ((0, 0, 1), y_slab), ((0, by - 1, 1), y_slab),
+        ((0, 1, 1), x_slab), ((bx - 1, 1, 1), x_slab),
+    ]
+    return halo + [((1, 1, 1), (bx - 2, by - 2, bz - 2))], len(halo)
+
+
 def _ordered_elements(local_shape: tuple[int, int, int]) -> tuple[np.ndarray, int]:
     """Halo-first local element coordinates: (E_loc, 3) int array + halo count.
 
     Elements on any face of the rank's local box come first — their
     operator contributions feed the halo exchange, and their Schwarz blocks
     are the only ones reading the expanded-box shells, so the same ordering
-    drives both communication-hiding splits.
+    drives both communication-hiding splits.  The order is that of
+    ``_element_blocks``, each block in its own lattice order (x fastest),
+    so that each block's elements are one lattice Z/Zᵀ apart from its
+    points (``_apply_assembled``).
     """
-    bx, by, bz = local_shape
-    elems = [
-        (i, j, k) for k in range(bz) for j in range(by) for i in range(bx)
-    ]
-    halo = [
-        e
-        for e in elems
-        if e[0] in (0, bx - 1) or e[1] in (0, by - 1) or e[2] in (0, bz - 1)
-    ]
-    halo_set = set(halo)
-    interior = [e for e in elems if e not in halo_set]
-    return np.array(halo + interior, dtype=np.int64), len(halo)
+    blocks, n_halo_blocks = _element_blocks(local_shape)
+    parts = []
+    for (i0, j0, k0), (ex, ey, ez) in blocks:
+        k, j, i = np.meshgrid(
+            np.arange(ez), np.arange(ey), np.arange(ex), indexing="ij"
+        )
+        parts.append(np.stack([i0 + i, j0 + j, k0 + k], axis=-1).reshape(-1, 3))
+    n_halo = sum(int(np.prod(shape)) for _, shape in blocks[:n_halo_blocks])
+    return np.concatenate(parts).astype(np.int64), n_halo
 
 
 def _local_l2g(n: int, local_shape: tuple[int, int, int]) -> tuple[np.ndarray, int]:
@@ -718,6 +760,70 @@ def _box_galerkin_apply(
     return apply
 
 
+def _split_z(prob: DistPoisson) -> tuple[tuple[Callable, Callable], ...]:
+    """``((Z_h, Z_hᵀ), (Z_i, Z_iᵀ))``: the halo and the interior elements'
+    scatter from and gather into one rank's flat padded box.
+
+    On the slab-lattice map (``DistPoisson.slab_lattice``) each
+    ``_element_blocks`` block is a sub-box of the padded box: Z is
+    ``lattice_scatter`` of its slice, the blocks' element arrays stacked
+    in block order; Zᵀ is ``lattice_gather`` of each block's rows, padded
+    back into the box and summed (blocks share face points, and the sum
+    adds them as the segment sum did).  The interior core's Zᵀ is padded
+    by N on every side and never touches a face plane.  Any other map
+    takes and segment-sums through ``l2g``.
+    """
+    eh, p, m3 = prob.halo_elems, prob.l2g.shape[1], prob.m3
+    if not prob.slab_lattice:
+        l2g_flat = jnp.asarray(prob.l2g.reshape(-1))
+
+        def indexed(rows: slice) -> tuple[Callable, Callable]:
+            idx = l2g_flat[rows]
+            return (
+                lambda x: jnp.take(x, idx, axis=0).reshape(-1, p),
+                lambda y: jax.ops.segment_sum(
+                    y.reshape(-1), idx, num_segments=m3
+                ),
+            )
+
+        return indexed(slice(None, eh * p)), indexed(slice(eh * p, None))
+
+    n = prob.n_degree
+    box3 = prob.box_shape[::-1]  # (z, y, x) points
+    blocks, n_halo_blocks = _element_blocks(prob.local_shape)
+
+    def lattice(blks: list) -> tuple[Callable, Callable]:
+        # per block: its (z, y, x) point window in the box, element count
+        wins = [
+            tuple(slice(o * n, (o + e) * n + 1) for o, e in zip(org[::-1], shp[::-1]))
+            for org, shp in blks
+        ]
+        counts = [int(np.prod(shp)) for _, shp in blks]
+
+        def z(x: jax.Array) -> jax.Array:
+            x3 = x.reshape(box3)
+            return jnp.concatenate([
+                lattice_scatter(x3[win].reshape(-1), shp, n)
+                for win, (_, shp) in zip(wins, blks)
+            ])
+
+        def zt(y: jax.Array) -> jax.Array:
+            out, start = None, 0
+            for win, (_, shp), e in zip(wins, blks, counts):
+                part = lattice_gather(y[start:start + e], shp, n)
+                part = jnp.pad(
+                    part.reshape([w.stop - w.start for w in win]),
+                    [(w.start, m - w.stop) for w, m in zip(win, box3)],
+                )
+                out = part if out is None else out + part
+                start += e
+            return out.reshape(-1)
+
+        return z, zt
+
+    return lattice(blocks[:n_halo_blocks]), lattice(blocks[n_halo_blocks:])
+
+
 def _apply_assembled(
     prob: DistPoisson,
     x_box: jax.Array,       # (m3,)
@@ -734,13 +840,16 @@ def _apply_assembled(
 ) -> jax.Array:
     """One A-apply inside shard_map, with the Fig. 2 overlap split.
 
+    Z and Zᵀ of each block are ``_split_z``'s: lattice slices on the
+    slab-lattice map, else the indexed take and segment sum.
+
     ``screen``, when given, is the rank's (E_loc, p) weak mass screen
     JW·λ(x): it replaces ``w`` on the kernels' w stream with the static
     ``lam`` pinned to 1.0 (``core.operator.screen_stream``'s contract —
     kernel signatures unchanged, Pallas' static lam stays a python float).
 
     ``fused_interior`` replaces the interior block's three-stage pipeline
-    (gather u, ``local_op``, segment_sum) with the single-pass Pallas
+    (Z, ``local_op``, Zᵀ) with the single-pass Pallas
     kernel ``kernels.ops.poisson_assembled_fused`` over the rank-local
     padded box — the interior elements touch no rank boundary, so their
     gather source and scatter target are both the local box and the fused
@@ -757,10 +866,8 @@ def _apply_assembled(
     V-cycle overlap).
     """
     eh = prob.halo_elems
-    p = prob.l2g.shape[1]
-    l2g_flat = jnp.asarray(prob.l2g.reshape(-1))
-    m3 = prob.m3
     w_eff, lam_eff = (w, prob.lam) if screen is None else (screen, 1.0)
+    (z_h, zt_h), (z_i, zt_i) = _split_z(prob)
 
     if two_phase:
         # paper-faithful: explicit scatter-side halo refresh first
@@ -772,41 +879,38 @@ def _apply_assembled(
         x_raw = None  # the refreshed box is the only valid source
     x_int = x_box if x_raw is None else x_raw
 
+    def block(z, zt, x: jax.Array, rows: slice) -> jax.Array:
+        with obs.scope("op.scatter"):
+            u = z(x)
+        with obs.scope("op.local"):
+            y = local_op(u, g[rows], prob.d, lam_eff, w_eff[rows])
+        with obs.scope("op.gather"):
+            return zt(y)
+
     # halo elements first; their contributions feed the exchange
-    u_h = jnp.take(x_box, l2g_flat[: eh * p], axis=0).reshape(eh, p)
-    y_h = local_op(u_h, g[:eh], prob.d, lam_eff, w_eff[:eh])
-    box_h = jax.ops.segment_sum(
-        y_h.reshape(-1), l2g_flat[: eh * p], num_segments=m3
-    )
+    box_h = block(z_h, zt_h, x_box, slice(None, eh))
     with obs.scope("halo.site.operator"):
         box_h = sum_exchange(
             box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
             xsum[1], xsum[0],
         ).reshape(-1)
+    if prob.e_local == eh:
+        return box_h
 
     # interior elements: no boundary contact -> overlaps the exchange above
     if fused_interior:
-        if prob.e_local > eh:
-            from ..kernels import ops as _kops  # lazy: kernels import core
+        from ..kernels import ops as _kops  # lazy: kernels import core
 
-            box_i = _kops.poisson_assembled_fused(
-                x_int,
-                jnp.asarray(prob.l2g)[eh:],
-                g[eh:],
-                w_eff[eh:],
-                prob.d,
-                lam=lam_eff,
-            )
-        else:
-            box_i = jnp.zeros_like(box_h)
+        box_i = _kops.poisson_assembled_fused(
+            x_int,
+            jnp.asarray(prob.l2g)[eh:],
+            g[eh:],
+            w_eff[eh:],
+            prob.d,
+            lam=lam_eff,
+        )
     else:
-        u_i = jnp.take(x_int, l2g_flat[eh * p :], axis=0).reshape(
-            prob.e_local - eh, p
-        )
-        y_i = local_op(u_i, g[eh:], prob.d, lam_eff, w_eff[eh:])
-        box_i = jax.ops.segment_sum(
-            y_i.reshape(-1), l2g_flat[eh * p :], num_segments=m3
-        )
+        box_i = block(z_i, zt_i, x_int, slice(eh, None))
     return box_h + box_i
 
 
@@ -2001,7 +2105,7 @@ def dist_solver(
         # replicated outputs are psum-derived either way
         check_rep=tol is None and not need_power and precond != "schwarz",
     )
-    obs.tally("op.assembly.indexed")
+    obs.tally(f"op.assembly.{'lattice' if prob.slab_lattice else 'indexed'}")
     operands = _place(mesh, spec, (
         prob.g, prob.w_local, prob.mask, seed_boxes, aux_data, pmg_data,
         schwarz_data,

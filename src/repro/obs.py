@@ -48,11 +48,17 @@ SCOPES = {
     "cg.operator": "core/cg.py _pcg: every A-apply of the Krylov recurrence",
     "cg.precond": "core/cg.py _pcg: every z = M^-1 r",
     "cg.allreduce": "core/distributed.py: every psum of the recurrence scalars",
-    "op.scatter": "core/operator.py poisson_assembled: x_L = Z x_G (lattice slices "
-                  "and a 0/1 product on box meshes, else take)",
-    "op.local": "core/operator.py poisson_assembled: the element operator, XLA or Pallas",
-    "op.gather": "core/operator.py poisson_assembled: Z^T y_L (lattice 0/1 product, "
-                 "slices, pads and adds on box meshes, else segment sum)",
+    "op.scatter": "core/operator.py poisson_assembled and the halo and interior "
+                  "blocks of core/distributed.py _apply_assembled: x_L = Z x_G "
+                  "(lattice slices and a 0/1 product on box meshes and slab-lattice "
+                  "rank boxes, else take)",
+    "op.local": "core/operator.py poisson_assembled and the halo and interior blocks "
+                "of core/distributed.py _apply_assembled: the element operator, XLA "
+                "or Pallas",
+    "op.gather": "core/operator.py poisson_assembled and the halo and interior "
+                 "blocks of core/distributed.py _apply_assembled: Z^T y_L (lattice "
+                 "0/1 product, slices, pads and adds on box meshes and slab-lattice "
+                 "rank boxes, else segment sum)",
     "op.fused": "kernels/ops.py: the fused assembled operator, one Pallas pass",
     "op.scattered": "core/operator.py poisson_scattered: (Z Z^T S_L + lambda) x_L",
     "pmg.l{i}": "core/precond.py V-cycle: level i's own work (smoothing, residual, "
@@ -98,10 +104,12 @@ SPANS = {
 
 # host tallies (tally) -> what each counts
 TALLIES = {
-    "op.assembly.lattice": "core/operator.py poisson_assembled: operators built "
-                           "with the lattice Z and Z^T",
-    "op.assembly.indexed": "core/operator.py poisson_assembled and the sharded "
-                           "operators of core/distributed.py: operators built "
+    "op.assembly.lattice": "core/operator.py poisson_assembled and "
+                           "core/distributed.py dist_solver: operators built "
+                           "with the lattice Z and Z^T (on one chip the box "
+                           "lattice, sharded the slab lattice of each rank box)",
+    "op.assembly.indexed": "core/operator.py poisson_assembled and "
+                           "core/distributed.py dist_solver: operators built "
                            "with the indexed Z and Z^T (take, segment sum)",
     "xch.route.face_sweep": "exchange sites that a resolved plan routes by the "
                             "per-dimension face sweep",

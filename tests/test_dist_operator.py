@@ -11,6 +11,7 @@ import pytest
 from conftest import run_subprocess
 
 PRELUDE = """
+import dataclasses
 import jax
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
@@ -198,10 +199,19 @@ for policy, routes in want.items():
         jnp.zeros((grid.size, prob.m3), jnp.float64), *solve.operator_operands)
     got = obs.tallies()
     # one plan and one operator build per solver; its operator adds none
-    assert got == {"op.assembly.indexed": 1, **routes}, (policy, got)
+    assert got == {"op.assembly.lattice": 1, **routes}, (policy, got)
     assert [s.name for s in obs.spans()] == ["setup.exchange_plan"]
     for name in got:
         assert name in obs.TALLIES
+# the same problem with its halo elements in another order: not the slab
+# lattice, so the solver's operator takes and segment-sums through l2g
+perm = np.r_[np.arange(prob.halo_elems)[::-1], np.arange(prob.halo_elems, prob.e_local)]
+other = dataclasses.replace(prob, l2g=prob.l2g[perm], g=prob.g[:, perm],
+                            w_local=prob.w_local[:, perm])
+assert prob.slab_lattice and not other.slab_lattice
+obs.reset()
+dist_solver(other, mesh, n_iter=5, exchange="face_sweep")
+assert obs.tallies() == {"op.assembly.indexed": 1, **want["face_sweep"]}, obs.tallies()
 for name in ("setup.build_dist_problem", "setup.dist.rank_data",
              "setup.dist.upload", "setup.exchange_plan"):
     assert name in obs.SPANS
