@@ -35,7 +35,7 @@ def bench(run):
         fn()[1].block_until_ready()
     return (time.perf_counter() - t0) / 3
 
-t_asm = bench(dist_cg(prob, mesh, b, n_iter=n_iter, fused_operator=FUSED))
+t_asm = bench(dist_cg(prob, mesh, b, n_iter=n_iter))
 t_sca = bench(dist_cg_scattered(prob, mesh, bL, n_iter=n_iter))
 e_tot = ranks * prob.e_local
 flops = nekbone_flops_per_iter(e_tot, n) * n_iter
@@ -50,19 +50,19 @@ print(json.dumps({
 """
 
 
-def _run(ranks: int, fused: bool | None = None) -> dict:
-    child = _CHILD.replace("RANKS", str(ranks)).replace("FUSED", repr(fused))
+def _run(ranks: int) -> dict:
+    child = _CHILD.replace("RANKS", str(ranks))
     return run_child(child, section="table2")
 
 
-def main(quick: bool = True, fused: bool | None = None) -> list[str]:
+def main(quick: bool = True) -> list[str]:
     rows = [
         "table2,ranks,fom_assembled_gflops,fom_per_rank,weak_scaling_eff_pct,"
         "fom_scattered_gflops,assembled_speedup,bytes_model_ratio"
     ]
     base = None
     for ranks in ([1, 2, 4, 8] if not quick else [1, 4]):
-        r = _run(ranks, fused)
+        r = _run(ranks)
         per = r["fom_assembled"] / ranks
         if base is None:
             base = per
@@ -79,15 +79,5 @@ if __name__ == "__main__":
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
-    ap.add_argument(
-        "--fused-operator",
-        action="store_true",
-        help="single-kernel fused assembled apply on the interior block "
-             "(kernels/poisson_fused.py) in the assembled-mode runs",
-    )
     args = ap.parse_args()
-    print(
-        "\n".join(
-            main(quick=args.quick, fused=args.fused_operator or None)
-        )
-    )
+    print("\n".join(main(quick=args.quick)))

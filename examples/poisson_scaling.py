@@ -58,10 +58,6 @@ def main() -> None:
                          "preconditioner dtype is narrower than the solve")
     ap.add_argument("--two-phase", action="store_true",
                     help="paper-faithful two-phase comm (halo + gather)")
-    ap.add_argument("--fused-operator", action="store_true",
-                    help="single-kernel fused assembled apply for the "
-                         "interior element block (kernels/poisson_fused.py); "
-                         "default: kernels.ops.should_fuse_operator policy")
     ap.add_argument("--exchange",
                     choices=["auto", "face_sweep", "crystal", "fused"],
                     default=None,
@@ -128,7 +124,6 @@ def main() -> None:
                           lmin=lmin, lmax=lmax,
                           precond_dtype=pdtype, cg_variant=variant,
                           two_phase=args.two_phase, record_history=True,
-                          fused_operator=args.fused_operator or None,
                           exchange=args.exchange))
     plan = getattr(getattr(run, "__wrapped__", run), "exchange_plan", None)
     if plan is not None:

@@ -89,12 +89,6 @@ class PoissonConfig:
     # approximately symmetric in fp64 arithmetic.
     precond_dtype: str | None = None
     cg_variant: str = "standard"        # "standard" (FR β) | "flexible" (PR β)
-    # fused assembled operator: True forces the single-kernel Pallas apply
-    # (kernels/poisson_fused.py — gather, local operator and scatter-add in
-    # one pass, interior block only under sharding), False pins the split
-    # scatter/local/gather pipeline, None defers to the backend policy
-    # (kernels.ops.should_fuse_operator; HIPBONE_FUSED=0/1 overrides).
-    fused_operator: bool | None = None
     # halo-exchange routing policy for sharded solves (comms.plan):
     # "auto" times face_sweep/crystal/fused per exchange site at setup and
     # records the winners (persisted per content signature), a named
@@ -194,11 +188,6 @@ class PoissonConfig:
             )
         if self.cg_variant not in ("standard", "flexible"):
             bad(f"unknown cg_variant {self.cg_variant!r}")
-        if not isinstance(self.fused_operator, (bool, type(None))):
-            bad(
-                f"fused_operator must be None/True/False, "
-                f"got {self.fused_operator!r}"
-            )
         if self.exchange not in (None, "auto", "face_sweep", "crystal", "fused"):
             bad(
                 f"unknown exchange {self.exchange!r}; use 'auto', "

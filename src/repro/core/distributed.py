@@ -15,10 +15,13 @@ Operator application follows the paper's Fig. 2 communication-hiding split:
     ─────────────────────────╯
     combine                             A x = exchanged(box_h) + box_i
 
-Z and Z^T are the lattice pair of ``gather_scatter`` on sub-boxes of the
-padded box — six face slabs for the halo, the core for the interior
-(``_element_blocks``) — where the rank's map is that slab lattice, else
-XLA's take and segment sum through ``l2g`` (``_split_z``).
+Z and Z^T are the rank's halo and interior ``gather_scatter.assembly``:
+the lattice pair on sub-boxes of the padded box — six face slabs for the
+halo, the core for the interior (``_element_blocks``) — where the rank's
+map is that lattice, else XLA's take and segment sum through ``l2g``.  The
+same split applies a Galerkin level's block matvec (``_split_apply``), and
+the smoother diagonal and pMG transfers are ``core.precond``'s, completed
+by the level's sum-exchange.
 
 Interior elements touch no rank-boundary points, so their contributions
 commute with the exchange — that is exactly why the split hides the
@@ -65,21 +68,23 @@ from .cg import (
     _pcg,
 )
 from .galerkin import block_matvec_einsum, galerkin_ladder_blocks
-from .gather_scatter import lattice_gather, lattice_scatter
+from .gather_scatter import assembly, indexed_assembly
 from .geometry import geometric_factors_from_coords
-from .operator import COARSE_K_FLOOR, local_poisson
+from .operator import COARSE_K_FLOOR, local_poisson, screen_stream
 from .precond import (
     CHEB_LMIN_SAFETY,
     CHEB_SAFETY,
     PMG_SMOOTHERS,
     PRECOND_KINDS,
     SCHWARZ_INNER_DEGREE,
+    assembled_diagonal,
     cast_apply,
     chebyshev_apply,
     chebyshev_apply_deferred,
     jacobi_apply,
     lanczos_extremes,
-    local_operator_diagonal,
+    level_assembly,
+    make_transfer_pair,
     make_vcycle,
     make_vcycle_overlapped,
     pmg_degree_ladder,
@@ -87,7 +92,6 @@ from .precond import (
     power_lambda_max,
     seed_values,
     smoother_interval,
-    tensor3_interp,
 )
 from .schwarz import (
     SchwarzFDM,
@@ -173,18 +177,6 @@ class DistPoisson:
     def m3(self) -> int:
         return int(np.prod(self.box_shape))
 
-    @functools.cached_property
-    def slab_lattice(self) -> bool:
-        """Whether ``l2g`` and ``halo_elems`` are ``_local_l2g``'s: each of
-        the rank's ``_element_blocks`` is then a box lattice of its points,
-        and ``_apply_assembled`` runs Z and Zᵀ as the lattice pair on
-        sub-boxes of the padded box, else as the indexed take and segment
-        sum.  Host numpy, checked once per problem."""
-        l2g, n_halo = _local_l2g(self.n_degree, self.local_shape)
-        return self.halo_elems == n_halo and bool(
-            np.array_equal(np.asarray(self.l2g), l2g)
-        )
-
     @property
     def e_local(self) -> int:
         return int(np.prod(self.local_shape))
@@ -243,7 +235,7 @@ def _ordered_elements(local_shape: tuple[int, int, int]) -> tuple[np.ndarray, in
     drives both communication-hiding splits.  The order is that of
     ``_element_blocks``, each block in its own lattice order (x fastest),
     so that each block's elements are one lattice Z/Zᵀ apart from its
-    points (``_apply_assembled``).
+    points (``_split_assemblies``).
     """
     blocks, n_halo_blocks = _element_blocks(local_shape)
     parts = []
@@ -662,7 +654,7 @@ def build_pmg_galerkin_blocks(
     ``_rank_data`` time) — so assembly of the owned coarse elements is
     embarrassingly rank-local on the padded box: **no setup exchange**.
     Apply time then needs only the standard sum-exchange of halo-element
-    contributions (``_box_galerkin_apply``), identical in shape to any
+    contributions (``_split_apply``), identical in shape to any
     rediscretized level's.
 
     Fields are cast to ``prob.dtype`` first, so a mixed-precision caller
@@ -680,9 +672,8 @@ def build_pmg_galerkin_blocks(
     r, e_loc = prob.g.shape[:2]
     degrees = tuple(lvl.n_degree for lvl in levels)
     # variable λ(x): the screen stream JW·λ replaces (w_local, λ) in the
-    # element blocks — Ĵᵀ(S_L^e + diag(JW·λ))Ĵ — matching screen_stream
-    w_src = prob.w_local if prob.screen is None else prob.screen
-    lam_eff = prob.lam if prob.screen is None else 1.0
+    # element blocks — Ĵᵀ(S_L^e + diag(JW·λ))Ĵ
+    w_src, lam_eff = screen_stream(prob)
 
     def build(g: jax.Array, w: jax.Array) -> list[jax.Array]:
         g2 = g.astype(prob.dtype).reshape(r * e_loc, *g.shape[2:])
@@ -697,264 +688,185 @@ def build_pmg_galerkin_blocks(
     return build(prob.g, w_src)
 
 
-def _box_galerkin_apply(
+def _exchange(fn: Callable, prob: DistPoisson, box: jax.Array, pick: tuple,
+              site: str) -> jax.Array:
+    """``fn`` (``comms.halo``'s sum or copy exchange) of one rank's flat
+    padded box, with the exchange plan's (routing, wire) ``pick``, under
+    the scope ``halo.site.<site>``."""
+    with obs.scope(f"halo.site.{site}"):
+        return fn(
+            box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
+            pick[1], pick[0],
+        ).reshape(-1)
+
+
+def _split_assemblies(prob: DistPoisson) -> tuple:
+    """``(halo, interior)``: the ``gather_scatter.assembly`` of the rank's
+    halo and interior elements from and into its flat padded box, the
+    lattice pair on ``_element_blocks``' face slabs and core where the
+    rank's map is that lattice (``_local_l2g``'s), else indexed."""
+    blocks, n_halo_blocks = _element_blocks(prob.local_shape)
+    eh = prob.halo_elems
+    return (
+        assembly(prob.l2g[:eh], prob.n_degree, prob.local_shape,
+                 blocks[:n_halo_blocks]),
+        assembly(prob.l2g[eh:], prob.n_degree, prob.local_shape,
+                 blocks[n_halo_blocks:]),
+    )
+
+
+def _split_kind(prob: DistPoisson) -> str:
+    """``"lattice"`` when both halves of the rank's split are, else ``"indexed"``."""
+    kinds = {asm.kind for asm in _split_assemblies(prob)}
+    return "lattice" if kinds == {"lattice"} else "indexed"
+
+
+def _split_apply(
     prob: DistPoisson,
-    blocks: jax.Array,
+    kernel: Callable[[jax.Array, slice], jax.Array],
     *,
-    two_phase: bool = False,
+    two_phase: bool,
     xsum: tuple = _XCH,
     xcopy: tuple = _XCH,
+    site: str = "operator",
 ) -> Callable[..., jax.Array]:
-    """Materialized Galerkin coarse-level A-apply on consistent padded boxes.
+    """One rank's A-apply on its consistent padded box, with the Fig. 2
+    overlap split.
 
-    The Fig. 2 halo/interior split of ``_apply_assembled`` with the fused
-    local kernel replaced by one batched dense element matvec: halo-element
-    matvecs feed the sum-exchange first, interior-element matvecs overlap
-    it, and zero fine-operator work happens per apply — the coarse level
-    touches only its own (E_loc, p_c, p_c) blocks and its own box.
-    ``two_phase`` mirrors ``_apply_assembled``'s paper-faithful explicit
-    scatter-side halo refresh, so the comparison mode stays uniform across
-    every level of the V-cycle.  ``xsum``/``xcopy`` are the exchange plan's
-    (routing, wire) picks for this level's sum/copy sites.
+    ``kernel(u, rows)`` is the element operator on the element-local field
+    ``u`` of the elements ``rows``: ``local_poisson`` on the rank's ``g`` /
+    ``w`` rows (``_rank_operator``), or a Galerkin level's batched block
+    matvec on its block rows.  Halo elements go first and their Zᵀ feeds
+    the sum-exchange; interior elements touch no rank-boundary point, so
+    their work overlaps that exchange.  Z and Zᵀ are
+    ``_split_assemblies``'.  ``two_phase`` first refreshes the replicas
+    with an explicit copy exchange (the paper's two-phase dataflow).
+    ``xsum``/``xcopy`` are the exchange plan's (routing, wire) picks for
+    this level's sites, scoped ``halo.site.<site>``.
 
-    The returned apply takes an optional deferred twin ``x_raw`` (the box
-    before its producing sum-exchange): interior blocks gather from it —
-    raw interior slots are bitwise final — so their matvecs need not wait
-    for the upstream exchange (cross-level V-cycle overlap).
+    The returned ``apply(x_box, x_raw=None)`` takes an optional deferred
+    twin ``x_raw`` (the same box *before* its producing sum-exchange): the
+    interior reads it instead — bitwise identical, since the exchange only
+    rewrites face slabs interior elements never touch — which releases the
+    interior block from the upstream exchange's data dependence
+    (cross-level V-cycle overlap).
     """
     eh = prob.halo_elems
-    l2g_flat = jnp.asarray(prob.l2g.reshape(-1))
-    m3 = prob.m3
-    p = prob.l2g.shape[1]
+    asm_h, asm_i = _split_assemblies(prob)
+
+    def block(asm, x: jax.Array, rows: slice) -> jax.Array:
+        with obs.scope("op.scatter"):
+            u = asm.z(x)
+        with obs.scope("op.local"):
+            y = kernel(u, rows)
+        with obs.scope("op.gather"):
+            return asm.zt(y)
 
     def apply(x_box: jax.Array, x_raw: jax.Array | None = None) -> jax.Array:
         if two_phase:
-            with obs.scope("halo.site.galerkin"):
-                x_box = copy_exchange(
-                    x_box.reshape(prob.box_shape[::-1]), prob.grid,
-                    prob.axis_name, xcopy[1], xcopy[0],
-                ).reshape(-1)
+            x_box = _exchange(copy_exchange, prob, x_box, xcopy, site)
             x_raw = None  # the refreshed box is the only valid source
-        u_h = jnp.take(x_box, l2g_flat[: eh * p], axis=0).reshape(eh, p)
-        y_h = block_matvec_einsum(blocks[:eh], u_h)
-        box_h = jax.ops.segment_sum(
-            y_h.reshape(-1), l2g_flat[: eh * p], num_segments=m3
+        box_h = _exchange(
+            sum_exchange, prob, block(asm_h, x_box, slice(None, eh)), xsum, site
         )
-        with obs.scope("halo.site.galerkin"):
-            box_h = sum_exchange(
-                box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-                xsum[1], xsum[0],
-            ).reshape(-1)
-
-        # interior blocks: no rank-boundary contact -> overlap the exchange
-        # (and, given a raw twin, the upstream transfer exchange too)
-        u_i = jnp.take(
-            x_box if x_raw is None else x_raw, l2g_flat[eh * p :], axis=0
-        ).reshape(prob.e_local - eh, p)
-        y_i = block_matvec_einsum(blocks[eh:], u_i)
-        box_i = jax.ops.segment_sum(
-            y_i.reshape(-1), l2g_flat[eh * p :], num_segments=m3
-        )
-        return box_h + box_i
+        if prob.e_local == eh:
+            return box_h
+        # interior elements: no boundary contact -> overlaps the exchange above
+        x_int = x_box if x_raw is None else x_raw
+        return box_h + block(asm_i, x_int, slice(eh, None))
 
     return apply
 
 
-def _split_z(prob: DistPoisson) -> tuple[tuple[Callable, Callable], ...]:
-    """``((Z_h, Z_hᵀ), (Z_i, Z_iᵀ))``: the halo and the interior elements'
-    scatter from and gather into one rank's flat padded box.
+def _bc_wrap(bc_mask: jax.Array | None, f: Callable) -> Callable:
+    """mask∘f∘mask on the Dirichlet subspace — the operator (congruence
+    keeps it SPD there) and every preconditioner ingredient, mirroring the
+    single-device ``poisson_assembled`` / ``precond._mask_wrap`` contract.
+    The optional deferred raw twin is masked too: the mask is elementwise
+    and the exchange only rewrites face slabs, so the masked raw stays a
+    bitwise-valid interior gather source."""
+    if bc_mask is None:
+        return f
 
-    On the slab-lattice map (``DistPoisson.slab_lattice``) each
-    ``_element_blocks`` block is a sub-box of the padded box: Z is
-    ``lattice_scatter`` of its slice, the blocks' element arrays stacked
-    in block order; Zᵀ is ``lattice_gather`` of each block's rows, padded
-    back into the box and summed (blocks share face points, and the sum
-    adds them as the segment sum did).  The interior core's Zᵀ is padded
-    by N on every side and never touches a face plane.  Any other map
-    takes and segment-sums through ``l2g``.
-    """
-    eh, p, m3 = prob.halo_elems, prob.l2g.shape[1], prob.m3
-    if not prob.slab_lattice:
-        l2g_flat = jnp.asarray(prob.l2g.reshape(-1))
+    def wrapped(v, raw=None):
+        if raw is None:
+            return bc_mask * f(bc_mask * v)
+        return bc_mask * f(bc_mask * v, bc_mask * raw)
 
-        def indexed(rows: slice) -> tuple[Callable, Callable]:
-            idx = l2g_flat[rows]
-            return (
-                lambda x: jnp.take(x, idx, axis=0).reshape(-1, p),
-                lambda y: jax.ops.segment_sum(
-                    y.reshape(-1), idx, num_segments=m3
-                ),
-            )
-
-        return indexed(slice(None, eh * p)), indexed(slice(eh * p, None))
-
-    n = prob.n_degree
-    box3 = prob.box_shape[::-1]  # (z, y, x) points
-    blocks, n_halo_blocks = _element_blocks(prob.local_shape)
-
-    def lattice(blks: list) -> tuple[Callable, Callable]:
-        # per block: its (z, y, x) point window in the box, element count
-        wins = [
-            tuple(slice(o * n, (o + e) * n + 1) for o, e in zip(org[::-1], shp[::-1]))
-            for org, shp in blks
-        ]
-        counts = [int(np.prod(shp)) for _, shp in blks]
-
-        def z(x: jax.Array) -> jax.Array:
-            x3 = x.reshape(box3)
-            return jnp.concatenate([
-                lattice_scatter(x3[win].reshape(-1), shp, n)
-                for win, (_, shp) in zip(wins, blks)
-            ])
-
-        def zt(y: jax.Array) -> jax.Array:
-            out, start = None, 0
-            for win, (_, shp), e in zip(wins, blks, counts):
-                part = lattice_gather(y[start:start + e], shp, n)
-                part = jnp.pad(
-                    part.reshape([w.stop - w.start for w in win]),
-                    [(w.start, m - w.stop) for w, m in zip(win, box3)],
-                )
-                out = part if out is None else out + part
-                start += e
-            return out.reshape(-1)
-
-        return z, zt
-
-    return lattice(blocks[:n_halo_blocks]), lattice(blocks[n_halo_blocks:])
+    return wrapped
 
 
-def _apply_assembled(
+def _on_rank(
     prob: DistPoisson,
-    x_box: jax.Array,       # (m3,)
-    g: jax.Array,           # (E_loc, 6, p)
-    w: jax.Array,           # (E_loc, p)
-    *,
-    local_op: Callable[..., jax.Array],
-    two_phase: bool,
-    fused_interior: bool = False,
-    xsum: tuple = _XCH,
-    xcopy: tuple = _XCH,
-    x_raw: jax.Array | None = None,
+    g: jax.Array,
+    w: jax.Array,
+    mask: jax.Array | None,
     screen: jax.Array | None = None,
-) -> jax.Array:
-    """One A-apply inside shard_map, with the Fig. 2 overlap split.
-
-    Z and Zᵀ of each block are ``_split_z``'s: lattice slices on the
-    slab-lattice map, else the indexed take and segment sum.
-
-    ``screen``, when given, is the rank's (E_loc, p) weak mass screen
-    JW·λ(x): it replaces ``w`` on the kernels' w stream with the static
-    ``lam`` pinned to 1.0 (``core.operator.screen_stream``'s contract —
-    kernel signatures unchanged, Pallas' static lam stays a python float).
-
-    ``fused_interior`` replaces the interior block's three-stage pipeline
-    (Z, ``local_op``, Zᵀ) with the single-pass Pallas
-    kernel ``kernels.ops.poisson_assembled_fused`` over the rank-local
-    padded box — the interior elements touch no rank boundary, so their
-    gather source and scatter target are both the local box and the fused
-    apply still overlaps the halo sum-exchange.  The halo block stays
-    split: its scatter-add must be materialized before it can feed the
-    exchange.
-
-    ``xsum``/``xcopy`` carry the exchange plan's (routing, wire) picks for
-    this site.  ``x_raw``, when given, is the deferred twin of ``x_box``
-    (same box *before* its producing sum-exchange): interior gathers read
-    it instead — bitwise identical, since the exchange only rewrites face
-    slabs interior elements never touch — which releases the interior
-    block from the upstream exchange's data dependence (cross-level
-    V-cycle overlap).
-    """
-    eh = prob.halo_elems
-    w_eff, lam_eff = (w, prob.lam) if screen is None else (screen, 1.0)
-    (z_h, zt_h), (z_i, zt_i) = _split_z(prob)
-
-    if two_phase:
-        # paper-faithful: explicit scatter-side halo refresh first
-        with obs.scope("halo.site.operator"):
-            x_box = copy_exchange(
-                x_box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-                xcopy[1], xcopy[0],
-            ).reshape(-1)
-        x_raw = None  # the refreshed box is the only valid source
-    x_int = x_box if x_raw is None else x_raw
-
-    def block(z, zt, x: jax.Array, rows: slice) -> jax.Array:
-        with obs.scope("op.scatter"):
-            u = z(x)
-        with obs.scope("op.local"):
-            y = local_op(u, g[rows], prob.d, lam_eff, w_eff[rows])
-        with obs.scope("op.gather"):
-            return zt(y)
-
-    # halo elements first; their contributions feed the exchange
-    box_h = block(z_h, zt_h, x_box, slice(None, eh))
-    with obs.scope("halo.site.operator"):
-        box_h = sum_exchange(
-            box_h.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xsum[1], xsum[0],
-        ).reshape(-1)
-    if prob.e_local == eh:
-        return box_h
-
-    # interior elements: no boundary contact -> overlaps the exchange above
-    if fused_interior:
-        from ..kernels import ops as _kops  # lazy: kernels import core
-
-        box_i = _kops.poisson_assembled_fused(
-            x_int,
-            jnp.asarray(prob.l2g)[eh:],
-            g[eh:],
-            w_eff[eh:],
-            prob.d,
-            lam=lam_eff,
-        )
-    else:
-        box_i = block(z_i, zt_i, x_int, slice(eh, None))
-    return box_h + box_i
+    bc_mask: jax.Array | None = None,
+) -> DistPoisson:
+    """One rank's view of a level inside shard_map: its ``g``, ``w_local``,
+    ``mask``, ``screen`` and ``bc_mask`` are that rank's shards, so that
+    ``operator.screen_stream`` and ``core.precond``'s level forms read it
+    as they read a single-device problem."""
+    return dataclasses.replace(
+        prob, g=g, w_local=w, mask=mask, screen=screen, bc_mask=bc_mask
+    )
 
 
 def _rank_operator(
-    prob: DistPoisson,
-    g1: jax.Array,
-    w1: jax.Array,
+    rank: DistPoisson,
     *,
-    screen: jax.Array | None,
-    bc_mask: jax.Array | None,
     local_op: Callable[..., jax.Array],
     two_phase: bool,
-    fused_interior: bool,
-    xsum: tuple,
-    xcopy: tuple,
-) -> Callable[[jax.Array], jax.Array]:
-    """The outer A-apply on one rank's consistent padded box (inside shard_map).
+    xsum: tuple = _XCH,
+    xcopy: tuple = _XCH,
+) -> Callable[..., jax.Array]:
+    """A level's A-apply on one rank (a ``_on_rank`` view): the Fig. 2
+    split of ``local_op`` with the exchange plan's picks, on the screen
+    stream (the weak mass screen JW·λ(x) with ``lam`` pinned to 1.0 when
+    present), wrapped as mask∘A∘mask when ``rank.bc_mask`` is given.
+    ``dist_solver``'s Krylov operator and ``solve.operator`` are both this
+    one construction."""
+    w_eff, lam_eff = screen_stream(rank)
 
-    ``_apply_assembled``'s Fig. 2 split with the exchange plan's picks,
-    wrapped as mask∘A∘mask on the Dirichlet subspace when ``bc_mask`` is
-    given.  ``dist_solver``'s Krylov operator and ``solve.operator`` are
-    both this one construction.
-    """
-    def apply(v: jax.Array) -> jax.Array:
-        return _apply_assembled(
-            prob, v, g1, w1, local_op=local_op, two_phase=two_phase,
-            fused_interior=fused_interior, xsum=xsum, xcopy=xcopy,
-            screen=screen,
-        )
+    def kernel(u: jax.Array, rows: slice) -> jax.Array:
+        return local_op(u, rank.g[rows], rank.d, lam_eff, w_eff[rows])
 
-    if bc_mask is None:
-        return apply
-    return lambda v: bc_mask * apply(bc_mask * v)
+    return _bc_wrap(rank.bc_mask, _split_apply(
+        rank, kernel, two_phase=two_phase, xsum=xsum, xcopy=xcopy
+    ))
 
 
-def _resolve_fused(local_op, fused_operator: bool | None) -> bool:
-    """``fused_operator=None``: the kernel policy, unless ``local_op`` pins the split."""
-    if fused_operator is not None:
-        return fused_operator
-    if local_op is not None:
-        return False
-    from ..kernels import ops as _kops  # lazy: kernels import core
+def _level_assembly(prob: DistPoisson):
+    """``precond.level_assembly`` of all the rank's elements on its padded box."""
+    blocks, _ = _element_blocks(prob.local_shape)
+    return level_assembly(prob.l2g, prob.n_degree, prob.local_shape, blocks)
 
-    return _kops.should_fuse_operator()
+
+def _rank_dinv(rank: DistPoisson, xsum: tuple = _XCH) -> jax.Array:
+    """Inverse assembled diagonal of one rank's level (a ``_on_rank`` view)
+    in consistent padded-box storage: ``precond.assembled_diagonal``
+    through the rank's level assembly, completed by one sum-exchange.  The
+    diagonal itself stays unmasked; on Dirichlet problems its inverse is
+    multiplied by the bc mask, mirroring ``precond.masked_dinv``."""
+    diag = _exchange(
+        sum_exchange, rank, assembled_diagonal(rank, _level_assembly(rank)),
+        xsum, "diag",
+    )
+    return 1.0 / diag if rank.bc_mask is None else rank.bc_mask * (1.0 / diag)
+
+
+def _completion(prob: DistPoisson, xsum: tuple, site: str) -> Callable:
+    """A pMG transfer's completion on one level of a rank: its locally
+    summed box ``raw`` -> ``(raw, consistent)``, the consistent box after
+    the level's sum-exchange.  Raw interior slots are already final."""
+    return lambda raw: (raw, _exchange(sum_exchange, prob, raw, xsum, site))
+
+
+def _masked_dot(mask: jax.Array) -> Callable[[jax.Array, jax.Array], jax.Array]:
+    """This rank's share of a dot product: each replicated slot counted by
+    its owner only (``mask``) or weighted (``w``), to be psum'd."""
+    return lambda a, b: jnp.vdot(a * mask, b, precision=_HI)
 
 
 def _aux_operands(prob: DistPoisson) -> tuple:
@@ -1035,88 +947,6 @@ def box_global_indices(prob: DistPoisson) -> np.ndarray:
         )
         out[r] = gidx.transpose(2, 1, 0).reshape(-1)
     return out
-
-
-def _box_dinv(
-    prob: DistPoisson,
-    g1: jax.Array,
-    w1: jax.Array,
-    xsum: tuple = _XCH,
-    screen: jax.Array | None = None,
-) -> jax.Array:
-    """Inverse assembled diagonal in consistent padded-box storage:
-    Z_loc^T diag(S_L + λW) Z made consistent by one sum-exchange.
-    ``screen`` swaps in the weak mass screen JW·λ(x) with lam pinned to
-    1.0 (see ``_apply_assembled``); the diagonal itself stays unmasked —
-    Dirichlet handling multiplies ``1/diag`` by the bc mask afterwards,
-    mirroring ``precond.masked_dinv``."""
-    w_eff, lam_eff = (w1, prob.lam) if screen is None else (screen, 1.0)
-    dloc = local_operator_diagonal(g1, prob.d, lam_eff, w_eff)
-    box_diag = jax.ops.segment_sum(
-        dloc.reshape(-1),
-        jnp.asarray(prob.l2g.reshape(-1)),
-        num_segments=prob.m3,
-    )
-    with obs.scope("halo.site.diag"):
-        box_diag = sum_exchange(
-            box_diag.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-            xsum[1], xsum[0],
-        ).reshape(-1)
-    return 1.0 / box_diag
-
-
-def _box_transfer_pair(
-    lf: DistPoisson,
-    lc: DistPoisson,
-    jmat: jax.Array,
-    w_lf: jax.Array,
-    xsum_f: tuple = _XCH,
-    xsum_c: tuple = _XCH,
-):
-    """(prolong, restrict) between two padded-box levels of one rank.
-
-    Same P = Z_f^T W_f Ĵ Z_c / R = P^T pair as the single-shard
-    ``precond.make_transfer_pair``, with the gathers expressed as local
-    segment-sums plus one halo sum-exchange (interface contributions from
-    neighbouring ranks complete the weighted average / the transpose sum).
-    Inputs are consistent boxes; each output is the ``(raw, consistent)``
-    pair — the locally summed box before and after its halo exchange.  The
-    raw twin's interior slots are bitwise final (the exchange only
-    rewrites face slabs), which is what the overlapped V-cycle hands to
-    the next level's interior work; plain consumers just take ``[1]`` and
-    the unused raw output folds away in tracing.  ``xsum_f``/``xsum_c``
-    are the plan's picks for the fine/coarse sum sites.
-    """
-    l2g_f = jnp.asarray(lf.l2g.reshape(-1))
-    l2g_c = jnp.asarray(lc.l2g.reshape(-1))
-
-    def prolong(x_c: jax.Array) -> tuple[jax.Array, jax.Array]:
-        u_c = jnp.take(x_c, l2g_c, axis=0).reshape(lc.e_local, -1)
-        u_f = tensor3_interp(jmat, u_c)
-        raw = jax.ops.segment_sum(
-            (w_lf * u_f).reshape(-1), l2g_f, num_segments=lf.m3
-        )
-        with obs.scope("halo.site.prolong"):
-            con = sum_exchange(
-                raw.reshape(lf.box_shape[::-1]), lf.grid, lf.axis_name,
-                xsum_f[1], xsum_f[0],
-            ).reshape(-1)
-        return raw, con
-
-    def restrict(r_f: jax.Array) -> tuple[jax.Array, jax.Array]:
-        u_f = w_lf * jnp.take(r_f, l2g_f, axis=0).reshape(lf.e_local, -1)
-        u_c = tensor3_interp(jmat.T, u_f)
-        raw = jax.ops.segment_sum(
-            u_c.reshape(-1), l2g_c, num_segments=lc.m3
-        )
-        with obs.scope("halo.site.restrict"):
-            con = sum_exchange(
-                raw.reshape(lc.box_shape[::-1]), lc.grid, lc.axis_name,
-                xsum_c[1], xsum_c[0],
-            ).reshape(-1)
-        return raw, con
-
-    return prolong, restrict
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1276,9 +1106,8 @@ def _box_schwarz_apply(
     """
     s = sd.overlap
     eh = sd.eh
-    m3_ext = int(np.prod(sd.ext_shape))
-    halo_flat = jnp.asarray(sd.l2g_halo.reshape(-1))
-    int_flat = jnp.asarray(sd.l2g_int.reshape(-1))
+    asm_h = indexed_assembly(sd.l2g_halo, int(np.prod(sd.ext_shape)))
+    asm_i = indexed_assembly(sd.l2g_int, prob.m3)
     fdm = sd.rank_fdm(fdm_fields, slice(None))
 
     def sub(lo: int, hi: int | None) -> SchwarzFDM:
@@ -1301,12 +1130,7 @@ def _box_schwarz_apply(
                 rw.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name, s,
                 xexpand[1], xexpand[0],
             ).reshape(-1)
-        u_h = jnp.take(ext, halo_flat, axis=0).reshape(eh, -1)
-        acc = jax.ops.segment_sum(
-            fdm_solve(fdm_halo, u_h).reshape(-1),
-            halo_flat,
-            num_segments=m3_ext,
-        )
+        acc = asm_h.zt(fdm_solve(fdm_halo, asm_h.z(ext)))
         with obs.scope("halo.site.schwarz"):
             box = contract_exchange(
                 acc.reshape(sd.ext_shape[::-1]), prob.grid, prob.axis_name, s,
@@ -1314,20 +1138,8 @@ def _box_schwarz_apply(
             ).reshape(-1)
         # interior blocks: no shell contact -> overlap the exchanges above
         if eh < prob.e_local:
-            u_i = jnp.take(rw, int_flat, axis=0).reshape(
-                prob.e_local - eh, -1
-            )
-            box = box + jax.ops.segment_sum(
-                fdm_solve(fdm_int, u_i).reshape(-1),
-                int_flat,
-                num_segments=prob.m3,
-            )
-        with obs.scope("halo.site.schwarz"):
-            out = sum_exchange(
-                box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-                xsum[1], xsum[0],
-            ).reshape(-1)
-        return wsq * out
+            box = box + asm_i.zt(fdm_solve(fdm_int, asm_i.z(rw)))
+        return wsq * _exchange(sum_exchange, prob, box, xsum, "schwarz")
 
     return apply
 
@@ -1375,6 +1187,48 @@ def _exchange_sites(
     return sites
 
 
+def _estimate(
+    prob: DistPoisson,
+    mesh: jax.sharding.Mesh,
+    estimate: Callable,
+    out_specs: Any,
+    *,
+    local_op: Callable[..., jax.Array] | None,
+    two_phase: bool,
+):
+    """``estimate(A, D⁻¹, seed, dot=, psum=)`` over the ranks, compiled and
+    run once: each rank's operator and inverse diagonal are the solver's
+    (``_rank_operator``, ``_rank_dinv``), dots are replica-masked and
+    psum'd, and the seed is a hash of *global* DOF indices, so consistent
+    across replicas; on Dirichlet problems it is zero on the Dirichlet
+    slots (mirrors ``precond.masked_seed``: no null-space pollution)."""
+    op = local_op or local_poisson
+    spec = P(prob.axis_name)
+    seed_boxes = jnp.asarray(seed_values(box_global_indices(prob)), prob.dtype)
+    if prob.bc_mask is not None:
+        seed_boxes = seed_boxes * prob.bc_mask.astype(seed_boxes.dtype)
+    aux = _aux_operands(prob)
+
+    def shard_fn(g_s, w_s, mask_s, seed_s, aux_s):
+        rank = _on_rank(prob, g_s[0], w_s[0], mask_s[0], *_aux_split(prob, aux_s))
+        return estimate(
+            _rank_operator(rank, local_op=op, two_phase=two_phase),
+            _rank_dinv(rank), seed_s[0],
+            dot=_masked_dot(rank.mask), psum=_psum(prob.axis_name),
+        )
+
+    fn = shard_map(
+        shard_fn,
+        mesh=mesh,
+        in_specs=(spec, spec, spec, spec, tuple(spec for _ in aux)),
+        out_specs=out_specs,
+        # check_rep cannot type the Lanczos / power-iteration carries
+        # (sharded iterate, replicated psum-derived scalars)
+        check_rep=False,
+    )
+    return jax.jit(fn)(prob.g, prob.w_local, prob.mask, seed_boxes, aux)
+
+
 def dist_spectrum(
     prob: DistPoisson,
     mesh: jax.sharding.Mesh,
@@ -1394,44 +1248,10 @@ def dist_spectrum(
       ``(lmin, lmax)`` python floats (the compiled estimate is pulled
       eagerly at setup time).
     """
-    op = local_op or local_poisson
-    spec = P(prob.axis_name)
-    seed_boxes = jnp.asarray(seed_values(box_global_indices(prob)), prob.dtype)
-    if prob.bc_mask is not None:
-        # Dirichlet: estimate on the interior subspace — masked seed, no
-        # null-space pollution (mirrors precond.masked_seed)
-        seed_boxes = seed_boxes * prob.bc_mask.astype(seed_boxes.dtype)
-    aux = _aux_operands(prob)
-
-    def shard_fn(g_s, w_s, mask_s, seed_s, aux_s):
-        g1, w1, m1 = g_s[0], w_s[0], mask_s[0]
-        s1, bcm1 = _aux_split(prob, aux_s)
-        base = lambda v: _apply_assembled(
-            prob, v, g1, w1, local_op=op, two_phase=two_phase, screen=s1
-        )
-        operator = base if bcm1 is None else (
-            lambda v: bcm1 * base(bcm1 * v)
-        )
-        dinv = _box_dinv(prob, g1, w1, screen=s1)
-        if bcm1 is not None:
-            dinv = bcm1 * dinv
-        mdot = lambda a, bb: jnp.vdot(a * m1, bb, precision=_HI)
-        lmin, lmax = lanczos_extremes(
-            operator, dinv, seed_s[0],
-            iters=lanczos_iters, dot=mdot,
-            psum=_psum(prob.axis_name),
-        )
-        return lmin, lmax
-
-    fn = shard_map(
-        shard_fn,
-        mesh=mesh,
-        in_specs=(spec, spec, spec, spec, tuple(spec for _ in aux)),
-        out_specs=(P(), P()),
-        # check_rep cannot type the mixed sharded/replicated Lanczos carry
-        check_rep=False,
+    lmin, lmax = _estimate(
+        prob, mesh, functools.partial(lanczos_extremes, iters=lanczos_iters),
+        (P(), P()), local_op=local_op, two_phase=two_phase,
     )
-    lmin, lmax = jax.jit(fn)(prob.g, prob.w_local, prob.mask, seed_boxes, aux)
     return float(lmin), float(lmax)
 
 
@@ -1447,44 +1267,10 @@ def dist_lambda_max(
     factor).  Pass the result to ``dist_cg(..., lmax=...)`` so repeated
     Chebyshev solves don't re-run the power iteration inside the compiled
     program (keeps benchmark timings pure solve)."""
-    op = local_op or local_poisson
-    spec = P(prob.axis_name)
-    seed_boxes = jnp.asarray(seed_values(box_global_indices(prob)), prob.dtype)
-    if prob.bc_mask is not None:
-        seed_boxes = seed_boxes * prob.bc_mask.astype(seed_boxes.dtype)
-    aux = _aux_operands(prob)
-
-    def shard_fn(g_s, w_s, mask_s, seed_s, aux_s):
-        g1, w1, m1 = g_s[0], w_s[0], mask_s[0]
-        s1, bcm1 = _aux_split(prob, aux_s)
-        base = lambda v: _apply_assembled(
-            prob, v, g1, w1, local_op=op, two_phase=two_phase, screen=s1
-        )
-        operator = base if bcm1 is None else (
-            lambda v: bcm1 * base(bcm1 * v)
-        )
-        dinv = _box_dinv(prob, g1, w1, screen=s1)
-        if bcm1 is not None:
-            dinv = bcm1 * dinv
-        mdot = lambda a, bb: jnp.vdot(a * m1, bb, precision=_HI)
-        return power_lambda_max(
-            operator, dinv, seed_s[0],
-            iters=power_iters, dot=mdot,
-            psum=_psum(prob.axis_name),
-        )
-
-    fn = shard_map(
-        shard_fn,
-        mesh=mesh,
-        in_specs=(spec, spec, spec, spec, tuple(spec for _ in aux)),
-        out_specs=P(),
-        # old jax's check_rep cannot type the power-iteration scan carry
-        # (sharded iterate + replicated psum-derived norm)
-        check_rep=False,
-    )
-    return float(
-        jax.jit(fn)(prob.g, prob.w_local, prob.mask, seed_boxes, aux)
-    )
+    return float(_estimate(
+        prob, mesh, functools.partial(power_lambda_max, iters=power_iters),
+        P(), local_op=local_op, two_phase=two_phase,
+    ))
 
 
 def dist_cg(
@@ -1530,7 +1316,6 @@ def dist_solver(
     precond_dtype: Any = None,
     cg_variant: str = "standard",
     local_op: Callable[..., jax.Array] | None = None,
-    fused_operator: bool | None = None,
     two_phase: bool = False,
     exchange: str | None = None,
     exchange_wire: str = "native",
@@ -1572,7 +1357,7 @@ def dist_solver(
         (no extra exchange — ``w_local`` already carries the global
         inverse degree), and each coarse apply is one batched element
         matvec riding the standard halo/interior split + sum-exchange
-        (``_box_galerkin_apply``) — matching the single-device
+        (``_split_apply``) — matching the single-device
         ``make_pmg_preconditioner(coarse_op="galerkin_mat")`` up to the
         order of summation, including under ``precond_dtype``.  The
         *chained* "galerkin" form stays single-device (its coarse applies
@@ -1595,16 +1380,6 @@ def dist_solver(
         (Polak–Ribière β; robust when M⁻¹ is only fp32-symmetric — see
         core.cg).
       local_op: optional Pallas element kernel replacing the jnp reference.
-      fused_operator: run the outer operator's interior block through the
-        single-pass fused assembled kernel
-        (``kernels.ops.poisson_assembled_fused`` — gather, local op and
-        scatter-add in one Pallas pass over the rank-local box) instead of
-        the split pipeline; interpret mode only (a native backend
-        raises).  ``None`` defers to ``kernels.ops.should_fuse_operator``
-        (off unless ``HIPBONE_FUSED=1``), except when an explicit
-        ``local_op`` pins the split pipeline.  Preconditioner-internal
-        A-applies keep the split form — they run in ``precond_dtype`` and
-        their traffic is not the Eq. 4 bound this kernel targets.
       two_phase: paper-faithful two-phase exchange instead of the fused one.
       exchange: halo-exchange policy — "face_sweep" (per-dim sweep, the
         default), "crystal" (staged bidirectional route), "fused"
@@ -1658,14 +1433,14 @@ def dist_solver(
     them later on a hard system, and a tolerance crossing can then land
     an iteration apart, with solutions that agree to the tolerance.
 
-    The Jacobi diagonal is assembled in padded-box storage — local element
-    diagonals gathered with Z_loc^T then made consistent by one
-    sum-exchange — so its apply is a pure elementwise scale (replicas stay
-    consistent for free).  Chebyshev A-applies reuse the
-    communication-hiding split operator, and the Lanczos spectrum
-    estimation runs with replica-masked inner products; its seed vector is
-    a hash of *global* DOF indices, hence consistent across replicas by
-    construction.
+    The Jacobi diagonal is ``precond.assembled_diagonal`` in padded-box
+    storage — local element diagonals gathered with the rank's Zᵀ, then
+    made consistent by one sum-exchange — so its apply is a pure
+    elementwise scale (replicas stay consistent for free).  Chebyshev
+    A-applies reuse the communication-hiding split operator, and the
+    Lanczos spectrum estimation runs with replica-masked inner products;
+    its seed vector is a hash of *global* DOF indices, hence consistent
+    across replicas by construction.
 
     ``precond="schwarz"`` runs symmetric weighted overlapping Schwarz with
     the overlap transported by ``comms.halo.expand_exchange`` /
@@ -1675,7 +1450,9 @@ def dist_solver(
 
     ``precond="pmg"`` runs the degree-ladder V-cycle of ``core.precond``
     with every level's A-apply, transfer, diagonal and Schwarz blocks
-    assembled through this rank's *coarsened* padded box — coarse-level
+    assembled through this rank's *coarsened* padded box (the transfers are
+    ``precond.make_transfer_pair``, completed by the level's sum-exchange
+    into the ``(raw, consistent)`` pair) — coarse-level
     applies are latency-dominated, so the halo/interior overlap matters
     most there.  The coarsest (degree-1) level is solved by a
     full-interval degree-``pmg_coarse_iters`` Chebyshev.
@@ -1690,7 +1467,7 @@ def dist_solver(
       once here) as arguments; ``solve.exchange_plan`` is the resolved
       plan.  ``solve.operator(x_boxes, *solve.operator_operands)`` is the
       un-jitted outer A-apply the solve iterates with (its plan, split,
-      ``fused_operator``, ``two_phase`` and ``local_op``) on ``(R, m3)``
+      ``two_phase`` and ``local_op``) on ``(R, m3)``
       consistent boxes, its operands a subset of ``solve.operands``.
     """
     if precond not in PRECOND_KINDS:
@@ -1714,7 +1491,6 @@ def dist_solver(
     if pmg_smooth_degree is None:
         pmg_smooth_degree = pmg_smooth_degree_default(pmg_smoother)
     op = local_op or local_poisson
-    fused_operator = _resolve_fused(local_op, fused_operator)
     spec = P(prob.axis_name)
     hist_len = n_iter
 
@@ -1728,13 +1504,6 @@ def dist_solver(
     pprob = prob if not mixed else dataclasses.replace(
         prob, d=prob.d.astype(cdtype), dtype=cdtype
     )
-
-    # variable-coefficient state: static presence flags (shard_map pytree
-    # specs must be static, so optional arrays ride conditional tuples) —
-    # coarse pMG levels inherit both fields from the fine problem, so one
-    # flag pair covers every level
-    has_screen = prob.screen is not None
-    has_bc = prob.bc_mask is not None
 
     def _masked_seed(lvl: DistPoisson) -> jax.Array:
         """Spectrum-estimation seed for one level, Dirichlet rows zeroed
@@ -1752,8 +1521,7 @@ def dist_solver(
     )
 
     if precond == "pmg":
-        levels, jmats = build_pmg_levels(pprob, pmg_ladder)
-        jmats = [jnp.asarray(j, cdtype) for j in jmats]
+        levels, _ = build_pmg_levels(pprob, pmg_ladder)
         # materialized Galerkin: per-rank block assembly at setup (pprob is
         # the cast view when mixed, so blocks are assembled once in cdtype)
         gal_blocks = (
@@ -1762,19 +1530,12 @@ def dist_solver(
             else [() for _ in levels[1:]]
         )
         pmg_data = tuple(
-            (
-                lvl.g,
-                lvl.w_local,
-                lvl.mask,
-                _masked_seed(lvl),
-            )
-            + ((lvl.screen,) if has_screen else ())
-            + ((lvl.bc_mask,) if has_bc else ())
+            (lvl.g, lvl.w_local, lvl.mask, _masked_seed(lvl), _aux_operands(lvl))
             + ((blk,) if pmg_coarse_op == "galerkin_mat" else ())
             for lvl, blk in zip(levels[1:], gal_blocks)
         )
     else:
-        levels, jmats, pmg_data = [pprob], [], ()
+        levels, pmg_data = [pprob], ()
     # fine-level optional arrays ride their own conditional tuple
     aux_data = _aux_operands(prob)
 
@@ -1824,89 +1585,56 @@ def dist_solver(
     if vcycle_overlap is None:
         vcycle_overlap = os.environ.get("HIPBONE_VCYCLE_OVERLAP", "1") != "0"
 
-    def outer_operator(g1, w1, s1, bcm1):
+    def outer_operator(rank: DistPoisson):
         """This rank's outer A-apply: the solve's and ``solve.operator``'s."""
         return _rank_operator(
-            prob, g1, w1, screen=s1, bc_mask=bcm1, local_op=op,
-            two_phase=two_phase, fused_interior=fused_operator,
-            xsum=xsum[0], xcopy=xcopy[0],
+            rank, local_op=op, two_phase=two_phase, xsum=xsum[0], xcopy=xcopy[0]
         )
 
     def shard_fn(b_s, g_s, w_s, mask_s, seed_s, aux_s, pmg_s, schwarz_s):
         b1, g1, w1, m1 = b_s[0], g_s[0], w_s[0], mask_s[0]
         # make rhs consistent (replicas hold true values)
-        with obs.scope("halo.site.rhs"):
-            b1 = copy_exchange(
-                b1.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-                xcopy[0][1], xcopy[0][0],
-            ).reshape(-1)
-        s1, bcm1 = _aux_split(prob, aux_s)
-
-        def _bc_wrap(bm, f):
-            """mask∘f∘mask on the Dirichlet subspace — both the operator
-            (congruence keeps it SPD there) and every preconditioner
-            ingredient, mirroring the single-device ``poisson_assembled`` /
-            ``precond._mask_wrap`` contract.  The optional deferred raw
-            twin is masked too: the mask is elementwise and the exchange
-            only rewrites face slabs, so the masked raw stays a
-            bitwise-valid interior gather source."""
-            if bm is None:
-                return f
-            def wrapped(v, raw=None):
-                if raw is None:
-                    return bm * f(bm * v)
-                return bm * f(bm * v, bm * raw)
-            return wrapped
-
-        operator = outer_operator(g1, w1, s1, bcm1)
+        b1 = _exchange(copy_exchange, prob, b1, xcopy[0], "rhs")
+        rank = _on_rank(prob, g1, w1, m1, *_aux_split(prob, aux_s))
+        operator = outer_operator(rank)
         psum = _psum(prob.axis_name)
 
-        # preconditioner-dtype views of the fine-level shards: the casts are
-        # single ops reused by every M⁻¹-internal A-apply in the program
+        # preconditioner-dtype view of the fine level: the casts are single
+        # ops reused by every M⁻¹-internal A-apply in the program; every
+        # level's operator takes the optional deferred raw twin
         if mixed:
-            g1c, w1c, m1c = (
-                g1.astype(cdtype), w1.astype(cdtype), m1.astype(cdtype)
+            cast = lambda a: None if a is None else a.astype(cdtype)
+            prank = _on_rank(pprob, *map(cast, (
+                g1, w1, m1, rank.screen, rank.bc_mask
+            )))
+            operator_pc = _rank_operator(
+                prank, local_op=op, two_phase=two_phase,
+                xsum=xsum[0], xcopy=xcopy[0],
             )
-            s1c = None if s1 is None else s1.astype(cdtype)
-            bcm1c = None if bcm1 is None else bcm1.astype(cdtype)
-            operator_pc = _bc_wrap(bcm1c, lambda v, raw=None: _apply_assembled(
-                pprob, v, g1c, w1c, local_op=op, two_phase=two_phase,
-                xsum=xsum[0], xcopy=xcopy[0], x_raw=raw, screen=s1c,
-            ))
         else:
-            g1c, w1c, m1c = g1, w1, m1
-            s1c, bcm1c = s1, bcm1
-            # same program as the outer operator (fused interior included),
-            # plus the optional deferred raw twin for the V-cycle overlap
-            operator_pc = _bc_wrap(bcm1c, lambda v, raw=None: _apply_assembled(
-                prob, v, g1, w1, local_op=op, two_phase=two_phase,
-                fused_interior=fused_operator,
-                xsum=xsum[0], xcopy=xcopy[0], x_raw=raw, screen=s1,
-            ))
+            prank, operator_pc = rank, operator
 
-        def schwarz_apply(i: int, lvl: DistPoisson, bm):
+        def schwarz_apply(i: int, lvl: DistPoisson):
             nf = len(schwarz_setups[i].fdm_fields)
             fields1 = tuple(f[0] for f in schwarz_s[i][:nf])
-            return _bc_wrap(bm, _box_schwarz_apply(
+            return _bc_wrap(lvl.bc_mask, _box_schwarz_apply(
                 lvl, schwarz_setups[i], fields1, schwarz_s[i][nf][0],
                 xsum=xsum[i], xexpand=xexp[i], xcontract=xcon[i],
             ))
 
         pc = None
         if precond != "none":
-            dinv = _box_dinv(pprob, g1c, w1c, xsum[0], screen=s1c)
-            if bcm1c is not None:
-                dinv = bcm1c * dinv
+            dinv = _rank_dinv(prank, xsum[0])
             if precond == "jacobi":
                 pc = jacobi_apply(dinv)
             elif precond == "schwarz":
-                pc = schwarz_apply(0, pprob, bcm1c)
+                pc = schwarz_apply(0, prank)
             elif precond == "chebyshev":
                 if lmax is None:
-                    mdot = lambda a, bb: jnp.vdot(a * m1c, bb, precision=_HI)
                     lmin_e, lmax_e = lanczos_extremes(
                         operator_pc, dinv, seed_s[0],
-                        iters=lanczos_iters, dot=mdot, psum=psum,
+                        iters=lanczos_iters, dot=_masked_dot(prank.mask),
+                        psum=psum,
                     )
                     top = CHEB_SAFETY * lmax_e
                     low = CHEB_LMIN_SAFETY * lmin_e
@@ -1919,61 +1647,38 @@ def dist_solver(
                     operator_pc, dinv, top, lmin=low, degree=cheb_degree
                 )
             else:  # pmg
-                lvl_ops = [operator_pc]
-                lvl_dinvs = [dinv]
-                lvl_masks = [m1c]
+                ranks, lvl_ops, lvl_dinvs = [prank], [operator_pc], [dinv]
                 lvl_seeds = [seed_s[0]]
-                lvl_wlocs = [w1c]
-                lvl_bcms = [bcm1c]
-                for li, (lvl, data_l) in enumerate(
+                for li, (lvl, (g_l, w_l, mk_l, sd_l, aux_l, *blk)) in enumerate(
                     zip(levels[1:], pmg_s), start=1
                 ):
-                    g_l, w_l, mk_l, sd_l = data_l[:4]
-                    ix = 4
-                    scr_l = None
-                    if has_screen:
-                        scr_l = data_l[ix][0]
-                        ix += 1
-                    bcm_l = None
-                    if has_bc:
-                        bcm_l = data_l[ix][0]
-                        ix += 1
-                    g1l, w1l = g_l[0], w_l[0]
-                    if pmg_coarse_op == "galerkin_mat":
+                    r = _on_rank(
+                        lvl, g_l[0], w_l[0], mk_l[0], *_aux_split(lvl, aux_l)
+                    )
+                    if blk:
                         # materialized P^T A P apply: batched element
                         # matvec + the standard sum-exchange, zero
                         # fine-operator work per coarse apply; the bc wrap
                         # uses this level's own mask (R = Pᵀ smears
                         # interior residual onto coarse Dirichlet rows)
-                        lvl_ops.append(_bc_wrap(
-                            bcm_l,
-                            _box_galerkin_apply(
-                                lvl, data_l[ix][0], two_phase=two_phase,
-                                xsum=xsum[li], xcopy=xcopy[li],
+                        lvl_ops.append(_bc_wrap(r.bc_mask, _split_apply(
+                            r,
+                            lambda u, rows, b=blk[0][0]: block_matvec_einsum(
+                                b[rows], u
                             ),
-                        ))
+                            two_phase=two_phase, xsum=xsum[li],
+                            xcopy=xcopy[li], site="galerkin",
+                        )))
                     else:
-                        lvl_ops.append(_bc_wrap(
-                            bcm_l,
-                            lambda v, raw=None, lvl=lvl, g1l=g1l, w1l=w1l,
-                            li=li, scr_l=scr_l:
-                            _apply_assembled(
-                                lvl, v, g1l, w1l, local_op=op,
-                                two_phase=two_phase,
-                                xsum=xsum[li], xcopy=xcopy[li], x_raw=raw,
-                                screen=scr_l,
-                            ),
+                        lvl_ops.append(_rank_operator(
+                            r, local_op=op, two_phase=two_phase,
+                            xsum=xsum[li], xcopy=xcopy[li],
                         ))
                     # smoother diagonals stay the rediscretized ones for
                     # the Galerkin variants, matching the single-device path
-                    dinv_l = _box_dinv(lvl, g1l, w1l, xsum[li], screen=scr_l)
-                    if bcm_l is not None:
-                        dinv_l = bcm_l * dinv_l
-                    lvl_dinvs.append(dinv_l)
-                    lvl_masks.append(mk_l[0])
+                    lvl_dinvs.append(_rank_dinv(r, xsum[li]))
+                    ranks.append(r)
                     lvl_seeds.append(sd_l[0])
-                    lvl_wlocs.append(w1l)
-                    lvl_bcms.append(bcm_l)
                 # every lvl_ops entry accepts (v, raw=None); the pair form
                 # feeds the overlapped V-cycle's deferred interior gathers
                 lvl_ops_pair = [
@@ -1982,15 +1687,14 @@ def dist_solver(
 
                 smoothers, smoothers_pair = [], []
                 for i in range(len(levels) - 1):
-                    mdot = lambda a, bb, mk=lvl_masks[i]: jnp.vdot(a * mk, bb, precision=_HI)
                     if pmg_smoother == "schwarz":
-                        base = schwarz_apply(i, levels[i], lvl_bcms[i])
+                        base = schwarz_apply(i, ranks[i])
                     else:
                         base = lvl_dinvs[i]
                     lo, lmax_e, _ = smoother_interval(
                         lvl_ops[i], base, lvl_seeds[i],
                         smoother=pmg_smoother, lanczos_iters=lanczos_iters,
-                        dot=mdot, psum=psum,
+                        dot=_masked_dot(ranks[i].mask), psum=psum,
                     )
                     smooth = chebyshev_apply(
                         lvl_ops[i],
@@ -2015,10 +1719,10 @@ def dist_solver(
                             )
                         )
                 # coarsest (degree-1): full-interval Chebyshev "solve"
-                mdot_c = lambda a, bb: jnp.vdot(a * lvl_masks[-1], bb, precision=_HI)
                 lmin_e, lmax_e = lanczos_extremes(
                     lvl_ops[-1], lvl_dinvs[-1], lvl_seeds[-1],
-                    iters=lanczos_iters, dot=mdot_c, psum=psum,
+                    iters=lanczos_iters, dot=_masked_dot(ranks[-1].mask),
+                    psum=psum,
                 )
                 coarse_apply = chebyshev_apply(
                     lvl_ops[-1],
@@ -2033,11 +1737,15 @@ def dist_solver(
                     lmin=CHEB_LMIN_SAFETY * lmin_e,
                     degree=pmg_coarse_iters,
                 )
+                # the single-device transfers over the ranks' level
+                # assemblies, each completed into its (raw, consistent) pair
                 prolongs, restricts = [], []
                 for i in range(len(levels) - 1):
-                    p_up, r_down = _box_transfer_pair(
-                        levels[i], levels[i + 1], jmats[i], lvl_wlocs[i],
-                        xsum[i], xsum[i + 1],
+                    p_up, r_down = make_transfer_pair(
+                        ranks[i], ranks[i + 1],
+                        _level_assembly(ranks[i]), _level_assembly(ranks[i + 1]),
+                        _completion(ranks[i], xsum[i], "prolong"),
+                        _completion(ranks[i + 1], xsum[i + 1], "restrict"),
                     )
                     prolongs.append(p_up)
                     restricts.append(r_down)
@@ -2094,9 +1802,9 @@ def dist_solver(
         mesh=mesh,
         in_specs=(
             spec, spec, spec, spec, spec,
-            tuple(spec for _ in aux_data),
-            tuple(tuple(spec for _ in entry) for entry in pmg_data),
-            tuple(tuple(spec for _ in lvl) for lvl in schwarz_data),
+            jax.tree.map(lambda _: spec, aux_data),
+            jax.tree.map(lambda _: spec, pmg_data),
+            jax.tree.map(lambda _: spec, schwarz_data),
         ),
         out_specs=(spec, P(), stat_spec, stat_spec, P()),
         # old jax's check_rep has no rule for while_loop (tol mode) and
@@ -2105,7 +1813,7 @@ def dist_solver(
         # replicated outputs are psum-derived either way
         check_rep=tol is None and not need_power and precond != "schwarz",
     )
-    obs.tally(f"op.assembly.{'lattice' if prob.slab_lattice else 'indexed'}")
+    obs.tally(f"op.assembly.{_split_kind(prob)}")
     operands = _place(mesh, spec, (
         prob.g, prob.w_local, prob.mask, seed_boxes, aux_data, pmg_data,
         schwarz_data,
@@ -2116,8 +1824,8 @@ def dist_solver(
         return jitted(b_boxes, *operands)
 
     def operator_fn(x_s, g_s, w_s, aux_s):
-        s1, bcm1 = _aux_split(prob, aux_s)
-        return outer_operator(g_s[0], w_s[0], s1, bcm1)(x_s[0])[None]
+        rank = _on_rank(prob, g_s[0], w_s[0], None, *_aux_split(prob, aux_s))
+        return outer_operator(rank)(x_s[0])[None]
 
     solve.program, solve.operands = fn, operands
     # the A-apply the solve iterates with, on its own: (x_boxes,
@@ -2212,8 +1920,7 @@ def dist_cg_scattered(
         )
     op = local_op or local_poisson
     spec = P(prob.axis_name)
-    l2g_flat = jnp.asarray(prob.l2g.reshape(-1))
-    m3 = prob.m3
+    asm = indexed_assembly(prob.l2g, prob.m3)
     cdtype = jnp.dtype(prob.dtype if precond_dtype is None else precond_dtype)
     mixed = cdtype != jnp.dtype(prob.dtype)
     pprob = prob if not mixed else dataclasses.replace(
@@ -2240,13 +1947,7 @@ def dist_cg_scattered(
     xs = exchange_plan.lookup("sum", 0)
 
     def gather_scatter(y_l):
-        box = jax.ops.segment_sum(y_l.reshape(-1), l2g_flat, num_segments=m3)
-        with obs.scope("halo.site.scattered"):
-            box = sum_exchange(
-                box.reshape(prob.box_shape[::-1]), prob.grid, prob.axis_name,
-                xs[1], xs[0],
-            ).reshape(-1)
-        return jnp.take(box, l2g_flat, axis=0).reshape(y_l.shape)
+        return asm.z(_exchange(sum_exchange, prob, asm.zt(y_l), xs, "scattered"))
 
     def shard_fn(b_s, g_s, w_s, seed_s):
         # caller passes a consistent b_L (NekBone gather-scatters its random
@@ -2275,20 +1976,14 @@ def dist_cg_scattered(
         if precond != "none":
             # assembled diag in box storage, scattered to the local layout:
             # Z diag(A)⁻¹ — consistent on the continuous subspace for free
-            dinv_l = jnp.take(
-                _box_dinv(pprob, g1c, w1c), l2g_flat, axis=0
-            ).reshape(b1.shape)
+            dinv_l = asm.z(_rank_dinv(_on_rank(pprob, g1c, w1c, None)))
             if precond == "jacobi":
                 pc = jacobi_apply(dinv_l)
             else:
-                wdot = lambda a, bb: jnp.vdot(a * w1c, bb, precision=_HI)
                 if lmax is None:
-                    seed_l = jnp.take(seed_s[0], l2g_flat, axis=0).reshape(
-                        b1.shape
-                    )
                     lmin_e, lmax_e = lanczos_extremes(
-                        operator_pc, dinv_l, seed_l,
-                        iters=lanczos_iters, dot=wdot, psum=psum,
+                        operator_pc, dinv_l, asm.z(seed_s[0]),
+                        iters=lanczos_iters, dot=_masked_dot(w1c), psum=psum,
                     )
                     top = CHEB_SAFETY * lmax_e
                     low = CHEB_LMIN_SAFETY * lmin_e

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import sem
-from .gather_scatter import gather, scatter
+from .gather_scatter import Assembly
 from .operator import local_operator_columns
 
 
@@ -170,39 +170,31 @@ def block_matvec_einsum(blocks: jax.Array, u: jax.Array) -> jax.Array:
 
 def galerkin_block_apply(
     blocks: jax.Array,
-    l2g: jax.Array | np.ndarray,
-    n_global: int,
+    asm: Assembly,
     *,
     matvec: Callable[[jax.Array, jax.Array], jax.Array] | None = None,
 ) -> Callable[[jax.Array], jax.Array]:
     """Assembled coarse-operator apply ``x → Z_cᵀ [B_e (Z_c x)_e]``.
 
-    Single-device form: scatter, one batched dense element matvec, gather —
-    no fine-operator work.  ``matvec`` lets callers swap in the Pallas
+    Single-device form: Z_c, one batched dense element matvec, Z_cᵀ — no
+    fine-operator work; ``asm`` is the coarse level's
+    ``gather_scatter.assembly``.  ``matvec`` lets callers swap in the Pallas
     batched matvec (``kernels.ops.block_matvec``); default is the einsum.
-    The sharded analogue (halo/interior split + sum-exchange) is
-    ``distributed._box_galerkin_apply``.
+    The sharded analogue (the halo/interior split with this matvec as its
+    element kernel, and the sum-exchange) is ``distributed._split_apply``.
     """
     mv = matvec or block_matvec_einsum
-    l2g = jnp.asarray(l2g)
-
-    def apply(x_c: jax.Array) -> jax.Array:
-        return gather(mv(blocks, scatter(x_c, l2g)), l2g, n_global)
-
-    return apply
+    return lambda x_c: asm.zt(mv(blocks, asm.z(x_c)))
 
 
-def galerkin_assembled_diagonal(
-    blocks: jax.Array, l2g: jax.Array | np.ndarray, n_global: int
-) -> jax.Array:
+def galerkin_assembled_diagonal(blocks: jax.Array, asm: Assembly) -> jax.Array:
     """Exact assembled diagonal of the materialized Galerkin operator.
 
-    ``diag(Z_cᵀ B Z_c)`` = gather of the per-element block diagonals.  The
+    ``diag(Z_cᵀ B Z_c)`` = Z_cᵀ of the per-element block diagonals.  The
     pMG smoothers keep the *rediscretized* diagonal by default (the
     standard spectrally-equivalent choice, and what keeps ``galerkin_mat``
     iteration-identical to the chained form); this exact diagonal is
     exposed for experimentation and used by tests as an independent
     cross-check of the block assembly.
     """
-    diag_loc = jnp.diagonal(blocks, axis1=1, axis2=2)
-    return gather(diag_loc, jnp.asarray(l2g), n_global)
+    return asm.zt(jnp.diagonal(blocks, axis1=1, axis2=2))

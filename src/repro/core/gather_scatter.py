@@ -22,10 +22,16 @@ Two forms of Z and Z^T, the same operators:
            as a product with a 0/1 matrix), with no index.
 On TPU an indexed gather or scatter costs several ns per index, hundreds of
 times the HBM time of its bytes; the lattice form runs at HBM speed.
+
+Solver code takes both through one seam, an :class:`Assembly`:
+:func:`assembly` is the one place that chooses between the forms, for a
+whole box (one device) or for element blocks of a box (a rank's halo or
+interior in its padded box); :func:`indexed_assembly` is the indexed form.
 """
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +43,9 @@ from .mesh import lattice_l2g
 _HI = lax.Precision.HIGHEST
 
 __all__ = [
+    "Assembly",
+    "assembly",
+    "indexed_assembly",
     "scatter",
     "gather",
     "gather_scatter",
@@ -67,14 +76,32 @@ def gather_scatter(y_l: jax.Array, l2g: jax.Array, n_global: int) -> jax.Array:
     return scatter(gather(y_l, l2g, n_global), l2g)
 
 
-def is_lattice(l2g, shape: tuple[int, int, int], n_degree: int) -> bool:
+Block = tuple[tuple[int, int, int], tuple[int, int, int]]
+
+
+def is_lattice(
+    l2g,
+    shape: tuple[int, int, int],
+    n_degree: int,
+    blocks: Sequence[Block] | None = None,
+) -> bool:
     """Whether ``l2g`` is exactly the box lattice numbering of (shape, N).
 
-    Host-side numpy, once at set-up: the lattice Z/Z^T pair may stand in for
-    the indexed one only where this holds.
+    ``blocks``, when given, are ``(origin, shape)`` element sub-boxes of the
+    box (in elements along x, y, z): ``l2g`` must then number the blocks'
+    elements one block after another, each block in its own lattice order,
+    onto the box's points.  Host-side numpy, once at set-up: the lattice
+    Z/Z^T pair may stand in for the indexed one only where this holds.
     """
     l2g = np.asarray(l2g)
     expect = lattice_l2g(shape, n_degree)
+    if blocks is not None:
+        ex, ey, ez = shape
+        grid = expect.reshape(ez, ey, ex, -1)
+        expect = np.concatenate([expect[:0]] + [
+            grid[k:k + bz, j:j + by, i:i + bx].reshape(-1, grid.shape[-1])
+            for (i, j, k), (bx, by, bz) in blocks
+        ])
     return l2g.shape == expect.shape and bool(np.array_equal(l2g, expect))
 
 
@@ -178,6 +205,86 @@ def lattice_gather(
     w = _fold(w, 2, ey, n)  # (k, c, gy, gx)
     w = _fold(w, 0, ez, n)  # (gz, gy, gx)
     return w.reshape(-1)
+
+
+class Assembly(NamedTuple):
+    """Z and Z^T of one element-to-point map, and the form they take.
+
+    ``z``: (N_G,) -> (E, p); ``zt``: (E, p) -> (N_G,); ``kind``: "lattice"
+    or "indexed".
+    """
+
+    z: Callable[[jax.Array], jax.Array]
+    zt: Callable[[jax.Array], jax.Array]
+    kind: str
+
+
+def indexed_assembly(l2g, n_points: int) -> Assembly:
+    """Z and Z^T as the take and segment sum through ``l2g`` (any map)."""
+    l2g = jnp.asarray(l2g)
+    return Assembly(
+        lambda x: scatter(x, l2g), lambda y: gather(y, l2g, n_points), "indexed"
+    )
+
+
+def assembly(
+    l2g,
+    n_degree: int,
+    box_shape: tuple[int, int, int],
+    blocks: Sequence[Block] | None = None,
+) -> Assembly:
+    """Z and Z^T of ``l2g`` onto the points of an element box: the lattice
+    form where ``l2g`` is the lattice numbering of ``blocks``, else indexed.
+
+    ``box_shape`` is the box in elements (x, y, z); its points are the
+    lattice ``(ez*N+1, ey*N+1, ex*N+1)``, x fastest.  ``blocks`` (default:
+    the whole box) are ``(origin, shape)`` element sub-boxes, as in
+    :func:`is_lattice`.  One whole-box block is :func:`lattice_scatter` /
+    :func:`lattice_gather` as they are; several blocks scatter from each
+    block's slice of the box, their element arrays stacked in block order,
+    and gather each block's rows padded back into the box and summed (blocks
+    share face points, and the sum adds them as the segment sum does).
+    """
+    shape = tuple(int(e) for e in box_shape)
+    n = int(n_degree)
+    whole = [((0, 0, 0), shape)]
+    blocks = whole if blocks is None else list(blocks)
+    box3 = tuple(e * n + 1 for e in shape[::-1])  # (z, y, x) points
+    if not is_lattice(l2g, shape, n, blocks):
+        return indexed_assembly(l2g, int(np.prod(box3)))
+    if blocks == whole:
+        return Assembly(
+            lambda x: lattice_scatter(x, shape, n),
+            lambda y: lattice_gather(y, shape, n),
+            "lattice",
+        )
+    # per block: its (z, y, x) point window in the box, and element count
+    wins = [
+        tuple(slice(o * n, (o + e) * n + 1) for o, e in zip(org[::-1], shp[::-1]))
+        for org, shp in blocks
+    ]
+    counts = [int(np.prod(shp)) for _, shp in blocks]
+
+    def z(x: jax.Array) -> jax.Array:
+        x3 = x.reshape(box3)
+        return jnp.concatenate([
+            lattice_scatter(x3[win].reshape(-1), shp, n)
+            for win, (_, shp) in zip(wins, blocks)
+        ])
+
+    def zt(y: jax.Array) -> jax.Array:
+        out, start = None, 0
+        for win, (_, shp), e in zip(wins, blocks, counts):
+            part = lattice_gather(y[start:start + e], shp, n)
+            part = jnp.pad(
+                part.reshape([w.stop - w.start for w in win]),
+                [(w.start, m - w.stop) for w, m in zip(win, box3)],
+            )
+            out = part if out is None else out + part
+            start += e
+        return out.reshape(-1)
+
+    return Assembly(z, zt, "lattice")
 
 
 def scatter_masked(x_g: jax.Array, l2g_ext: jax.Array) -> jax.Array:

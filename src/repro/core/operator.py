@@ -25,15 +25,7 @@ import numpy as np
 from .. import obs
 from . import coefficients as _coef
 from . import geometry, sem
-from .gather_scatter import (
-    gather,
-    gather_scatter,
-    inverse_degree,
-    is_lattice,
-    lattice_gather,
-    lattice_scatter,
-    scatter,
-)
+from .gather_scatter import assembly, gather_scatter, inverse_degree
 from .mesh import BoxMesh, build_box_mesh, dirichlet_mask, normalize_bc
 
 
@@ -177,26 +169,36 @@ class PoissonProblem:
     def n_local(self) -> int:
         return self.mesh.n_local
 
+    @property
+    def n_degree(self) -> int:
+        return self.mesh.n_degree
 
-def screen_stream(
-    prob: PoissonProblem,
-) -> tuple[jax.Array | None, float]:
+    @property
+    def screen(self) -> jax.Array | None:
+        """The weak mass screen JW·λ(x), or None with no λ(x) field."""
+        return None if self.lam_field is None else self.jw * self.lam_field
+
+
+def screen_stream(prob) -> tuple[jax.Array | None, float]:
     """The (w, lam) pair every element kernel consumes for the screen term.
 
-    Classic mode (``lam_field is None``): ``(w_local, λ)`` — the algebraic
+    Classic mode (``prob.screen is None``): ``(w_local, λ)`` — the algebraic
     λ·W screen that assembles to exactly λI (hipBone/NekBone semantics;
     bit-identical to pre-coefficient builds).
 
-    PDE mode (``lam_field`` set): ``(JW·λ_field, 1.0)`` — the mass-weighted
+    PDE mode (a λ(x) field): ``(JW·λ_field, 1.0)`` — the mass-weighted
     weak screen Zᵀ diag(JW·λ) Z.  No inverse-degree factor enters: the
     element-wise assembly sum IS the quadrature sum.  ``lam`` stays a
     static python float either way, which is what lets the variable screen
     ride the existing ``w`` stream through kernels whose ``lam`` is a
     static argname (``kernels.poisson`` / ``kernels.poisson_fused``).
+
+    ``prob`` is a :class:`PoissonProblem` or a sharded
+    ``distributed.DistPoisson``, which carries the same ``screen``,
+    ``w_local`` and ``lam``.
     """
-    if prob.lam_field is None:
-        return prob.w_local, prob.lam
-    return prob.jw * prob.lam_field, 1.0
+    screen = prob.screen
+    return (prob.w_local, prob.lam) if screen is None else (screen, 1.0)
 
 
 def _eval_field(spec, coords: np.ndarray) -> np.ndarray | None:
@@ -419,28 +421,21 @@ def _split_assembled(
     """The split apply Z^T (S_L + λW) Z of :func:`poisson_assembled`."""
     w_eff, lam_eff = screen_stream(prob)
     mask = prob.mask
-    shape, n = prob.mesh.shape, prob.mesh.n_degree
-    lattice = is_lattice(prob.l2g, shape, n)
-    if lattice:
-        z = lambda x_g: lattice_scatter(x_g, shape, n)
-        zt = lambda y_l: lattice_gather(y_l, shape, n)
-    else:
-        z = lambda x_g: scatter(x_g, prob.l2g)
-        zt = lambda y_l: gather(y_l, prob.l2g, prob.n_global)
+    asm = assembly(prob.l2g, prob.mesh.n_degree, prob.mesh.shape)
 
     def apply(x_g: jax.Array) -> jax.Array:
         with obs.scope("op.scatter"):
             if mask is not None:
                 x_g = mask * x_g
-            x_l = z(x_g)
+            x_l = asm.z(x_g)
         with obs.scope("op.local"):
             y_l = op(x_l, prob.g, prob.d, lam_eff, w_eff)
         with obs.scope("op.gather"):
-            y_g = zt(y_l)
+            y_g = asm.zt(y_l)
             return y_g if mask is None else mask * y_g
 
     apply.fused = False
-    apply.assembly = "lattice" if lattice else "indexed"
+    apply.assembly = asm.kind
     return apply
 
 

@@ -73,7 +73,7 @@ import numpy as np
 
 from .. import obs
 from . import sem
-from .gather_scatter import gather, scatter
+from .gather_scatter import Assembly, assembly, indexed_assembly
 from .schwarz import SCHWARZ_INNER_DEGREE, make_schwarz_apply
 
 
@@ -82,6 +82,7 @@ _HI = jax.lax.Precision.HIGHEST
 
 __all__ = [
     "local_operator_diagonal",
+    "level_assembly",
     "assembled_diagonal",
     "masked_dinv",
     "masked_seed",
@@ -172,13 +173,38 @@ def local_operator_diagonal(
     return diag + lam * screen
 
 
-def assembled_diagonal(prob) -> jax.Array:
+def level_assembly(
+    l2g, n_degree: int, box_shape: tuple[int, int, int], blocks=None
+) -> Assembly:
+    """Z and Zᵀ of one level for its smoother diagonal and pMG transfers.
+
+    The arguments are :func:`gather_scatter.assembly`'s, on one device (the
+    mesh's element box) and on a rank (its padded box and all its element
+    blocks) alike.  Both paths take the indexed form here on every map;
+    returning ``assembly(l2g, n_degree, box_shape, blocks)`` instead moves
+    both to the lattice form where the map allows it.
+    """
+    return indexed_assembly(l2g, int(np.prod([e * n_degree + 1 for e in box_shape])))
+
+
+def _own_assembly(prob) -> Assembly:
+    """:func:`level_assembly` of a single-device problem."""
+    return level_assembly(prob.l2g, prob.n_degree, prob.mesh.shape)
+
+
+def assembled_diagonal(prob, asm: Assembly | None = None) -> jax.Array:
     """diag(A) on assembled DOFs: Z^T diag(S_L + λ·screen) Z (Z picks out
     the diagonal entries, so this is just the gather of the local diagonal).
 
-    The screen factors come from ``operator.screen_stream`` — the algebraic
+    ``asm`` is the level's Z/Zᵀ (default: :func:`level_assembly` of a
+    single-device problem).  The screen factors come from
+    ``operator.screen_stream`` — the algebraic
     λW pair on legacy problems, the mass-weighted JW·λ(x) stream on
     variable-coefficient ones (k(x) is already folded into ``prob.g``).
+    ``prob`` may also be one rank's view of a sharded level (its ``g``,
+    ``w_local`` and ``screen`` that rank's shards): with that rank's ``asm``
+    the result is its padded box's local sum, which one sum exchange
+    completes.
     Deliberately *unmasked* even when ``prob.mask`` is set: the diagonal of
     the unmasked operator is strictly positive everywhere, so ``1/diag``
     stays finite; consumers keep M⁻¹ in the Dirichlet-interior subspace by
@@ -188,7 +214,7 @@ def assembled_diagonal(prob) -> jax.Array:
 
     w_eff, lam_eff = screen_stream(prob)
     dloc = local_operator_diagonal(prob.g, prob.d, lam_eff, w_eff)
-    return gather(dloc, prob.l2g, prob.n_global)
+    return (asm or _own_assembly(prob)).zt(dloc)
 
 
 def masked_dinv(prob, diag: jax.Array) -> jax.Array:
@@ -573,7 +599,12 @@ def tensor3_interp(j: jax.Array, u: jax.Array) -> jax.Array:
 
 
 def make_transfer_pair(
-    prob_f, prob_c
+    prob_f,
+    prob_c,
+    asm_f: Assembly | None = None,
+    asm_c: Assembly | None = None,
+    complete_f: Callable | None = None,
+    complete_c: Callable | None = None,
 ) -> tuple[Callable[[jax.Array], jax.Array], Callable[[jax.Array], jax.Array]]:
     """(prolong, restrict) between two assembled levels of one element grid.
 
@@ -582,22 +613,27 @@ def make_transfer_pair(
     copies back onto fine DOFs — ``P = Z_f^T W_f Ĵ Z_c``.  Restriction is
     built as the exact transpose ``R = P^T = Z_c^T Ĵ^T W_f Z_f`` so the
     V-cycle stays symmetric for PCG.
+
+    ``asm_f`` / ``asm_c`` are the levels' Z/Zᵀ (default:
+    :func:`level_assembly` of single-device problems).  ``complete_f`` /
+    ``complete_c`` finish each result on its level (default: as it is).  On
+    a rank of the sharded path the levels are that rank's views, the
+    assemblies its padded boxes', and each completion is the level's sum
+    exchange returning the ``(raw, consistent)`` pair: the raw box's
+    interior slots are already final, which the overlapped V-cycle reads.
     """
     j = jnp.asarray(
-        sem.interpolation_matrix(prob_c.mesh.n_degree, prob_f.mesh.n_degree),
-        prob_f.dtype,
+        sem.interpolation_matrix(prob_c.n_degree, prob_f.n_degree), prob_f.dtype
     )
-    l2g_f, l2g_c = prob_f.l2g, prob_c.l2g
-    w_lf = prob_f.w_local
-    ngf, ngc = prob_f.n_global, prob_c.n_global
+    zf, zc = asm_f or _own_assembly(prob_f), asm_c or _own_assembly(prob_c)
+    w_f = prob_f.w_local
+    same = lambda v: v
 
-    def prolong(x_c: jax.Array) -> jax.Array:
-        u_f = tensor3_interp(j, scatter(x_c, l2g_c))
-        return gather(w_lf * u_f, l2g_f, ngf)
+    def prolong(x_c: jax.Array):
+        return (complete_f or same)(zf.zt(w_f * tensor3_interp(j, zc.z(x_c))))
 
-    def restrict(r_f: jax.Array) -> jax.Array:
-        u_c = tensor3_interp(j.T, w_lf * scatter(r_f, l2g_f))
-        return gather(u_c, l2g_c, ngc)
+    def restrict(r_f: jax.Array):
+        return (complete_c or same)(zc.zt(tensor3_interp(j.T, w_f * zf.z(r_f))))
 
     return prolong, restrict
 
@@ -824,7 +860,10 @@ def make_pmg_preconditioner(
                 _mask_wrap(
                     pc_prob.mask,
                     galerkin_block_apply(
-                        blocks, pc_prob.l2g, pc_prob.n_global,
+                        blocks,
+                        assembly(
+                            pc_prob.l2g, pc_prob.n_degree, pc_prob.mesh.shape
+                        ),
                         matvec=galerkin_matvec,
                     ),
                 )

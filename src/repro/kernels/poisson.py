@@ -30,8 +30,8 @@ TPU adaptation of the paper's CUDA/HIP operator kernel (DESIGN.md §3):
 
 The scatter Z (read of x_G) happens outside at the XLA level — TPU has
 no efficient per-lane random HBM gather inside a kernel. On a box mesh Z
-is dense lattice slices (``core.gather_scatter.lattice_scatter``), else
-XLA's ``take``.
+is dense lattice slices (``core.gather_scatter.assembly``), else XLA's
+``take``.
 """
 from __future__ import annotations
 

@@ -361,7 +361,6 @@ def run_poisson_cell(name: str, mesh_kind: str) -> dict:
         schwarz_inner_degree=pc.schwarz_inner_degree,
         precond_dtype=pc.precond_dtype,
         cg_variant=pc.cg_variant,
-        fused_operator=pc.fused_operator,
         exchange=pc.exchange,
     )
     lowered = jax.jit(run.func).lower(*run.args)

@@ -49,16 +49,17 @@ SCOPES = {
     "cg.precond": "core/cg.py _pcg: every z = M^-1 r",
     "cg.allreduce": "core/distributed.py: every psum of the recurrence scalars",
     "op.scatter": "core/operator.py poisson_assembled and the halo and interior "
-                  "blocks of core/distributed.py _apply_assembled: x_L = Z x_G "
-                  "(lattice slices and a 0/1 product on box meshes and slab-lattice "
-                  "rank boxes, else take)",
+                  "blocks of core/distributed.py _split_apply: x_L = Z x_G "
+                  "(gather_scatter.assembly: lattice slices and a 0/1 product on "
+                  "box meshes and the element blocks of rank boxes, else take)",
     "op.local": "core/operator.py poisson_assembled and the halo and interior blocks "
-                "of core/distributed.py _apply_assembled: the element operator, XLA "
-                "or Pallas",
+                "of core/distributed.py _split_apply: the element operator, XLA "
+                "or Pallas, or a Galerkin level's block matvec",
     "op.gather": "core/operator.py poisson_assembled and the halo and interior "
-                 "blocks of core/distributed.py _apply_assembled: Z^T y_L (lattice "
-                 "0/1 product, slices, pads and adds on box meshes and slab-lattice "
-                 "rank boxes, else segment sum)",
+                 "blocks of core/distributed.py _split_apply: Z^T y_L "
+                 "(gather_scatter.assembly: lattice 0/1 product, slices, pads and "
+                 "adds on box meshes and the element blocks of rank boxes, else "
+                 "segment sum)",
     "op.fused": "kernels/ops.py: the fused assembled operator, one Pallas pass",
     "op.scattered": "core/operator.py poisson_scattered: (Z Z^T S_L + lambda) x_L",
     "pmg.l{i}": "core/precond.py V-cycle: level i's own work (smoothing, residual, "
@@ -107,7 +108,8 @@ TALLIES = {
     "op.assembly.lattice": "core/operator.py poisson_assembled and "
                            "core/distributed.py dist_solver: operators built "
                            "with the lattice Z and Z^T (on one chip the box "
-                           "lattice, sharded the slab lattice of each rank box)",
+                           "lattice, sharded the lattice of each rank box's "
+                           "element blocks)",
     "op.assembly.indexed": "core/operator.py poisson_assembled and "
                            "core/distributed.py dist_solver: operators built "
                            "with the indexed Z and Z^T (take, segment sum)",

@@ -219,7 +219,7 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from functools import partial
-from repro.core.distributed import build_dist_problem, _apply_assembled
+from repro.core.distributed import build_dist_problem, _on_rank, _rank_operator
 from repro.comms.topology import ProcessGrid
 from repro.core.operator import local_poisson
 
@@ -235,8 +235,9 @@ spec = P("ranks")
 @partial(shard_map, mesh=mesh, in_specs=(spec,)*3, out_specs=(spec, spec))
 def apply_both(xb, g, w):
     xc = copy_exchange(xb[0].reshape(prob.box_shape[::-1]), prob.grid, "ranks").reshape(-1)
-    one = _apply_assembled(prob, xc, g[0], w[0], local_op=local_poisson, two_phase=False)
-    two = _apply_assembled(prob, xc, g[0], w[0], local_op=local_poisson, two_phase=True)
+    rank = _on_rank(prob, g[0], w[0], None)
+    one = _rank_operator(rank, local_op=local_poisson, two_phase=False)(xc)
+    two = _rank_operator(rank, local_op=local_poisson, two_phase=True)(xc)
     return one[None], two[None]
 one, two = apply_both(jnp.asarray(x), prob.g, prob.w_local)
 np.testing.assert_allclose(np.array(one), np.array(two), atol=1e-11)

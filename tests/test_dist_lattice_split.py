@@ -1,11 +1,12 @@
 """The lattice Z and Zᵀ of the sharded halo/interior split.
 
-On the slab-lattice map (``DistPoisson.slab_lattice``) ``_apply_assembled``
-runs Z and Zᵀ as ``lattice_scatter`` / ``lattice_gather`` on the six face
-slabs and the core of each rank's padded box.  The reference is the same
-problem with its elements reordered (halo and interior each reversed) in
-``l2g``, ``g``, ``w_local`` and ``screen``, which sends it down the indexed
-take and segment sum; both run on 4 fake CPU devices in a subprocess.
+Where a rank's map is the lattice of its ``_element_blocks``,
+``gather_scatter.assembly`` gives ``_split_apply`` Z and Zᵀ as lattice
+slices on the six face slabs and the core of each rank's padded box
+(``Assembly.kind == "lattice"``).  The reference is the same problem with
+its elements reordered (halo and interior each reversed) in ``l2g``, ``g``,
+``w_local`` and ``screen``, which sends it down the indexed take and
+segment sum; both run on 4 fake CPU devices in a subprocess.
 """
 import numpy as np
 import pytest
@@ -25,8 +26,8 @@ from jax.sharding import PartitionSpec as P
 from repro.compat import make_mesh, shard_map
 from repro.comms.topology import ProcessGrid
 from repro.core.distributed import (
-    _XCH, _apply_assembled, _rank_operator, box_global_indices,
-    build_dist_problem,
+    _aux_split, _on_rank, _rank_operator, _split_assemblies, _split_kind,
+    box_global_indices, build_dist_problem,
 )
 from repro.core.operator import local_poisson
 
@@ -60,16 +61,13 @@ def applies(prob, x, x_raw):
     @partial(shard_map, mesh=mesh, in_specs=(spec,) * 4 + (tuple(spec for _ in aux),),
              out_specs=(spec,) * 4)
     def run(x_s, raw_s, g_s, w_s, aux_s):
-        x1, g1, w1 = x_s[0], g_s[0], w_s[0]
-        s1 = aux_s[0][0] if prob.screen is not None else None
-        bcm1 = aux_s[-1][0] if prob.bc_mask is not None else None
-        apply = partial(_apply_assembled, prob, g=g1, w=w1, local_op=local_poisson,
-                        screen=s1)
-        masked = _rank_operator(prob, g1, w1, screen=s1, bc_mask=bcm1,
-                                local_op=local_poisson, two_phase=False,
-                                fused_interior=False, xsum=_XCH, xcopy=_XCH)
-        outs = (apply(x_box=x1, two_phase=False), apply(x_box=x1, two_phase=True),
-                apply(x_box=x1, two_phase=False, x_raw=raw_s[0]), masked(x1))
+        x1 = x_s[0]
+        rank = _on_rank(prob, g_s[0], w_s[0], None, *_aux_split(prob, aux_s))
+        plain = _on_rank(prob, g_s[0], w_s[0], None, rank.screen)  # no bc mask
+        apply = partial(_rank_operator, local_op=local_poisson)
+        outs = (apply(plain, two_phase=False)(x1), apply(plain, two_phase=True)(x1),
+                apply(plain, two_phase=False)(x1, raw_s[0]),
+                apply(rank, two_phase=False)(x1))
         return tuple(o[None] for o in outs)
 
     return [np.asarray(o) for o in jax.jit(run)(x, x_raw, prob.g, prob.w_local, aux)]
@@ -79,7 +77,9 @@ rng = np.random.default_rng(7)
 for kw in ({{}}, {{"coefficient": "smooth", "bc": "mixed"}}):
     lat = build_dist_problem(n, grid, local, lam=0.7, dtype=jnp.float64, **kw)
     idx = reordered(lat)
-    assert lat.slab_lattice and not idx.slab_lattice
+    assert _split_kind(lat) == "lattice" and _split_kind(idx) == "indexed"
+    assert [a.kind for a in _split_assemblies(lat)] == ["lattice"] * 2
+    assert _split_assemblies(idx)[0].kind == "indexed"
     assert (lat.screen is None) == (not kw)
     xg = rng.standard_normal(lat.n_global)
     x = jnp.asarray(xg[box_global_indices(lat)])

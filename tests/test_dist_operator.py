@@ -203,12 +203,15 @@ for policy, routes in want.items():
     assert [s.name for s in obs.spans()] == ["setup.exchange_plan"]
     for name in got:
         assert name in obs.TALLIES
-# the same problem with its halo elements in another order: not the slab
-# lattice, so the solver's operator takes and segment-sums through l2g
+# the same problem with its halo elements in another order: not the lattice
+# of its element blocks, so the solver's operator takes and segment-sums
+# through l2g
 perm = np.r_[np.arange(prob.halo_elems)[::-1], np.arange(prob.halo_elems, prob.e_local)]
 other = dataclasses.replace(prob, l2g=prob.l2g[perm], g=prob.g[:, perm],
                             w_local=prob.w_local[:, perm])
-assert prob.slab_lattice and not other.slab_lattice
+from repro.core.distributed import _split_assemblies
+assert [a.kind for a in _split_assemblies(prob)] == ["lattice"] * 2
+assert _split_assemblies(other)[0].kind == "indexed"
 obs.reset()
 dist_solver(other, mesh, n_iter=5, exchange="face_sweep")
 assert obs.tallies() == {"op.assembly.indexed": 1, **want["face_sweep"]}, obs.tallies()

@@ -295,7 +295,7 @@ def test_config_rejects_invalid_knob_combos():
         dict(n_degree=1, precond="pmg"), dict(schwarz_overlap=7),
         dict(precond_dtype="bfloat16"),
         dict(precond_dtype="float32", precond="none"),
-        dict(cg_variant="cgs"), dict(fused_operator=1),
+        dict(cg_variant="cgs"),
         dict(divergence_factor=1.0), dict(stagnation_window=0),
         dict(stagnation_rtol=0.0),
     ]

@@ -11,8 +11,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import run_subprocess
-
 jax.config.update("jax_enable_x64", True)
 
 from repro.core import build_problem, cg_assembled, poisson_assembled  # noqa: E402
@@ -173,37 +171,3 @@ def test_fused_vmem_budget_helpers():
     n_pad = -(-100_000 // 128) * 128
     assert fused_vmem_bytes(eb, 8, n_pad, jnp.float32) <= 8 * 2**20
     assert eb >= 1
-
-
-@pytest.mark.slow
-def test_dist_cg_fused_operator_parity():
-    """fused_operator=True matches the split distributed solve exactly
-    (both with the Pallas element kernel, so only gather/scatter differ)."""
-    code = """
-import jax
-import jax.numpy as jnp
-import numpy as np
-from repro.compat import make_mesh
-from repro.comms.topology import ProcessGrid, factor3
-from repro.core.distributed import build_dist_problem, dist_cg
-from repro.kernels import ops
-
-ranks = 8
-grid = ProcessGrid(factor3(ranks))
-mesh = make_mesh((ranks,), ("ranks",))
-prob = build_dist_problem(3, grid, (3, 3, 3), lam=1.0, dtype=jnp.float32)
-assert prob.e_local > prob.halo_elems, "need a non-empty interior block"
-rng = np.random.default_rng(0)
-b = jnp.asarray(rng.standard_normal((ranks, prob.m3)), jnp.float32)
-runs = {}
-for fused in (False, True):
-    run = jax.jit(dist_cg(prob, mesh, b, n_iter=40, tol=1e-6,
-                          precond="jacobi", fused_operator=fused,
-                          local_op=ops.make_local_op()))
-    x, rr, iters, status, hist = run()
-    runs[fused] = (np.asarray(x), int(iters))
-assert runs[True][1] == runs[False][1], runs
-np.testing.assert_allclose(runs[True][0], runs[False][0], rtol=1e-6)
-print("OK")
-"""
-    assert "OK" in run_subprocess(code, devices=8)

@@ -14,6 +14,7 @@ from repro.core.galerkin import (
     galerkin_element_blocks,
     galerkin_ladder_blocks,
 )
+from repro.core.gather_scatter import assembly
 from repro.core.operator import coarsen_problem, local_operator_columns
 from repro.core.precond import (
     make_pmg_preconditioner,
@@ -21,6 +22,10 @@ from repro.core.precond import (
     make_transfer_pair,
 )
 from repro.core.sem import interpolation_matrix
+
+
+def _asm(prob):
+    return assembly(prob.l2g, prob.n_degree, prob.mesh.shape)
 
 
 @pytest.fixture(scope="module")
@@ -62,7 +67,7 @@ def test_materialized_equals_chained_triple_product(prob64):
         prob64.g, prob64.d, prob64.lam, prob64.w_local, 2
     )
     got1 = _dense(
-        galerkin_block_apply(blocks1, pc1.l2g, pc1.n_global), pc1.n_global
+        galerkin_block_apply(blocks1, _asm(pc1)), pc1.n_global
     )
     np.testing.assert_allclose(got1, want1, atol=1e-12)
 
@@ -72,7 +77,7 @@ def test_materialized_equals_chained_triple_product(prob64):
     want2 = _dense(lambda v: r2(restrict(a(prolong(p2(v))))), pc2.n_global)
     blocks2 = coarsen_element_blocks(blocks1, interpolation_matrix(1, 2))
     got2 = _dense(
-        galerkin_block_apply(blocks2, pc2.l2g, pc2.n_global), pc2.n_global
+        galerkin_block_apply(blocks2, _asm(pc2)), pc2.n_global
     )
     np.testing.assert_allclose(got2, want2, atol=1e-12)
 
@@ -81,7 +86,7 @@ def test_materialized_equals_chained_triple_product(prob64):
         np.array(blocks1), np.array(blocks1.transpose(0, 2, 1))
     )
     np.testing.assert_allclose(
-        np.array(galerkin_assembled_diagonal(blocks1, pc1.l2g, pc1.n_global)),
+        np.array(galerkin_assembled_diagonal(blocks1, _asm(pc1))),
         np.diag(want1),
         atol=1e-12,
     )
@@ -107,7 +112,7 @@ def test_galerkin_probing_is_coefficient_agnostic(coefficient):
     w_eff, lam_eff = screen_stream(prob)
     blocks = galerkin_element_blocks(prob.g, prob.d, lam_eff, w_eff, 2)
     got = _dense(
-        galerkin_block_apply(blocks, pc1.l2g, pc1.n_global), pc1.n_global
+        galerkin_block_apply(blocks, _asm(pc1)), pc1.n_global
     )
     np.testing.assert_allclose(got, want, atol=1e-12)
 
@@ -176,7 +181,7 @@ def test_galerkin_mat_zero_fine_applies_per_coarse_apply(prob64):
         prob64.g, prob64.d, prob64.lam, prob64.w_local, 2
     )
     pc1 = coarsen_problem(prob64, 2)
-    coarse = galerkin_block_apply(blocks, pc1.l2g, pc1.n_global)
+    coarse = galerkin_block_apply(blocks, _asm(pc1))
     jax.block_until_ready(coarse(jnp.ones(pc1.n_global)))
     assert calls["n"] == 0
 

@@ -2,7 +2,9 @@
 
 The lattice pair must be the indexed pair (``take`` / ``segment_sum``) on
 the box lattice numbering: Z exactly, Z^T up to the order of each shared
-point's sum. ``poisson_assembled`` takes it only where ``is_lattice`` holds.
+point's sum. ``assembly`` chooses it only where the map is that lattice, of
+a whole box or of element blocks of it; ``poisson_assembled`` takes its Z
+and Z^T from there.
 """
 import dataclasses
 
@@ -13,10 +15,11 @@ import pytest
 
 from repro import obs
 from repro.core import build_box_mesh, build_problem, poisson_assembled
-from repro.core.distributed import _local_l2g
+from repro.core.distributed import _element_blocks, _local_l2g
 from repro.core.gather_scatter import (
+    assembly,
     gather,
-    is_lattice,
+    indexed_assembly,
     lattice_gather,
     lattice_scatter,
     scatter,
@@ -67,10 +70,14 @@ def test_lattice_pair_is_adjoint(n, shape):
     assert abs(float(lhs - rhs)) <= 1e-12 * abs(float(lhs))
 
 
+def _kind(l2g, shape, n, blocks=None):
+    return assembly(l2g, n, shape, blocks).kind
+
+
 def test_lattice_numbering_is_the_box_mesh_map():
     for n, shape in CASES:
         l2g = build_box_mesh(n, shape).l2g
-        assert is_lattice(l2g, shape, n)
+        assert _kind(l2g, shape, n) == "lattice"
         np.testing.assert_array_equal(lattice_l2g(shape, n), l2g)
 
 
@@ -79,24 +86,71 @@ def test_is_lattice_rejects_other_maps():
     l2g = build_box_mesh(n, shape).l2g
     rng = np.random.default_rng(0)
     perm = rng.permutation(l2g.max() + 1)
-    assert not is_lattice(perm[l2g], shape, n)           # renumbered points
-    assert not is_lattice(l2g[rng.permutation(len(l2g))], shape, n)  # elements
-    assert not is_lattice(l2g[:, ::-1], shape, n)        # local node order
+    indexed = lambda m, s=shape, k=n: _kind(m, s, k) == "indexed"
+    assert indexed(perm[l2g])                            # renumbered points
+    assert indexed(l2g[rng.permutation(len(l2g))])       # elements
+    assert indexed(l2g[:, ::-1])                         # local node order
     swapped = l2g.copy()
     swapped[0, [0, 1]] = swapped[0, [1, 0]]
-    assert not is_lattice(swapped, shape, n)             # one swap
-    assert not is_lattice(l2g, (2, 3, 4), n)             # another grid
-    assert not is_lattice(l2g, shape, 2)                 # another degree
-    assert not is_lattice(l2g[:-1], shape, n)            # another shape
+    assert indexed(swapped)                              # one swap
+    assert indexed(l2g, (2, 3, 4))                       # another grid
+    assert indexed(l2g, shape, 2)                        # another degree
+    assert indexed(l2g[:-1])                             # another shape
 
 
 def test_is_lattice_on_halo_first_maps():
     # a box with interior elements numbers its halo elements first: not the
-    # lattice; in a box that is all halo the two orders coincide
+    # lattice of the whole box but that of its element blocks; in a box that
+    # is all halo the two orders coincide
     l2g, n_halo = _local_l2g(3, (4, 4, 4))
-    assert n_halo < 64 and not is_lattice(l2g, (4, 4, 4), 3)
+    blocks, _ = _element_blocks((4, 4, 4))
+    assert n_halo < 64 and _kind(l2g, (4, 4, 4), 3) == "indexed"
+    assert _kind(l2g, (4, 4, 4), 3, blocks) == "lattice"
+    assert _kind(l2g, (4, 4, 4), 3, blocks[::-1]) == "indexed"
     l2g, n_halo = _local_l2g(3, (2, 2, 2))
-    assert n_halo == 8 and is_lattice(l2g, (2, 2, 2), 3)
+    assert n_halo == 8 and _kind(l2g, (2, 2, 2), 3) == "lattice"
+
+
+def _part(n, shape, part):
+    """(l2g, blocks, kind expected) of one element set of the box ``shape``."""
+    if part == "box":
+        return lattice_l2g(shape, n), None, "lattice"
+    l2g, eh = _local_l2g(n, shape)
+    blocks, nh = _element_blocks(shape)
+    if part == "halo":
+        return l2g[:eh], blocks[:nh], "lattice"
+    if part == "interior":
+        return l2g[eh:], blocks[nh:], "lattice"
+    # the interior with its elements in another order
+    perm = np.random.default_rng(0).permutation(len(l2g) - eh)
+    return l2g[eh:][perm], blocks[nh:], "indexed"
+
+
+SEAM = [
+    (n, shape, part)
+    for n in (1, 2, 3, 7)
+    for shape in ((1, 1, 1), (2, 3, 1), (4, 4, 4))
+    for part in ("box", "halo", "interior")
+    if part != "interior" or min(shape) > 2
+] + [(3, (4, 4, 4), "permuted")]
+
+
+@pytest.mark.parametrize("n,shape,part", SEAM)
+def test_assembly_lattice_equals_indexed(n, shape, part):
+    """One map's ``assembly`` (lattice where it may be) and
+    ``indexed_assembly``: Z bitwise, Z^T to the order of each point's sum."""
+    l2g, blocks, kind = _part(n, shape, part)
+    n_points = int(np.prod([e * n + 1 for e in shape]))
+    got, ref = assembly(l2g, n, shape, blocks), indexed_assembly(l2g, n_points)
+    assert (got.kind, ref.kind) == (kind, "indexed")
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal(n_points))
+    y = jnp.asarray(rng.standard_normal(l2g.shape))
+    np.testing.assert_array_equal(jax.jit(got.z)(x), jax.jit(ref.z)(x))
+    want = np.asarray(jax.jit(ref.zt)(y))
+    np.testing.assert_allclose(
+        jax.jit(got.zt)(y), want, rtol=0, atol=1e-13 * np.abs(want).max()
+    )
 
 
 def _renumbered(prob, seed=0):
